@@ -1,29 +1,21 @@
 """Distributed + batched sparse execution benchmarks (`BENCH_dist.json`).
 
-Sharded rows need a real device mesh, so the measurement happens in a
-forced-8-device subprocess (``--xla_force_host_platform_device_count``
-must be set before JAX initializes; the main benchmark process has
-already initialized a single-device runtime). ``run()`` spawns the
-subprocess and relays its rows; ``python -m benchmarks.bench_dist``
-is the inner entry point.
+Runs in the calling process on the devices JAX already sees: the shard
+mesh spans ``jax.devices()``. On a CPU host, set
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before starting
+Python to get an 8-device mesh; the process never starts a JAX child
+(a chip belongs to the one process that opened it).
 
-On a CPU host the 8 "devices" share the same cores, so sharded
-wall-clock is a correctness/overhead trail, not a speedup claim — the
-derived column records the ratio honestly. The batched rows quantify
-the real win on any backend: one AOT executable over a panel stack vs
-a Python loop of single applies.
+On a CPU host the "devices" share the same cores, so sharded wall-clock
+is a correctness/overhead trail, not a speedup claim — the derived
+column records the ratio honestly. The batched rows quantify the win on
+any backend: one AOT executable over a panel stack vs a Python loop of
+single applies.
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
 
-_MARK = "BENCH_DIST_JSON:"
-
-
-def _inner() -> None:
+def run() -> list[tuple]:
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -119,28 +111,9 @@ def _inner() -> None:
     rows.append((f"dist/gcn_step_dist_p{n_dev}", t_step_d * 1e6,
                  f"loss{float(loss_d):.4f}_gap{gap:.1e}"))
 
-    print(_MARK + json.dumps(rows))
-
-
-def run() -> list[tuple]:
-    env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               PYTHONPATH=os.pathsep.join(
-                   [os.path.join(os.path.dirname(__file__), "..", "src"),
-                    os.path.join(os.path.dirname(__file__), ".."),
-                    os.environ.get("PYTHONPATH", "")]))
-    out = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_dist"],
-        capture_output=True, text=True, env=env, timeout=1800,
-        cwd=os.path.join(os.path.dirname(__file__), ".."))
-    if out.returncode != 0:
-        raise RuntimeError(f"bench_dist subprocess failed:\n"
-                           f"{out.stderr[-3000:]}")
-    for line in out.stdout.splitlines():
-        if line.startswith(_MARK):
-            return [tuple(r) for r in json.loads(line[len(_MARK):])]
-    raise RuntimeError("bench_dist subprocess emitted no rows")
+    return rows
 
 
 if __name__ == "__main__":
-    _inner()
+    for row in run():
+        print(",".join(str(c) for c in row))

@@ -38,7 +38,7 @@ def run() -> list[tuple]:
             t_m = timeit(lambda: op_m(x, y))
             cfg = op_m.tune_config
             rows.append((f"sddmm/{name}/tuned_model", t_m * 1e6,
-                         f"thr{cfg.threshold}_kf{cfg.kf_tile}_yt{cfg.yt}"
+                         f"thr{cfg.threshold}_kf{cfg.kf_tile}"
                          f"_x{t_h / t_m:.2f}"))
         rows.append((f"sddmm/{name}/hybrid", t_h * 1e6,
                      f"{sddmm_gflops(a.nnz, K, t_h):.2f}GF"))

@@ -25,8 +25,7 @@ def _pallas_bytes_accessed(op: LibraSpMM, b) -> float:
     from repro.launch import hlo_analysis as H
 
     lowered = spmm_apply.lower(op.arrays, b, m=op.m, nwin=op.nwin,
-                               backend="pallas", cfg=op.tune_config,
-                               interpret=True)
+                               backend="pallas", cfg=op.tune_config)
     return float(H.analyze_hlo(lowered.compile().as_text()).hbm_bytes)
 
 
@@ -46,7 +45,7 @@ def _tuned_rows(name: str, a, b, t_default: float) -> list[tuple]:
     occ = occupancy_report(vmem_spmm_bytes(
         cfg, bk=op_m.plan.tc.bk, ts=op_m.plan.vpu.ts))
     rows.append((f"spmm/{name}/tuned_model", t_model * 1e6,
-                 f"thr{cfg.threshold}_kt{cfg.kt}_nt{cfg.nt}"
+                 f"thr{cfg.threshold}_nt{cfg.nt}"
                  f"_vmem{occ['bytes_per_step'] // 1024}KB"
                  f"_x{t_default / t_model:.2f}"))
     with tempfile.TemporaryDirectory() as d:
@@ -65,7 +64,7 @@ def _tuned_rows(name: str, a, b, t_default: float) -> list[tuple]:
     else:
         t_search = timeit(lambda: op_s(b))
     rows.append((f"spmm/{name}/tuned_search", t_search * 1e6,
-                 f"thr{cfg_s.threshold}_kt{cfg_s.kt}"
+                 f"thr{cfg_s.threshold}_nt{cfg_s.nt}"
                  f"_x{t_default / t_search:.2f}"))
     return rows
 

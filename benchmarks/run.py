@@ -30,6 +30,9 @@ def main() -> None:
                     help="append this run's ratio bars (+ git sha/date) "
                          "to a BENCH_history.jsonl trajectory file")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         bench_ablations,
         bench_chaos,

@@ -20,10 +20,10 @@ def main() -> None:
     b = jnp.asarray(rng.standard_normal((a.k, 128)).astype(np.float32))
     spmm = LibraSpMM(a)                       # preprocess + autotune once
     cfg = spmm.tune_config                    # the model-tuned plan choice
-    print(f"tuned: threshold={cfg.threshold} kt={cfg.kt} nt={cfg.nt} "
+    print(f"tuned: threshold={cfg.threshold} nt={cfg.nt} "
           f"grid_order={cfg.grid_order} (source={cfg.source})")
     c = spmm(b)                               # fast XLA path
-    c_pallas = spmm(b, backend="pallas")      # Pallas TPU kernels (interpret)
+    c_pallas = spmm(b, backend="pallas")      # Pallas kernels (interpreted on CPU)
     oracle = ref.spmm_dense_oracle(a.to_dense(), np.asarray(b))
     print(f"SpMM: tc_ratio={spmm.tc_ratio:.2f} "
           f"max_err_xla={np.abs(np.asarray(c) - oracle).max():.2e} "

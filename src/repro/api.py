@@ -1,7 +1,7 @@
 """`ExecSpec`: the one execution-knob surface for every Libra operator.
 
 Before this module, the same knobs — ``tune=``, ``tune_backend=``,
-``tune_cache=``, ``backend=``, ``interpret=``, ``mode=``, per-op
+``tune_cache=``, ``backend=``, ``mode=``, per-op
 thresholds, and now ``reorder=`` — were duplicated (with drifting
 defaults) across :class:`~repro.core.spmm.LibraSpMM`,
 :class:`~repro.core.sddmm.LibraSDDMM`, ``GraphOps``, ``DistGraphOps``,
@@ -65,8 +65,9 @@ class ExecSpec:
       tune_cache:       PlanCache instance or cache-dir path
 
     Execution:
-      backend:          default apply backend ("xla" | "pallas")
-      interpret:        run Pallas kernels in interpret mode
+      backend:          default apply backend ("xla" | "pallas"); Pallas
+                        kernels compile on a TPU and run in the Pallas
+                        interpreter elsewhere
       b_layout:         dense-operand layout for sharded ops
                         ("replicated" | "rowshard")
     """
@@ -83,7 +84,6 @@ class ExecSpec:
     tune_kf: int = 128
     tune_cache: Any = None
     backend: str = "xla"
-    interpret: bool = True
     b_layout: str = "replicated"
 
     def __post_init__(self):

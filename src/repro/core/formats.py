@@ -72,13 +72,13 @@ class TCBlocks:
     Two fields are *derived* from ``window`` (the TC-window compaction map):
 
     rank:       (nblk,) i32 — dense rank of each block's window among the
-                windows that have TC work. The MXU kernel writes its output
-                at ``rank`` instead of ``window``, so the TC partial buffer
-                is ``(n_active, 8, n)`` rather than ``(nwin, 8, n)`` — on
-                hyper-sparse matrices (tc_ratio → 0) that removes nearly
-                the entire zero-initialized dense output.
-    active_win: (n_active,) i32 — rank → window id, used by the scatter
-                epilogue to place compacted TC rows into C.
+                windows that have TC work. The XLA reference sums block
+                partials at ``rank`` instead of ``window``, so its TC
+                buffer is ``(n_active, 8, n)`` rather than ``(nwin, 8, n)``
+                — on hyper-sparse matrices (tc_ratio → 0) that removes
+                nearly the entire zero-initialized dense output.
+    active_win: (n_active,) i32 — rank → window id, used by the combine
+                epilogues to place TC rows into C.
     """
 
     vals: np.ndarray
@@ -206,10 +206,8 @@ def _spmm_segment_arrays(plan: "SpMMPlan") -> dict[str, np.ndarray]:
     MXU: segment ``s`` owns ≤ ``ts`` condensed blocks of one window,
     flattened to an ``(8, ts·bk)`` operand (the sum of per-block
     ``8×bk @ bk×n`` products equals one ``8×(ts·bk) @ (ts·bk)×n``
-    product, so a segment is a single MXU dot). Every segment has its
-    own compacted output slot (``rank = arange``), so the k-tile carry
-    never chains across segments and ``block_outer`` is always legal.
-    VPU: segment ``s`` owns ≤ ``cs`` residual elements (whole tiles) of
+    product, so a segment is a single MXU dot); ``tc_seg_row`` maps its
+    8 output rows to its window's rows of C. VPU: segment ``s`` owns ≤ ``cs`` residual elements (whole tiles) of
     one row — the same kernel, a wider tile. Padding is inert: zero
     values multiply B row 0; ``pos`` stays −1 so revaluation skips it.
     """
@@ -231,7 +229,6 @@ def _spmm_segment_arrays(plan: "SpMMPlan") -> dict[str, np.ndarray]:
         if pos is not None:
             out["tc_seg_pos"] = pos.transpose(0, 2, 1, 3).reshape(
                 nseg, WINDOW, w * bk).astype(np.int32)
-        out["tc_seg_rank"] = np.arange(nseg, dtype=np.int32)
         out["tc_seg_row"] = (
             win[:, None].astype(np.int64) * WINDOW
             + np.arange(WINDOW, dtype=np.int64)[None, :]
@@ -353,7 +350,7 @@ def _host_arrays(plan) -> dict[str, np.ndarray]:
 # Compact key sets per stream (SpMM / SDDMM) and their §4.3 segment
 # replacements — the ingredients of PlanArrays.backend_keys.
 _SPMM_TC = ("tc_vals", "tc_cols", "tc_rank", "tc_active_row")
-_SPMM_TC_SEG = ("tc_seg_vals", "tc_seg_cols", "tc_seg_rank", "tc_seg_row")
+_SPMM_TC_SEG = ("tc_seg_vals", "tc_seg_cols", "tc_seg_row")
 _SPMM_VPU = ("vpu_vals", "vpu_cols", "vpu_row")
 _SPMM_VPU_SEG = ("vpu_seg_vals", "vpu_seg_cols", "vpu_seg_row")
 _SDDMM_TC = ("tc_cols", "tc_bitmap", "tc_window", "tc_out_pos")

@@ -12,8 +12,8 @@ Execution knobs live on one frozen :class:`repro.api.ExecSpec`
 SDDMM block threshold maps to ``ExecSpec.sddmm_threshold``). Autotuning
 semantics (``spec.tune``) match :class:`repro.core.spmm.LibraSpMM`:
 ``"model"`` (default) picks the block threshold from the matrix's
-vector histogram and sizes the feature tile (``kf_tile``) and the Y row
-panel (``yt``) to the VMEM budget; ``"search"`` times a candidate grid
+vector histogram and sizes the feature tile (``kf_tile``) to the VMEM
+budget; ``"search"`` times a candidate grid
 and memoizes the winner in the persistent plan cache; ``"off"`` keeps
 the hardcoded defaults; a :class:`~repro.tune.model.TuneConfig`
 instance is used as-is. Explicit ``threshold=``/forcing ``mode=``
@@ -84,11 +84,9 @@ class LibraSDDMM:
             backend=spec.tune_backend)
 
     def __call__(self, x: jnp.ndarray, y: jnp.ndarray,
-                 backend: str | None = None,
-                 interpret: bool | None = None) -> jnp.ndarray:
+                 backend: str | None = None) -> jnp.ndarray:
         assert x.shape[0] >= self.m and y.shape[0] >= self.k
         backend = self.spec.backend if backend is None else backend
-        interpret = self.spec.interpret if interpret is None else interpret
         if self._row_perm is not None:
             # Row-permuted plan: gather x into reordered row space (the
             # output scatter maps already point back to original
@@ -102,11 +100,9 @@ class LibraSDDMM:
         arrs = self.arrays.for_backend(backend)
         fn = cached_compile(
             self._apply_cache,
-            (x.shape[1], str(x.dtype), backend, interpret,
-             x.shape[0], y.shape[0]),
+            (x.shape[1], str(x.dtype), backend, x.shape[0], y.shape[0]),
             lambda: sddmm_apply.lower(arrs, x, y, nnz=self.nnz,
-                                      backend=backend, cfg=self.tune_config,
-                                      interpret=interpret),
+                                      backend=backend, cfg=self.tune_config),
             sample=apply_sampler(self, "sddmm", width=x.shape[1],
                                  dtype=str(x.dtype), backend=backend))
         return fn(arrs, x, y)

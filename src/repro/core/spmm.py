@@ -26,7 +26,7 @@ per matrix instead of hardcoded):
 
 * ``tune="model"`` (default) — the analytical occupancy model in
   :mod:`repro.tune` picks the TC/VPU threshold from the matrix's vector
-  histogram and sizes ``kt``/``nt``/grid order to the VMEM budget.
+  histogram and sizes ``nt``/grid order to the VMEM budget.
   Cheap (one feature pass, no timing).
 * ``tune="search"`` — empirically times a small candidate grid through
   this apply path and keeps the argmin; memoized in the persistent
@@ -113,22 +113,20 @@ class LibraSpMM:
             width=spec.tune_n, dtype="float32",
             backend=spec.tune_backend)
 
-    def __call__(self, b: jnp.ndarray, backend: str | None = None,
-                 interpret: bool | None = None) -> jnp.ndarray:
+    def __call__(self, b: jnp.ndarray, backend: str | None = None
+                 ) -> jnp.ndarray:
         assert b.shape[0] == self.k, (b.shape, self.k)
         backend = self.spec.backend if backend is None else backend
-        interpret = self.spec.interpret if interpret is None else interpret
         # Only the key set this backend's apply reads is uploaded —
         # an xla operator never materializes the §4.3 segment tables
         # and a pallas one never the compact fallback.
         arrs = self.arrays.for_backend(backend)
         fn = cached_compile(
             self._apply_cache,
-            (b.shape[1], str(b.dtype), backend, interpret),
+            (b.shape[1], str(b.dtype), backend),
             lambda: spmm_apply.lower(arrs, b, m=self.m,
                                      nwin=self.nwin, backend=backend,
-                                     cfg=self.tune_config,
-                                     interpret=interpret),
+                                     cfg=self.tune_config),
             sample=apply_sampler(self, "spmm", width=b.shape[1],
                                  dtype=str(b.dtype), backend=backend))
         out = fn(arrs, b)

@@ -29,7 +29,8 @@ from jax.sharding import Mesh
 
 from repro.api import UNSET, ExecSpec, resolve_spec
 from repro.dist.partition import partition_sddmm, partition_spmm
-from repro.dist.sparse import SHARD_AXIS, sddmm_sharded, spmm_sharded
+from repro.dist.sparse import (SHARD_AXIS, place_partition, sddmm_sharded,
+                               spmm_sharded)
 from repro.models.gnn import edge_softmax, gcn_forward, transpose_csr
 from repro.sparse.matrix import SparseCSR
 
@@ -45,8 +46,8 @@ class DistGraphOps:
 
     def __init__(self, a: SparseCSR, mesh: Mesh, axis: str = SHARD_AXIS,
                  mode=UNSET, spmm_threshold=UNSET, sddmm_threshold=UNSET,
-                 tune=UNSET, backend=UNSET, b_layout=UNSET,
-                 interpret=UNSET, *, spec: ExecSpec | None = None):
+                 tune=UNSET, backend=UNSET, b_layout=UNSET, *,
+                 spec: ExecSpec | None = None):
         # ExecSpec's tune default ("model") matches this class's legacy
         # default, so the spec-less path is unchanged. Reordering
         # (spec.reorder) rides inside the partitions: their gathers are
@@ -55,19 +56,20 @@ class DistGraphOps:
         spec = resolve_spec(
             spec, "DistGraphOps", mode=mode, threshold=spmm_threshold,
             sddmm_threshold=sddmm_threshold, tune=tune, backend=backend,
-            b_layout=b_layout, interpret=interpret)
+            b_layout=b_layout)
         self.spec = spec
         self.mesh, self.axis = mesh, axis
         self.backend, self.b_layout = spec.backend, spec.b_layout
-        self.interpret = spec.interpret
         self.a = a
         self.m, self.k = a.shape
         self.nnz = a.nnz
         n_shards = int(mesh.shape[axis])
-        self.part = partition_spmm(a, n_shards, spec=spec)
         at, self.perm = transpose_csr(a)
-        self.part_t = partition_spmm(at, n_shards, spec=spec)
-        self.part_sd = partition_sddmm(a, n_shards, spec=spec)
+        self.part, self.part_t, self.part_sd = (
+            place_partition(p, mesh, axis) for p in (
+                partition_spmm(a, n_shards, spec=spec),
+                partition_spmm(at, n_shards, spec=spec),
+                partition_sddmm(a, n_shards, spec=spec)))
         self.perm_dev = jnp.asarray(self.perm)
         rows, _, _ = a.to_coo()
         self.edge_row = jnp.asarray(rows, jnp.int32)
@@ -90,14 +92,12 @@ class DistGraphOps:
     def _spmm(self, part, b, edge_vals=None):
         return spmm_sharded(part, b, mesh=self.mesh, axis=self.axis,
                             backend=self.backend, edge_vals=edge_vals,
-                            b_layout=self.b_layout,
-                            interpret=self.interpret)
+                            b_layout=self.b_layout)
 
     def _sddmm(self, x, y):
         return sddmm_sharded(self.part_sd, x, y, mesh=self.mesh,
                              axis=self.axis, backend=self.backend,
-                             y_layout=self.b_layout,
-                             interpret=self.interpret)
+                             y_layout=self.b_layout)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))

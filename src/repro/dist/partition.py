@@ -210,20 +210,13 @@ def _make_shards(a: SparseCSR, n_shards: int,
 def _combine_run_cfg(cfgs: list[TuneConfig], bk, ts_tile,
                      seg_ts, seg_cs) -> TuneConfig:
     """One kernel-tile config every shard can run: min tiles across
-    shards (VMEM-safe on all of them), always-legal grid order. The
+    shards (VMEM-safe on all of them). The
     §4.3 segment caps ride through verbatim — they are unified across
     shards before preprocessing (stacked launch tables must agree in
     width), like ``bk``/``ts_tile``."""
-    def opt_min(vals):
-        got = [v for v in vals if v is not None]
-        return min(got) if got else None
-
     return TuneConfig(
-        kt=min(c.kt for c in cfgs),
         nt=min(c.nt for c in cfgs),
         kf_tile=min(c.kf_tile for c in cfgs),
-        yt=opt_min([c.yt for c in cfgs]),
-        xt=opt_min([c.xt for c in cfgs]),
         threshold=None, bk=bk, ts_tile=ts_tile,
         ts=seg_ts, cs=seg_cs,
         grid_order="n_outer", source="dist",
@@ -246,14 +239,11 @@ def _run_cfg_candidates(base: TuneConfig, op: str,
     if backend != "pallas":
         return cands
     if op == "spmm":
-        for kt in (base.kt * 2, base.kt // 2):
-            if kt >= 8:
-                cands.append(base.replace(kt=kt))
-    else:
-        if base.yt is not None and base.yt // 2 >= 8:
-            cands.append(base.replace(yt=base.yt // 2))
-        if base.xt is not None and base.xt // 2 >= 8:
-            cands.append(base.replace(xt=base.xt // 2))
+        cands.append(base.replace(grid_order="block_outer"))
+        if base.nt // 2 >= 128:
+            cands.append(base.replace(nt=base.nt // 2))
+    elif base.kf_tile // 2 >= 128:
+        cands.append(base.replace(kf_tile=base.kf_tile // 2))
     seen, out = set(), []
     for c in cands:
         if c not in seen:
@@ -316,7 +306,7 @@ def _timed_apply(part, op: str, *, backend: str, mesh):
                                                   backend=backend))
         return jax.jit(lambda x, y: sddmm_sharded(part, x, y, mesh=mesh,
                                                   backend=backend))
-    from repro.kernels.ops import sddmm_apply, spmm_apply
+    from repro.kernels.ops import map_batch, sddmm_apply, spmm_apply
 
     if op == "spmm":
         def apply_spmm(b):
@@ -325,8 +315,8 @@ def _timed_apply(part, op: str, *, backend: str, mesh):
                 b_halo = jnp.take(b, local["halo"], axis=0)
                 return spmm_apply(arrs, b_halo, m=part.rows_pad,
                                   nwin=part.wmax, backend=backend,
-                                  cfg=part.run_cfg, interpret=True)
-            out = jax.vmap(body)(part.stacked)
+                                  cfg=part.run_cfg)
+            out = map_batch(backend, body, part.stacked)
             return jnp.take(out.reshape(-1, b.shape[1]),
                             part.out_gather, axis=0)
         return jax.jit(apply_spmm)
@@ -339,44 +329,42 @@ def _timed_apply(part, op: str, *, backend: str, mesh):
             arrs = {k: v for k, v in local.items() if k != "halo"}
             y_halo = jnp.take(y, local["halo"], axis=0)
             return sddmm_apply(arrs, xx, y_halo, nnz=part.nnz_pad,
-                               backend=backend, cfg=part.run_cfg,
-                               interpret=True)
-        out = jax.vmap(body)(part.stacked, x_panels)
+                               backend=backend, cfg=part.run_cfg)
+        out = map_batch(backend, body, part.stacked, x_panels)
         return jnp.take(out.reshape(-1), part.nnz_gather, axis=0)
     return jax.jit(apply_sddmm)
 
 
-def _stack_spmm_segments(plans, shards, n_shards) -> dict[str, np.ndarray]:
+def _stack_spmm_segments(plans, shards, n_shards,
+                         pad_row: int) -> dict[str, np.ndarray]:
     """Pad/stack each shard's §4.3 segment launch tables on the leading
     shard axis. Padding segments are inert: zero values scatter zeros
-    onto local row 0, pos −1 skips revaluation, and ranks stay unique
-    (``arange``) so the Pallas kernel writes every padded output slot."""
+    onto local row ``pad_row`` (the last, so rows stay non-decreasing)
+    and pos −1 skips revaluation."""
     seg_list = [_spmm_segment_arrays(p) for p in plans]
     out: dict[str, np.ndarray] = {}
     if "tc_seg_vals" in seg_list[0]:
-        ns = max(s["tc_seg_rank"].shape[0] for s in seg_list)
+        ns = max(s["tc_seg_vals"].shape[0] for s in seg_list)
         wbk = seg_list[0]["tc_seg_vals"].shape[-1]
         vals = np.zeros((n_shards, ns, WINDOW, wbk), np.float32)
         cols = np.zeros((n_shards, ns, wbk), np.int32)
         pos = np.full((n_shards, ns, WINDOW, wbk), -1, np.int32)
-        row = np.zeros((n_shards, ns * WINDOW), np.int32)
+        row = np.full((n_shards, ns * WINDOW), pad_row, np.int32)
         for p, (s, sh) in enumerate(zip(seg_list, shards)):
-            k = s["tc_seg_rank"].shape[0]
+            k = s["tc_seg_vals"].shape[0]
             vals[p, :k] = s["tc_seg_vals"]
             cols[p, :k] = s["tc_seg_cols"]
             pos[p, :k] = _offset_pos(s["tc_seg_pos"], sh.nnz_start)
             row[p, :k * WINDOW] = s["tc_seg_row"]
-        rank = np.broadcast_to(np.arange(ns, dtype=np.int32),
-                               (n_shards, ns)).copy()
         out.update(tc_seg_vals=vals, tc_seg_cols=cols, tc_seg_pos=pos,
-                   tc_seg_row=row, tc_seg_rank=rank)
+                   tc_seg_row=row)
     if "vpu_seg_vals" in seg_list[0]:
         ns = max(s["vpu_seg_row"].shape[0] for s in seg_list)
         w = seg_list[0]["vpu_seg_vals"].shape[-1]
         vals = np.zeros((n_shards, ns, w), np.float32)
         cols = np.zeros((n_shards, ns, w), np.int32)
         pos = np.full((n_shards, ns, w), -1, np.int32)
-        row = np.zeros((n_shards, ns), np.int32)
+        row = np.full((n_shards, ns), pad_row, np.int32)
         for p, (s, sh) in enumerate(zip(seg_list, shards)):
             k = s["vpu_seg_row"].shape[0]
             vals[p, :k] = s["vpu_seg_vals"]
@@ -516,10 +504,12 @@ def partition_spmm(a: SparseCSR, n_shards: int, *, mode=UNSET,
     tc_cols = np.zeros((n_shards, nb, bk_c), np.int32)
     tc_rank = np.zeros((n_shards, nb), np.int32)
     tc_pos = np.full((n_shards, nb, WINDOW, bk_c), -1, np.int32)
-    tc_active_row = np.zeros((n_shards, na * WINDOW), np.int32)
+    # Row maps pad with the last local row: padding adds zeros there and
+    # keeps every map non-decreasing (the combine's sorted scatters).
+    tc_active_row = np.full((n_shards, na * WINDOW), rows_pad - 1, np.int32)
     vpu_vals = np.zeros((n_shards, nt, ts_c), np.float32)
     vpu_cols = np.zeros((n_shards, nt, ts_c), np.int32)
-    vpu_row = np.zeros((n_shards, nt), np.int32)
+    vpu_row = np.full((n_shards, nt), rows_pad - 1, np.int32)
     vpu_pos = np.full((n_shards, nt, ts_c), -1, np.int32)
     halo_arr = np.zeros((n_shards, hmax), np.int32)
 
@@ -561,7 +551,7 @@ def partition_spmm(a: SparseCSR, n_shards: int, *, mode=UNSET,
         tc_active_row=tc_active_row, tc_pos=tc_pos,
         vpu_vals=vpu_vals, vpu_cols=vpu_cols, vpu_row=vpu_row,
         vpu_pos=vpu_pos, halo=halo_arr)
-    host.update(_stack_spmm_segments(plans, shards, n_shards))
+    host.update(_stack_spmm_segments(plans, shards, n_shards, rows_pad - 1))
     stacked = {k: jnp.asarray(v) for k, v in host.items()}
     meta = {
         "balance": balance_report(

@@ -28,11 +28,24 @@ import math
 import threading
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 MODEL_AXIS = "model"
 
 _ctx = threading.local()
+
+
+def auto_axes(mesh: Mesh) -> Mesh:
+    """The same devices and axis names with every axis ``Auto``.
+
+    These rules place tensors with GSPMD sharding constraints, which
+    only ``Auto`` axes accept; ``jax.make_mesh`` builds ``Explicit``
+    ones by default.
+    """
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 # ----------------------------------------------------------- mesh axes ---

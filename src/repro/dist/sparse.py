@@ -49,12 +49,14 @@ Every public entry point here is traceable — it can sit under an outer
 """
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.api import ExecSpec, resolve_spec
+from repro.dist.sharding import auto_axes
 from repro.core.spmm import LibraSpMM
 from repro.core.sddmm import LibraSDDMM
 from repro.kernels import ref
@@ -72,6 +74,14 @@ SHARD_AXIS = "shards"
 _LAYOUTS = ("replicated", "rowshard")
 
 
+def place_partition(part, mesh: Mesh, axis: str = SHARD_AXIS):
+    """The partition with shard ``i``'s plan resident on device ``i`` of
+    the mesh axis, so no apply re-sends plans from one device."""
+    sharding = NamedSharding(auto_axes(mesh), P(axis))
+    return dataclasses.replace(part, stacked={
+        k: jax.device_put(v, sharding) for k, v in part.stacked.items()})
+
+
 def _local(stacked: dict) -> tuple[dict, jnp.ndarray]:
     """Strip the length-1 shard axis shard_map leaves on each block and
     split off the halo map."""
@@ -82,8 +92,7 @@ def _local(stacked: dict) -> tuple[dict, jnp.ndarray]:
 def spmm_sharded(part: SpMMPartition, b: jnp.ndarray, *, mesh: Mesh,
                  axis: str = SHARD_AXIS, backend: str = "xla",
                  edge_vals: jnp.ndarray | None = None,
-                 b_layout: str = "replicated",
-                 interpret: bool = True) -> jnp.ndarray:
+                 b_layout: str = "replicated") -> jnp.ndarray:
     """C = A @ B over a mesh axis; each device applies its shard's plan.
 
     ``edge_vals`` (canonical global nnz order, replicated) revalues
@@ -107,9 +116,12 @@ def spmm_sharded(part: SpMMPartition, b: jnp.ndarray, *, mesh: Mesh,
         b_halo = jnp.take(b_full, halo, axis=0)
         if ev:
             local = ref.revalue_spmm_arrays(local, ev[0])
-        return spmm_apply(local, b_halo, m=part.rows_pad, nwin=part.wmax,
-                          backend=backend, cfg=part.run_cfg,
-                          interpret=interpret)
+        out = spmm_apply(local, b_halo, m=part.rows_pad, nwin=part.wmax,
+                         backend=backend, cfg=part.run_cfg)
+        # Reassemble C from the (P * rows_pad, n) shard panels inside
+        # the body, so the gather never indexes a sharded operand.
+        out = jax.lax.all_gather(out, axis, axis=0, tiled=True)
+        return jnp.take(out, part.out_gather, axis=0)
 
     spec_plan = {k: P(axis) for k in part.stacked}
     in_specs = [spec_plan, P(axis) if rowshard else P()]
@@ -117,16 +129,16 @@ def spmm_sharded(part: SpMMPartition, b: jnp.ndarray, *, mesh: Mesh,
     if edge_vals is not None:
         in_specs.append(P())
         args.append(edge_vals)
-    fn = shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                   out_specs=P(axis), check_rep=False)
-    out = fn(*args)                       # (P * rows_pad, n)
-    return jnp.take(out, part.out_gather, axis=0)
+    fn = jax.shard_map(body, mesh=auto_axes(mesh),
+                       in_specs=tuple(in_specs), out_specs=P(),
+                       check_vma=False)
+    return fn(*args)
 
 
 def sddmm_sharded(part: SDDMMPartition, x: jnp.ndarray, y: jnp.ndarray, *,
                   mesh: Mesh, axis: str = SHARD_AXIS,
-                  backend: str = "xla", y_layout: str = "replicated",
-                  interpret: bool = True) -> jnp.ndarray:
+                  backend: str = "xla", y_layout: str = "replicated"
+                  ) -> jnp.ndarray:
     """values = sample(X·Yᵀ, sparsity(A)) over a mesh axis, canonical
     global nnz order.
 
@@ -146,17 +158,17 @@ def sddmm_sharded(part: SDDMMPartition, x: jnp.ndarray, y: jnp.ndarray, *,
         y_full = (jax.lax.all_gather(y_in, axis, axis=0, tiled=True)
                   if rowshard else y_in)
         y_halo = jnp.take(y_full, halo, axis=0)
-        return sddmm_apply(local, x_in, y_halo, nnz=part.nnz_pad,
-                           backend=backend, cfg=part.run_cfg,
-                           interpret=interpret)
+        out = sddmm_apply(local, x_in, y_halo, nnz=part.nnz_pad,
+                          backend=backend, cfg=part.run_cfg)
+        out = jax.lax.all_gather(out, axis, axis=0, tiled=True)
+        return jnp.take(out, part.nnz_gather, axis=0)
 
     spec_plan = {k: P(axis) for k in part.stacked}
     in_specs = (spec_plan, P(axis), P(axis) if rowshard else P())
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                   out_specs=P(axis), check_rep=False)
-    out = fn(part.stacked, x_panels,
-             _pad_to(y, 0, part.n_shards) if rowshard else y)
-    return jnp.take(out.reshape(-1), part.nnz_gather, axis=0)
+    fn = jax.shard_map(body, mesh=auto_axes(mesh), in_specs=in_specs,
+                       out_specs=P(), check_vma=False)
+    return fn(part.stacked, x_panels,
+              _pad_to(y, 0, part.n_shards) if rowshard else y)
 
 
 # ----------------------------------------------------------- batched ---
@@ -173,7 +185,6 @@ class BatchedSpMM:
         self._cache: dict = {}
 
     def __call__(self, b_stack: jnp.ndarray, backend: str = "xla",
-                 interpret: bool = True,
                  edge_vals: jnp.ndarray | None = None) -> jnp.ndarray:
         """Apply the plan to every panel; ``edge_vals`` — optional
         ``(batch, nnz)`` canonical per-panel values — revalues the plan
@@ -186,7 +197,6 @@ class BatchedSpMM:
         def batched(arrs, bb, *ev):
             out = spmm_apply_stack(arrs, bb, m=op.m, nwin=op.nwin,
                                    backend=backend, cfg=op.tune_config,
-                                   interpret=interpret,
                                    edge_vals=ev[0] if ev else None)
             if unperm is not None:   # reordered plan: restore row order
                 out = jnp.take(out, unperm, axis=1)
@@ -198,7 +208,7 @@ class BatchedSpMM:
         args = (arrs, b_stack) + ((edge_vals,) if has_ev else ())
         fn = cached_compile(
             self._cache,
-            (b_stack.shape, str(b_stack.dtype), backend, interpret, has_ev),
+            (b_stack.shape, str(b_stack.dtype), backend, has_ev),
             lambda: jax.jit(batched).lower(*args))
         return fn(*args)
 
@@ -217,8 +227,7 @@ class BatchedSDDMM:
         self._cache: dict = {}
 
     def __call__(self, x_stack: jnp.ndarray, y_stack: jnp.ndarray,
-                 backend: str = "xla", interpret: bool = True
-                 ) -> jnp.ndarray:
+                 backend: str = "xla") -> jnp.ndarray:
         op = self.op
         assert x_stack.ndim == 3 and y_stack.ndim == 3
         perm = op._row_perm
@@ -230,14 +239,12 @@ class BatchedSDDMM:
             if perm is not None:   # reordered plan: permute the X rows
                 xx = jnp.take(xx, perm, axis=1)
             return sddmm_apply_stack(arrs, xx, yy, nnz=op.nnz,
-                                     backend=backend, cfg=op.tune_config,
-                                     interpret=interpret)
+                                     backend=backend, cfg=op.tune_config)
 
         arrs = op.arrays.for_backend(backend)
         fn = cached_compile(
             self._cache,
-            (x_stack.shape, y_stack.shape, str(x_stack.dtype), backend,
-             interpret),
+            (x_stack.shape, y_stack.shape, str(x_stack.dtype), backend),
             lambda: jax.jit(batched).lower(arrs, x_stack, y_stack))
         return fn(arrs, x_stack, y_stack)
 
@@ -262,13 +269,13 @@ class ShardedSpMM:
             spec = resolve_spec(spec, "ShardedSpMM", **part_kwargs)
         spec = ExecSpec() if spec is None else spec
         self.spec = spec
-        self.part = (a if isinstance(a, SpMMPartition)
-                     else partition_spmm(a, int(mesh.shape[axis]),
-                                         spec=spec, timer=timer))
+        self.part = place_partition(
+            a if isinstance(a, SpMMPartition)
+            else partition_spmm(a, int(mesh.shape[axis]), spec=spec,
+                                timer=timer), mesh, axis)
         assert int(mesh.shape[axis]) == self.part.n_shards
         self.mesh, self.axis = mesh, axis
         self.backend, self.b_layout = spec.backend, spec.b_layout
-        self.interpret = spec.interpret
         self.m, self.k, self.nnz = self.part.m, self.part.k, self.part.nnz
         self._cache: dict = {}
 
@@ -285,8 +292,7 @@ class ShardedSpMM:
             return spmm_sharded(self.part, bb, mesh=self.mesh,
                                 axis=self.axis, backend=self.backend,
                                 edge_vals=ev[0] if ev else None,
-                                b_layout=self.b_layout,
-                                interpret=self.interpret)
+                                b_layout=self.b_layout)
 
         args = (b,) + ((edge_vals,) if has_ev else ())
         exe = cached_compile(self._cache, (b.shape, str(b.dtype), has_ev),
@@ -307,13 +313,13 @@ class ShardedSDDMM:
             spec = resolve_spec(spec, "ShardedSDDMM", **part_kwargs)
         spec = ExecSpec() if spec is None else spec
         self.spec = spec
-        self.part = (a if isinstance(a, SDDMMPartition)
-                     else partition_sddmm(a, int(mesh.shape[axis]),
-                                          spec=spec, timer=timer))
+        self.part = place_partition(
+            a if isinstance(a, SDDMMPartition)
+            else partition_sddmm(a, int(mesh.shape[axis]), spec=spec,
+                                 timer=timer), mesh, axis)
         assert int(mesh.shape[axis]) == self.part.n_shards
         self.mesh, self.axis = mesh, axis
         self.backend, self.y_layout = spec.backend, spec.b_layout
-        self.interpret = spec.interpret
         self.m, self.k, self.nnz = self.part.m, self.part.k, self.part.nnz
         self._cache: dict = {}
 
@@ -327,8 +333,7 @@ class ShardedSDDMM:
         def fn(xx, yy):
             return sddmm_sharded(self.part, xx, yy, mesh=self.mesh,
                                  axis=self.axis, backend=self.backend,
-                                 y_layout=self.y_layout,
-                                 interpret=self.interpret)
+                                 y_layout=self.y_layout)
 
         exe = cached_compile(self._cache,
                              (x.shape, y.shape, str(x.dtype)),

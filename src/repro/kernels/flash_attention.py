@@ -27,6 +27,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.gather import default_interpret
+
 NEG = -1e30
 
 
@@ -78,7 +80,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 def flash_attention_fused(q, k, v, *, causal: bool = True,
                           window: int = 0, softcap: float = 0.0,
                           bq: int = 512, bk: int = 512,
-                          interpret: bool = True):
+                          interpret: bool | None = None):
     """q: (B, Sq, H, D); k, v: (B, Sk, KV, D). Returns (B, Sq, H, D).
 
     window == 0 disables the sliding-window constraint.
@@ -120,7 +122,7 @@ def flash_attention_fused(q, k, v, *, causal: bool = True,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(qt, kt, vt)
     out = out[:, :sq].reshape(b, h, sq, d)
     return jnp.moveaxis(out, 1, 2)

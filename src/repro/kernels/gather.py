@@ -1,34 +1,70 @@
-"""Shared masked panel-gather for the streamed-operand kernels.
+"""Shared id-driven row fetch for the four sparse kernels.
 
-All four kernels stream one dense operand (B rows for SpMM, Y rows for
-SDDMM) through VMEM in row panels and fetch the rows a block/tile needs
-with one batched ``take`` on the resident panel. Rows whose global id
-lives in another panel are masked to zero — each id belongs to exactly
-one panel, so summing the per-panel partials counts every contribution
-exactly once. This module is the single home of that exactly-once
-accounting (clamp + mask semantics), so a Mosaic-era change to the
-gather idiom lands in one place (see the ROADMAP hardware item).
+Every kernel multiplies against rows of one dense operand (B rows for
+SpMM, X/Y rows for SDDMM) named by the plan's column or row ids. The
+operand stays in HBM (``memory_space=pl.ANY``); the ids of the current
+grid step sit in SMEM, and the step DMAs exactly the rows it names into
+a VMEM scratch before computing. B traffic therefore scales with the
+plan's padded nnz, not with ``k`` — no k-panel sweep, no in-kernel
+gather on a resident panel. Each (id, lane tile) pair is copied once
+per grid step, so the exactly-once accounting is the plan's: padding
+ids carry zero values (SpMM) or are routed to the combine's swallow
+slot (SDDMM).
+
+The dense operand is viewed as ``(rows, 1, width)`` (:func:`row_view`)
+so one row is a whole trailing ``(1, width)`` tile slice — a single-row
+slice of an ``(8, 128)``-tiled 2-D HBM array is refused by Mosaic.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def panel_gather(panel_ref, ids, panel_idx):
-    """Gather ``ids`` rows from the resident row panel, zero-masked.
+def default_interpret(interpret: bool | None) -> bool:
+    """Compiled kernels on a TPU, the Pallas interpreter elsewhere;
+    an explicit ``interpret`` wins (a described-chip compile passes
+    ``False`` from a CPU host)."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
 
-    Args:
-      panel_ref: Pallas ref of the resident ``(tile, lanes)`` panel —
-        panel ``panel_idx`` of the full operand.
-      ids: (g,) i32 *global* row ids to fetch.
-      panel_idx: current panel index along the streamed grid dimension.
 
-    Returns:
-      ``(rows, in_panel)``: (g, lanes) rows with out-of-panel rows
-      zeroed, and the (g,) bool residency mask.
-    """
-    tile = panel_ref.shape[0]
-    local = ids - panel_idx * tile
-    in_panel = (local >= 0) & (local < tile)
-    rows = jnp.take(panel_ref[...], jnp.clip(local, 0, tile - 1), axis=0)
-    return jnp.where(in_panel[:, None], rows, 0.0), in_panel
+def row_view(x: jnp.ndarray) -> jnp.ndarray:
+    """``(rows, width)`` → ``(rows, 1, width)`` for row-granular DMA."""
+    return x.reshape(x.shape[0], 1, x.shape[1])
+
+
+def fetch_rows(src, ids, dst_of, sem, lanes) -> None:
+    """DMA ``src[ids[..., g, w], :, lanes]`` into ``dst_of(g, w)`` for
+    every entry of the SMEM id block ``ids`` (shape ``(G, W)``, or
+    ``(1, G, W)`` for a one-segment block), then wait for all of them.
+    Ids are clamped into ``src`` so a corrupt plan can never address
+    past the operand."""
+    *lead, g_n, w_n = ids.shape
+    lead = (0,) * len(lead)
+    hi = src.shape[0] - 1
+
+    def start(t, carry):
+        g, w = t // w_n, t % w_n
+        row = jnp.minimum(jnp.maximum(ids[lead + (g, w)], 0), hi)
+        pltpu.make_async_copy(src.at[row, :, lanes], dst_of(g, w),
+                              sem).start()
+        return carry
+
+    def wait(t, carry):
+        # Every copy has the same size, so any same-shaped descriptor
+        # waits off one completion.
+        pltpu.make_async_copy(src.at[0, :, lanes], dst_of(0, 0),
+                              sem).wait()
+        return carry
+
+    jax.lax.fori_loop(0, g_n * w_n, start, 0)
+    jax.lax.fori_loop(0, g_n * w_n, wait, 0)
+
+
+def lane_tile(j, width: int):
+    """Lane slice of the ``j``-th ``width``-wide tile (aligned hint)."""
+    return pl.ds(pl.multiple_of(j * width, width), width)

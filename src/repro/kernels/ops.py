@@ -1,48 +1,40 @@
 """Jit'd wrappers around the Pallas kernels + the single-pass hybrid combine.
 
-``backend="pallas"`` runs the TPU kernels (interpret mode on CPU — the
-correctness substrate); ``backend="xla"`` runs the pure-jnp oracles from
-:mod:`repro.kernels.ref` (the fast path on CPU and the baseline the
-kernels are validated against). All padding (N → multiple of the lane
-tile, K → multiple of the k-tile, M → multiple of the window) happens here
-so kernels stay hardware-aligned (MXU multiples of 128 lanes / 8 sublanes).
+``backend="pallas"`` runs the TPU kernels (compiled on a TPU; the Pallas
+interpreter elsewhere — the CPU correctness substrate); ``backend="xla"``
+runs the pure-jnp oracles from :mod:`repro.kernels.ref` (the fast path on
+CPU and the baseline the kernels are validated against). All padding
+(N → multiple of the lane tile, features → multiple of the feature tile,
+M → multiple of the window) happens here so kernels stay hardware-aligned
+(MXU multiples of 128 lanes / 8 sublanes).
 
 Kernel architecture (single-pass fused hybrid)
 ----------------------------------------------
 
 The hybrid overhead the paper drives to zero (§4.4–4.5) is re-introduced
 whenever the two streams materialize redundant output or combine in extra
-passes. The apply path here makes exactly one pass over every output byte:
+passes:
 
-1. **Compacted TC layout.** Preprocessing ranks the windows that have TC
-   work (``TCBlocks.rank`` / ``TCBlocks.active_win``); ``spmm_mxu`` writes
-   a ``(n_active, 8, n)`` partial instead of a dense zero-initialized
-   ``(nwin, 8, n)`` buffer. ``tc_active_row`` maps compacted rows back to
-   rows of C.
-2. **k-tiled B streaming.** Both SpMM kernels walk B in ``(kt, nt)``
-   VMEM panels (third grid dimension, accumulator carried on the
-   revisited output block), so k is unbounded by VMEM.
-3. **Vectorized gathers.** All four kernels fetch their B/X/Y rows with
-   batched ``take`` formulations on the resident panel — no per-row
-   scalar DMA loops.
-0. **Tuned tiling.** Every tile-size / grid-order decision (``kt``,
-   ``nt``, ``kf_tile``, ``yt``, ``grid_order``) arrives as one static
-   :class:`repro.tune.model.TuneConfig` — emitted by the occupancy-aware
-   tuner in :mod:`repro.tune` (or its defaults when callers pass
-   nothing). No module constants.
-4. **Fused combine epilogue.** VPU residual tiles are row-sorted at
-   preprocess time, and the TC scatter + VPU segment reduction + the
-   TC/VPU add collapse into ONE ``scatter-add`` of the concatenated
-   partials into a single zero-initialized C — the TPU-deterministic
-   analogue of the paper's atomicAdd combine, touching each output byte
-   once. SDDMM likewise combines both streams' scores with a single
-   scatter into the canonical nnz vector.
-5. **Segment-granular launch (§4.3).** Plans carrying the hybrid
+1. **Id-driven row fetch.** The dense operands stay in HBM; every grid
+   step DMAs exactly the rows its column/row ids name into VMEM
+   (:mod:`repro.kernels.gather`), so operand traffic scales with the
+   plan's padded non-zeros, not with ``k`` — there is no k-panel sweep.
+2. **Tuned tiling.** Every lane-tile / segment-cap / grid-order decision
+   arrives as one static :class:`repro.tune.model.TuneConfig` — emitted
+   by the occupancy-aware tuner in :mod:`repro.tune` (or its defaults
+   when callers pass nothing). No module constants.
+3. **Fused combine epilogue.** The TC partials sum into their windows
+   of C and the row-sorted VPU partials scatter-add over them — the
+   TPU-deterministic analogue of the paper's atomicAdd combine. Both are
+   sorted scatters (plans keep windows and rows non-decreasing). SDDMM
+   combines both streams' scores with a single scatter into the
+   canonical nnz vector.
+4. **Segment-granular launch (§4.3).** Plans carrying the hybrid
    balancer's Ts/Cs launch tables (``*_seg_*`` device arrays — the
    default) run the kernels one *segment* per grid step: bounded work
-   per step no matter how skewed the matrix, and the scatter epilogue
-   is exactly where atomic segments (decomposed windows/rows, shared
-   windows) combine while non-atomic ones degenerate to stores.
+   per step no matter how skewed the matrix, and the combine is exactly
+   where atomic segments (decomposed windows/rows, shared windows)
+   accumulate while non-atomic ones degenerate to stores.
    ``TuneConfig(ts=0, cs=0)`` falls back to the per-block/per-tile
    launch.
 """
@@ -167,155 +159,153 @@ def _pad_to(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
     static_argnames=("m", "nwin", "backend", "cfg", "interpret"),
 )
 def spmm_apply(arrs, b, *, m: int, nwin: int, backend: str = "xla",
-               cfg: TuneConfig | None = None, interpret: bool = True):
+               cfg: TuneConfig | None = None, interpret: bool | None = None):
     """Hybrid SpMM: C[m, n] = A_sp @ B using a preprocessed Libra plan.
 
     ``cfg`` carries every tile-size / grid-order decision (a
     :class:`repro.tune.model.TuneConfig`); callers that pass nothing get
     the library default — module constants no longer exist.
+    ``interpret`` defaults to compiled kernels on a TPU and the Pallas
+    interpreter elsewhere.
     """
     cfg = DEFAULT_TUNE if cfg is None else cfg
     n0 = b.shape[1]
     if backend == "xla":
         return ref.spmm_hybrid_ref(arrs, b, m, nwin)
     nt = cfg.nt
-    ktile = min(cfg.kt, b.shape[0])
-    b_p = _pad_to(_pad_to(b, 1, nt), 0, ktile)
+    b_p = _pad_to(b, 1, nt)
     if "tc_seg_vals" in arrs:
         # Segment-granular launch (§4.3 Ts decomposition): one grid step
-        # per segment of ≤ ts blocks of one window; every segment owns
-        # its own compacted output slot, so any grid order is legal.
-        nseg = arrs["tc_seg_rank"].shape[0]
-        tc = spmm_mxu(arrs["tc_seg_vals"], arrs["tc_seg_cols"],
-                      arrs["tc_seg_rank"], b_p, n_active=nseg, nt=nt,
-                      kt=ktile, grid_order=cfg.grid_order,
-                      unique_ranks=True, interpret=interpret)
-        tc_rows = arrs["tc_seg_row"]
+        # per segment of ≤ ts blocks of one window.
+        tc = spmm_mxu(arrs["tc_seg_vals"], arrs["tc_seg_cols"], b_p, nt=nt,
+                      grid_order=cfg.grid_order, interpret=interpret)
+        tc_win = arrs["tc_seg_row"][::WINDOW] // WINDOW
     else:
-        n_active = arrs["tc_active_row"].shape[0] // WINDOW
-        # block_outer is only legal with one TC block per compacted rank
-        # (see spmm_mxu docstring); downgrade silently otherwise — the
-        # shapes are static here, so this costs nothing at runtime.
-        nb = arrs["tc_vals"].shape[0]
-        order = cfg.grid_order if nb == n_active else "n_outer"
-        tc = spmm_mxu(arrs["tc_vals"], arrs["tc_cols"], arrs["tc_rank"],
-                      b_p, n_active=n_active, nt=nt, kt=ktile,
-                      grid_order=order, interpret=interpret)
-        tc_rows = arrs["tc_active_row"]
+        # Per-block launch: each block's 8 output rows are its window's
+        # (compacted rank → window); blocks sharing a window add up in
+        # the combine below.
+        tc = spmm_mxu(arrs["tc_vals"], arrs["tc_cols"], b_p, nt=nt,
+                      grid_order=cfg.grid_order, interpret=interpret)
+        tc_win = (arrs["tc_active_row"][::WINDOW] // WINDOW)[arrs["tc_rank"]]
     if "vpu_seg_vals" in arrs:
-        # §4.3 Cs decomposition: one grid step per row-segment of ≤ cs
-        # residual elements (same kernel, wider tiles).
+        # §4.3 Cs decomposition: one row-segment of ≤ cs residual
+        # elements per tile (same kernel, wider tiles).
         partials = spmm_vpu(arrs["vpu_seg_vals"], arrs["vpu_seg_cols"],
-                            b_p, nt=nt, kt=ktile,
-                            grid_order=cfg.grid_order, interpret=interpret)
+                            b_p, nt=nt, grid_order=cfg.grid_order,
+                            interpret=interpret)
         vpu_rows = arrs["vpu_seg_row"]
     else:
         partials = spmm_vpu(arrs["vpu_vals"], arrs["vpu_cols"], b_p, nt=nt,
-                            kt=ktile, grid_order=cfg.grid_order,
-                            interpret=interpret)
+                            grid_order=cfg.grid_order, interpret=interpret)
         vpu_rows = arrs["vpu_row"]
-    # Fused combine epilogue: one scatter-add of both streams' partials
-    # into a single zero-initialized C (rows ≥ m from the padded last
-    # window are sliced off; TC rows of empty-TC plans add only zeros).
-    # Under the segmented launch this is where atomic segments combine:
-    # non-atomic segments own their rows exclusively (the add is a
-    # store), atomic ones — decomposed windows/rows or TC∩VPU windows —
-    # deterministically accumulate in segment order, the TPU analogue of
-    # the paper's invoke-atomicAdd-only-when-necessary rule.
-    rows = jnp.concatenate([tc_rows, vpu_rows])
-    data = jnp.concatenate([tc, partials])
-    out = jnp.zeros((nwin * WINDOW, b_p.shape[1]), tc.dtype)
-    out = out.at[rows].add(data)
+    # Fused combine epilogue: the TC partials sum into their windows of
+    # a zero C, then one scatter-add lays the VPU partials over it (rows
+    # ≥ m from the padded last window are sliced off). Under the
+    # segmented launch this is where atomic segments combine: non-atomic
+    # segments own their rows exclusively (the add is a store), atomic
+    # ones — decomposed windows/rows or TC∩VPU windows — deterministically
+    # accumulate in segment order, the TPU analogue of the paper's
+    # invoke-atomicAdd-only-when-necessary rule. Plans keep block windows
+    # and VPU rows non-decreasing (padding repeats the last row), so both
+    # sums are sorted scatters — an unsorted one makes the TPU compiler
+    # sort the updates, tens of seconds of compile at graph scale.
+    out = jax.ops.segment_sum(
+        tc.reshape(-1, WINDOW, tc.shape[-1]), tc_win, num_segments=nwin,
+        indices_are_sorted=True).reshape(nwin * WINDOW, -1)
+    out = out.at[vpu_rows].add(partials, indices_are_sorted=True)
     return out[:m, :n0]
+
+
+def map_batch(backend: str, fn, *xs):
+    """``fn`` over the leading axis of ``xs``: ``vmap`` for the XLA
+    reference, a sequential ``lax.map`` for the Pallas kernels, whose
+    HBM-resident operands (``memory_space=pl.ANY``) cannot take the grid
+    axis ``vmap`` would add. Each element runs the single-call program
+    either way."""
+    if backend == "xla":
+        return jax.vmap(fn)(*xs)
+    return jax.lax.map(lambda a: fn(*a), xs)
 
 
 def spmm_apply_stack(arrs, b_stack, *, m: int, nwin: int,
                      backend: str = "xla", cfg: TuneConfig | None = None,
-                     interpret: bool = True,
                      edge_vals: jnp.ndarray | None = None) -> jnp.ndarray:
     """Panel-stack hybrid SpMM: one plan over a ``(batch, k, n)`` stack.
 
     The serving-shape primitive: a graph's plan is the amortized asset,
-    requests arrive as feature panels. ``vmap`` over the single fused
-    apply keeps per-panel results bitwise identical to looped single
-    applies (each batch element's compute graph is the single-panel
-    one), so bucketed serving can promise bit-identity with direct
-    operator calls. ``edge_vals`` — optional ``(batch, nnz)`` canonical
-    per-panel values — revalues the plan per panel inside the vmap (the
-    attention-serving path: pattern shared, values per request).
+    requests arrive as feature panels. Mapping the single fused apply
+    over the stack (:func:`map_batch`) keeps per-panel results bitwise
+    identical to looped single applies (each batch element's compute
+    graph is the single-panel one), so bucketed serving can promise
+    bit-identity with direct operator calls. ``edge_vals`` — optional
+    ``(batch, nnz)`` canonical per-panel values — revalues the plan per
+    panel inside the map (the attention-serving path: pattern shared,
+    values per request).
 
     Traceable; callers AOT-compile via :func:`cached_compile` (see
     :class:`repro.dist.sparse.BatchedSpMM` / the serve engine).
     """
     one = functools.partial(spmm_apply, m=m, nwin=nwin, backend=backend,
-                            cfg=cfg, interpret=interpret)
+                            cfg=cfg)
     if edge_vals is None:
-        return jax.vmap(lambda bb: one(arrs, bb))(b_stack)
-    return jax.vmap(
-        lambda ev, bb: one(ref.revalue_spmm_arrays(arrs, ev), bb)
-    )(edge_vals, b_stack)
+        return map_batch(backend, lambda bb: one(arrs, bb), b_stack)
+    return map_batch(
+        backend, lambda ev, bb: one(ref.revalue_spmm_arrays(arrs, ev), bb),
+        edge_vals, b_stack)
 
 
 def sddmm_apply_stack(arrs, x_stack, y_stack, *, nnz: int,
-                      backend: str = "xla", cfg: TuneConfig | None = None,
-                      interpret: bool = True) -> jnp.ndarray:
+                      backend: str = "xla", cfg: TuneConfig | None = None
+                      ) -> jnp.ndarray:
     """Panel-stack hybrid SDDMM: ``(batch, m, kf) × (batch, k, kf) →
     (batch, nnz)`` — see :func:`spmm_apply_stack` for the contract."""
-    one = functools.partial(sddmm_apply, nnz=nnz, backend=backend,
-                            cfg=cfg, interpret=interpret)
-    return jax.vmap(lambda xx, yy: one(arrs, xx, yy))(x_stack, y_stack)
+    one = functools.partial(sddmm_apply, nnz=nnz, backend=backend, cfg=cfg)
+    return map_batch(backend, lambda xx, yy: one(arrs, xx, yy), x_stack,
+                     y_stack)
 
 
 @functools.partial(
     jax.jit, static_argnames=("nnz", "backend", "cfg", "interpret")
 )
 def sddmm_apply(arrs, x, y, *, nnz: int, backend: str = "xla",
-                cfg: TuneConfig | None = None, interpret: bool = True):
+                cfg: TuneConfig | None = None, interpret: bool | None = None):
     """Hybrid SDDMM: values[nnz] = sample(X @ Yᵀ) in canonical CSR order.
 
-    ``cfg.kf_tile`` tiles the feature dimension; ``cfg.yt`` streams Y in
-    row panels and ``cfg.xt`` streams X (VPU kernel) the same way —
-    padded here so panel counts divide evenly; padded rows are zeros and
-    no real row/column index points at them.
+    ``cfg.kf_tile`` tiles the feature dimension (padded here to whole
+    tiles — a lane-dense row is 128 wide in HBM regardless; padded
+    features are zeros).
     """
     cfg = DEFAULT_TUNE if cfg is None else cfg
     if backend == "xla":
         return ref.sddmm_hybrid_ref(arrs, _pad_to(x, 0, WINDOW), y, nnz)
-    kf = x.shape[1]
-    kf_tile = cfg.kf_tile
-    kt = min(kf_tile, kf) if kf % kf_tile else kf_tile
-    if kf % kt:
-        x = _pad_to(x, 1, kt)
-        y = _pad_to(y, 1, kt)
+    kft = cfg.kf_tile
+    x = _pad_to(x, 1, kft)
+    y = _pad_to(y, 1, kft)
     x_p = _pad_to(x, 0, WINDOW)
-    yt = None if cfg.yt is None else min(cfg.yt, y.shape[0])
-    y_p = y if yt is None else _pad_to(y, 0, yt)
-    xt = None if cfg.xt is None else min(cfg.xt, x.shape[0])
-    x_v = x if xt is None else _pad_to(x, 0, xt)
     if "tc_seg_cols" in arrs:
         # §4.3 Ts decomposition: one grid step scores a whole segment of
         # ≤ ts blocks sharing a window — one 8×kf @ kf×(ts·bk) dot,
         # bitmap-sampled (zero bitmap padding samples to zero and its
         # out_pos −1 lands in the scatter's swallow slot).
         s_tc = sddmm_mxu(arrs["tc_seg_cols"], arrs["tc_seg_bitmap"],
-                         arrs["tc_seg_window"], x_p, y_p, kf_tile=kt,
-                         yt=yt, interpret=interpret)
+                         arrs["tc_seg_window"], x_p, y, kf_tile=kft,
+                         interpret=interpret)
         tc_pos_src = arrs["tc_seg_out_pos"]
     else:
         s_tc = sddmm_mxu(arrs["tc_cols"], arrs["tc_bitmap"],
-                         arrs["tc_window"], x_p, y_p, kf_tile=kt, yt=yt,
+                         arrs["tc_window"], x_p, y, kf_tile=kft,
                          interpret=interpret)
         tc_pos_src = arrs["tc_out_pos"]
     if "vpu_seg_rows" in arrs:
         # Cs cap batches whole element tiles per VPU grid step.
         vpu_mask = arrs["vpu_seg_mask"]
-        s_el = sddmm_vpu(arrs["vpu_seg_rows"], arrs["vpu_seg_cols"], x_v,
-                         y_p, kf_tile=kt, yt=yt, xt=xt, interpret=interpret)
+        s_el = sddmm_vpu(arrs["vpu_seg_rows"], arrs["vpu_seg_cols"], x, y,
+                         kf_tile=kft, interpret=interpret)
         el_pos_src = arrs["vpu_seg_out_pos"]
     else:
         vpu_mask = arrs["vpu_mask"]
-        s_el = sddmm_vpu(arrs["vpu_rows"], arrs["vpu_cols"], x_v, y_p,
-                         kf_tile=kt, yt=yt, xt=xt, interpret=interpret)
+        s_el = sddmm_vpu(arrs["vpu_rows"], arrs["vpu_cols"], x, y,
+                         kf_tile=kft, interpret=interpret)
         el_pos_src = arrs["vpu_out_pos"]
     s_el = jnp.where(vpu_mask, s_el, 0.0)
     # Fused combine: one scatter of both streams into the canonical nnz

@@ -28,7 +28,8 @@ def spmm_tc_compact_ref(tc_vals, tc_cols, tc_rank, b, n_active):
     ``n_active → nwin``; the kernel no longer produces it.)"""
     gathered = jnp.take(b, tc_cols, axis=0)  # (nb, bk, n)
     partial = jnp.einsum("bsk,bkn->bsn", tc_vals, gathered)  # (nb, 8, n)
-    out = jax.ops.segment_sum(partial, tc_rank, num_segments=n_active)
+    out = jax.ops.segment_sum(partial, tc_rank, num_segments=n_active,
+                              indices_are_sorted=True)
     return out.reshape(n_active * WINDOW, b.shape[1])
 
 
@@ -36,21 +37,23 @@ def spmm_vpu_ref(vpu_vals, vpu_cols, vpu_row, b, m):
     """(nt,ts)×(nt,ts) → rows of (m, n)."""
     gathered = jnp.take(b, vpu_cols, axis=0)  # (nt, ts, n)
     partial = jnp.einsum("tj,tjn->tn", vpu_vals, gathered)  # (nt, n)
-    return jax.ops.segment_sum(partial, vpu_row, num_segments=m)
+    return jax.ops.segment_sum(partial, vpu_row, num_segments=m,
+                               indices_are_sorted=True)
 
 
 def spmm_hybrid_ref(arrs, b, m, nwin):
     """Single-pass hybrid reference mirroring the fused Pallas epilogue:
-    compacted TC partials + VPU tile partials → ONE scatter-add into C."""
+    compacted TC partials, then VPU tile partials, scatter-added into C.
+    Ranks, active rows and VPU rows are non-decreasing in every plan, so
+    each scatter is a sorted one."""
     tc_rows = arrs["tc_active_row"]
     tc = spmm_tc_compact_ref(arrs["tc_vals"], arrs["tc_cols"],
                              arrs["tc_rank"], b, tc_rows.shape[0] // WINDOW)
     gathered = jnp.take(b, arrs["vpu_cols"], axis=0)  # (nt, ts, n)
     partials = jnp.einsum("tj,tjn->tn", arrs["vpu_vals"], gathered)
-    rows = jnp.concatenate([tc_rows, arrs["vpu_row"]])
-    data = jnp.concatenate([tc, partials])
     out = jnp.zeros((nwin * WINDOW, b.shape[1]), tc.dtype)
-    return out.at[rows].add(data)[:m]
+    out = out.at[tc_rows].add(tc, indices_are_sorted=True)
+    return out.at[arrs["vpu_row"]].add(partials, indices_are_sorted=True)[:m]
 
 
 def bitmap_mask(bitmap):

@@ -7,26 +7,19 @@ tests its own bit of the 32-bit occupancy word, ``(bitmap >> sub) & 1``,
 which is the paper's per-thread ``(binary >> tid) & 1`` mapped onto the
 vector unit with zero divergence and no shared memory (§4.4, Fig. 8).
 
-Both operand dimensions stream through bounded VMEM panels (k-tiling
-symmetry with the SpMM kernels):
-
-* the **feature dimension** is tiled (``kf_tile``) with in-VMEM
-  accumulation, so arbitrarily wide embeddings fit;
-* **Y rows** stream in ``(yt, kf_tile)`` panels on a third grid
-  dimension — the ``BK`` rows of a block are fetched with one batched
-  ``take`` on the resident panel, rows outside the panel masked to
-  zero (each block column lives in exactly one panel, so the sum over
-  panels counts every score term once). Huge ``kcols`` masks no longer
-  require a whole-Y VMEM residency.
-
-The bitmap sample is applied once, on the final (feature, Y-panel)
-visit of the block's accumulator.
+Both dense operands stay in HBM. The step DMAs the block's 8-row X
+window and the ``BK`` Y rows its column ids (an SMEM block) name into
+VMEM (:func:`repro.kernels.gather.fetch_rows`), so Y traffic scales
+with the condensed non-zeros, not with ``kcols``. The feature dimension
+is tiled (``kf_tile``, the fastest grid axis) with the output block as
+the accumulator, so arbitrarily wide embeddings fit; the bitmap sample
+is applied on the last feature tile.
 
 **Segment-granular launch (§4.3 Ts decomposition).** The preferred
 operand layout is the hybrid balancer's segment table: one grid step
 scores a whole segment of ≤ ``Ts`` blocks sharing a window — ``bk``
 becomes ``ts·bk`` concatenated condensed vectors, the step is a single
-``8×kf @ kf×(ts·bk)`` dot, and the shared window's X panel is fetched
+``8×kf @ kf×(ts·bk)`` dot, and the shared window's X rows are fetched
 once per segment instead of once per block. Zero-bitmap cap padding
 samples to zero and its ``out_pos`` −1 lands in the combine's swallow
 slot, so the kernel body is layout-agnostic (this docstring's "block"
@@ -42,86 +35,78 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.formats import WINDOW
-from repro.kernels.gather import panel_gather
+from repro.kernels.gather import (default_interpret, fetch_rows, lane_tile,
+                                  row_view)
 
 
-def _kernel(window_ref, cols_ref, bitmap_ref, x_ref, y_ref, out_ref):
-    f = pl.program_id(1)   # feature tile index
-    kk = pl.program_id(2)  # Y row-panel index (fastest)
-    bk = cols_ref.shape[1]
-
-    # Batched gather of BK rows of Y from the resident (yt, kft) panel;
-    # rows living in another panel contribute zero this step.
-    gathered, _ = panel_gather(y_ref, cols_ref[0], kk)     # (bk, kft)
+def _kernel(cols_ref, win_ref, bitmap_ref, x_hbm, y_hbm, out_ref, xw, rows,
+            sem):
+    f = pl.program_id(1)   # feature tile index (fastest)
+    bk, _, kft = rows.shape
+    lanes = lane_tile(f, kft)
+    win_copy = pltpu.make_async_copy(x_hbm.at[win_ref[0, 0, 0], :, lanes], xw,
+                                     sem.at[1])
+    win_copy.start()
+    fetch_rows(y_hbm, cols_ref, lambda g, w: rows.at[w], sem.at[0], lanes)
+    win_copy.wait()
 
     # 8×KFt @ KFt×BK on the MXU.
-    s = jax.lax.dot_general(
-        x_ref[0],
-        gathered,
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    s = jax.lax.dot_general(xw[...], rows[...].reshape(bk, kft),
+                            (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
 
-    first = jnp.logical_and(f == 0, kk == 0)
-    last = jnp.logical_and(f == pl.num_programs(1) - 1,
-                           kk == pl.num_programs(2) - 1)
-
-    @pl.when(first)
+    @pl.when(f == 0)
     def _():
-        out_ref[...] = jnp.zeros_like(out_ref)
+        out_ref[0] = s
 
-    @pl.when(last)
+    @pl.when(f != 0)
     def _():
-        # Bit-Decoding sample on the final accumulation: sublane r keeps
-        # column j iff bit r of bitmap[j] is set.
-        sub = jax.lax.broadcasted_iota(jnp.uint32, (WINDOW, bk), 0)
-        bits = (bitmap_ref[0][None, :].astype(jnp.uint32) >> sub) & jnp.uint32(1)
-        out_ref[...] = jnp.where(bits > 0, out_ref[0] + s, 0.0)[None]
+        out_ref[0] += s
 
-    @pl.when(jnp.logical_not(last))
+    @pl.when(f == pl.num_programs(1) - 1)
     def _():
-        out_ref[...] += s[None]
+        # Bit-Decoding sample: sublane r keeps column j iff bit r of
+        # bitmap[j] is set.
+        sub = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape[1:], 0)
+        bits = (bitmap_ref[0] >> sub) & 1
+        out_ref[0] = jnp.where(bits > 0, out_ref[0], 0.0)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("kf_tile", "yt", "interpret"))
+@functools.partial(jax.jit, static_argnames=("kf_tile", "interpret"))
 def sddmm_mxu(tc_cols, tc_bitmap, tc_window, x, y, *, kf_tile: int = 128,
-              yt: int | None = None, interpret: bool = True):
+              interpret: bool | None = None):
     """Bitmap-sampled block scores, shape ``(nb, 8, bk)``.
 
     Args:
       tc_cols: (nb, bk) i32 sparse-block column indices.
       tc_bitmap: (nb, bk) u32 8-bit occupancy words.
       tc_window: (nb,) i32 window (row-block) ids.
-      x: (nwin*8, kf) dense rows; y: (kcols, kf) dense rows.
-      yt: Y rows resident per grid step (``None`` = all of Y resident);
-          ``kcols`` must be a multiple of ``yt`` (ops.py pads).
+      x: (nwin*8, kf) dense rows; y: (kcols, kf) dense rows; ``kf``
+         must be a multiple of ``kf_tile`` (ops.py pads).
     """
     nb, bk = tc_cols.shape
     kf = x.shape[1]
-    kcols = y.shape[0]
-    yt = kcols if yt is None else min(yt, kcols)
     assert kf % kf_tile == 0, (kf, kf_tile)
-    assert kcols % yt == 0, (kcols, yt)
-    grid = (nb, kf // kf_tile, kcols // yt)
     xw = x.reshape(-1, WINDOW, kf)
+    bitmap = tc_bitmap.astype(jnp.int32).reshape(nb, 1, bk)
 
-    out = pl.pallas_call(
+    return pl.pallas_call(
         _kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, bk), lambda i, f, kk, w: (i, 0)),
-                pl.BlockSpec((1, bk), lambda i, f, kk, w: (i, 0)),
-                pl.BlockSpec((1, WINDOW, kf_tile),
-                             lambda i, f, kk, w: (w[i], 0, f)),
-                pl.BlockSpec((yt, kf_tile), lambda i, f, kk, w: (kk, f)),
-            ],
-            out_specs=pl.BlockSpec((1, WINDOW, bk),
-                                   lambda i, f, kk, w: (i, 0, 0)),
-        ),
+        grid=(nb, kf // kf_tile),
+        in_specs=[
+            pl.BlockSpec((1, 1, bk), lambda i, f: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, 1), lambda i, f: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, bk), lambda i, f: (i, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, WINDOW, bk), lambda i, f: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, WINDOW, bk), jnp.float32),
-        interpret=interpret,
-    )(tc_window, tc_cols, tc_bitmap, xw, y)
-    return out
+        scratch_shapes=[pltpu.VMEM((WINDOW, kf_tile), jnp.float32),
+                        pltpu.VMEM((bk, 1, kf_tile), jnp.float32),
+                        pltpu.SemaphoreType.DMA((2,))],
+        interpret=default_interpret(interpret),
+    )(tc_cols.reshape(nb, 1, bk), tc_window.reshape(nb, 1, 1), bitmap, xw,
+      row_view(y))

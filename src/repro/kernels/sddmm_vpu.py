@@ -1,28 +1,21 @@
 """VPU SDDMM path as a Pallas TPU kernel.
 
-One grid step processes a tile of ``TS`` isolated non-zero elements:
-``s[j] = ⟨X[rows[j]], Y[cols[j]]⟩``. The ``TS`` X-rows and Y-rows of a
-tile are fetched with two batched ``take``s on the resident feature
-panels (vectorized gather — the paper's CUDA-core stream with Float4
-chunks → 128-lane VMEM rows here, but without the per-element scalar
-loop); the dot reduction runs on the VPU.
-
-Three streamed dimensions keep the working set bounded (k-tiling
-symmetry with SpMM, completed): the feature dimension is tiled
-(``kf_tile``) with in-VMEM accumulation, Y rows stream in
-``(yt, kf_tile)`` panels, and X rows stream in ``(xt, kf_tile)`` panels
-on a fourth grid dimension. An element contributes only on the one
-(X-panel, Y-panel) step where both of its rows are resident — on every
-other step at least one gathered row is masked to zero, so each element
-is counted exactly once across the sweep. No whole-operand VMEM
-residency remains.
+One tile is ``TS`` isolated non-zero elements:
+``s[j] = ⟨X[rows[j]], Y[cols[j]]⟩``. One grid step owns ``GROUP``
+(= 8, one sublane each) tiles and one feature tile: it DMAs the
+``GROUP · TS`` X rows and Y rows its ids (SMEM blocks) name from HBM
+into VMEM (:func:`repro.kernels.gather.fetch_rows`) and reduces their
+products on the VPU — the paper's CUDA-core stream with Float4 chunks →
+128-lane VMEM rows here. Operand traffic scales with the element count,
+not with ``m`` or ``kcols``; the feature dimension is tiled
+(``kf_tile``, the fastest grid axis) with the output block as the
+accumulator.
 
 **Segment-granular launch (§4.3 Cs cap).** SDDMM element tiles are
 flat (every score owns its canonical output slot — no atomicity), so
 the hybrid balancer's Cs cap simply batches ``cs/ts`` whole tiles per
-grid step (``ts`` becomes the segment width; mask-False padding rides
-the existing exactly-once accounting). Rows longer than ``cs`` were
-already split across tiles by construction.
+grid step (``ts`` becomes the segment width); mask-False padding is
+dropped by the caller's combine.
 """
 from __future__ import annotations
 
@@ -31,62 +24,65 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.gather import panel_gather
+from repro.kernels.gather import (default_interpret, fetch_rows, lane_tile,
+                                  row_view)
+
+GROUP = 8   # tiles per grid step, one per sublane
 
 
-def _kernel(rows_ref, cols_ref, x_ref, y_ref, out_ref):
-    f = pl.program_id(1)   # feature tile
-    kk = pl.program_id(2)  # Y row-panel index
-    xx = pl.program_id(3)  # X row-panel index (fastest)
+def _kernel(rows_ref, cols_ref, x_hbm, y_hbm, out_ref, xg, yg, sem):
+    f = pl.program_id(1)   # feature tile (fastest)
+    kft = xg.shape[3]
+    lanes = lane_tile(f, kft)
+    fetch_rows(x_hbm, rows_ref, lambda g, w: xg.at[w, g], sem, lanes)
+    fetch_rows(y_hbm, cols_ref, lambda g, w: yg.at[w, g], sem, lanes)
+    lane = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 1)
+    s = jnp.zeros(out_ref.shape, jnp.float32)              # (8, ts)
+    for w in range(out_ref.shape[1]):
+        prod = (xg[w] * yg[w]).reshape(GROUP, kft)
+        dot = jnp.sum(prod, axis=1, keepdims=True)         # (8, 1)
+        s = jnp.where(lane == w, dot, s)
 
-    xg, _ = panel_gather(x_ref, rows_ref[0], xx)                # (ts, kft)
-    yg, _ = panel_gather(y_ref, cols_ref[0], kk)                # (ts, kft)
-    partial = jnp.sum(xg * yg, axis=1)[None, :]                 # (1, ts)
-
-    first = jnp.logical_and(f == 0, jnp.logical_and(kk == 0, xx == 0))
-
-    @pl.when(first)
+    @pl.when(f == 0)
     def _():
-        out_ref[...] = partial
+        out_ref[...] = s
 
-    @pl.when(jnp.logical_not(first))
+    @pl.when(f != 0)
     def _():
-        out_ref[...] += partial
+        out_ref[...] += s
 
 
-@functools.partial(
-    jax.jit, static_argnames=("kf_tile", "yt", "xt", "interpret"))
+@functools.partial(jax.jit, static_argnames=("kf_tile", "interpret"))
 def sddmm_vpu(rows, cols, x, y, *, kf_tile: int = 128,
-              yt: int | None = None, xt: int | None = None,
-              interpret: bool = True):
+              interpret: bool | None = None):
     """Element scores, shape ``(ntiles, ts)`` (mask applied by the caller).
 
-    ``yt`` rows of Y and ``xt`` rows of X are resident per grid step
-    (``None`` = the whole operand); ``y.shape[0]`` must be a multiple of
-    ``yt`` and ``x.shape[0]`` of ``xt`` (ops.py pads both).
+    ``x.shape[1]`` (= ``y.shape[1]``) must be a multiple of ``kf_tile``
+    (ops.py pads).
     """
     ntiles, ts = rows.shape
-    mrows, kf = x.shape
-    kcols = y.shape[0]
-    yt = kcols if yt is None else min(yt, kcols)
-    xt = mrows if xt is None else min(xt, mrows)
+    kf = x.shape[1]
     assert kf % kf_tile == 0, (kf, kf_tile)
-    assert kcols % yt == 0, (kcols, yt)
-    assert mrows % xt == 0, (mrows, xt)
-    grid = (ntiles, kf // kf_tile, kcols // yt, mrows // xt)
+    pad = (-ntiles) % GROUP
+    if pad:
+        rows = jnp.pad(rows, ((0, pad), (0, 0)))
+        cols = jnp.pad(cols, ((0, pad), (0, 0)))
+    ngroups = (ntiles + pad) // GROUP
+    ids = pl.BlockSpec((GROUP, ts), lambda i, f: (i, 0),
+                       memory_space=pltpu.SMEM)
 
     out = pl.pallas_call(
         _kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, ts), lambda i, f, kk, xx: (i, 0)),
-            pl.BlockSpec((1, ts), lambda i, f, kk, xx: (i, 0)),
-            pl.BlockSpec((xt, kf_tile), lambda i, f, kk, xx: (xx, f)),
-            pl.BlockSpec((yt, kf_tile), lambda i, f, kk, xx: (kk, f)),
-        ],
-        out_specs=pl.BlockSpec((1, ts), lambda i, f, kk, xx: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((ntiles, ts), jnp.float32),
-        interpret=interpret,
-    )(rows, cols, x, y)
-    return out
+        grid=(ngroups, kf // kf_tile),
+        in_specs=[ids, ids, pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((GROUP, ts), lambda i, f: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((ngroups * GROUP, ts), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((ts, GROUP, 1, kf_tile), jnp.float32),
+                        pltpu.VMEM((ts, GROUP, 1, kf_tile), jnp.float32),
+                        pltpu.SemaphoreType.DMA(())],
+        interpret=default_interpret(interpret),
+    )(rows, cols, row_view(x), row_view(y))
+    return out[:ntiles]

@@ -1,62 +1,36 @@
 """MXU (Tensor-core analogue) SpMM path as a Pallas TPU kernel.
 
-One grid step multiplies a condensed ``8×BK`` TC block by ``BK`` gathered
-rows of one k-tile of the dense matrix B and accumulates into the block's
-*compacted* output window.
+One grid step multiplies one condensed ``8×BK`` TC block by the ``BK``
+rows of the dense matrix B its column ids name, for one ``nt``-lane
+tile of the output: an ``8×BK @ BK×nt`` MXU dot written to the block's
+own ``(8, nt)`` output slot.
 
-TPU adaptation of the paper's TCU stream (§4.4), single-pass edition:
+TPU adaptation of the paper's TCU stream (§4.4):
 
-* **Compacted output (TC-window rank map).** Preprocessing assigns every
-  block a dense ``rank`` over the windows that actually have TC work; the
-  kernel writes ``(n_active, 8, n)`` instead of ``(nwin, 8, n)``. On
-  hyper-sparse matrices (tc_ratio → 0) this eliminates nearly the whole
-  zero-initialized dense TC output — the redundant-output-traffic term the
-  paper drives to zero. The caller scatters the compacted rows into C with
-  the plan's ``tc_active_row`` map (fused with the VPU combine).
-* **k-tiled B streaming.** The grid has a dimension over k-tiles of
-  B (``kt`` rows per step) with VMEM accumulator carry on the revisited
-  output block, so only a ``(kt, nt)`` panel of B is ever resident —
-  large-k matrices (GNN feature dims, MoE dispatch) no longer need a
-  whole-``(k, nt)`` VMEM panel.
-* **Vectorized gather.** The per-block B-row gather is one batched
-  ``take`` on the resident k-tile (clamped indices + an in-tile mask zeroes
-  vectors whose source row lives in another k-tile), replacing the
-  scalar one-row-at-a-time ``fori_loop`` DMA of the previous revision.
+* **Id-driven row fetch.** B stays in HBM; the step DMAs exactly the
+  ``BK`` rows its column ids (an SMEM block) name into VMEM
+  (:func:`repro.kernels.gather.fetch_rows`). B traffic is
+  ``blocks · BK · nt`` per lane tile — it scales with the condensed
+  non-zeros, not with ``k``, and there is no k-panel grid axis.
+* **One output slot per block.** Every step writes its own ``(8, nt)``
+  block, so no output block is ever revisited and both grid orders are
+  always legal; the caller's fused scatter-add maps each block's 8 rows
+  to its window's rows of C (and sums blocks that share a window).
 * **Segment-granular launch (§4.3 Ts decomposition).** The preferred
-  operand layout is the hybrid balancer's segment table: one grid step
-  owns one *segment* of ≤ ``Ts`` condensed blocks of a single window,
-  flattened to an ``(8, ts·bk)`` operand (the sum of per-block
-  ``8×bk @ bk×nt`` products is one ``8×(ts·bk) @ (ts·bk)×nt`` product).
-  Per-step work is bounded by ``Ts`` no matter how long a power-law
-  window is, every segment owns its own compacted output slot
-  (``unique_ranks=True``: the k-tile carry never chains across
-  segments, and ``block_outer`` is always legal), and the caller's
-  fused scatter-add combines segments — the atomic case included:
-  segments marked ``atomic`` (decomposed windows, or windows shared
-  with the VPU path) share scatter rows with another producer, while
-  non-atomic segments own their rows exclusively, so the add degenerates
-  to a store for them. The legacy un-segmented layout (one block per
-  step) remains supported: blocks are pre-sorted by window, so an output
-  block is revisited consecutively across (block, k-tile) steps and the
-  kernel stores on the first visit of a rank, accumulating after.
+  operand layout is the hybrid balancer's segment table: a "block" is a
+  *segment* of ≤ ``Ts`` condensed blocks of a single window, flattened
+  to an ``(8, ts·bk)`` operand (the sum of per-block ``8×bk @ bk×nt``
+  products is one ``8×(ts·bk) @ (ts·bk)×nt`` product). Per-step work is
+  bounded by ``Ts`` no matter how long a power-law window is. Segments
+  marked ``atomic`` (decomposed windows, or windows shared with the VPU
+  path) share scatter rows with another producer; non-atomic segments
+  own their rows, so the add degenerates to a store for them. The
+  per-block (unsegmented) table runs through the same kernel.
 
 Grid order (``grid_order``, tuner-selected — paper §4.2's
-occupancy-aware scheduling choice):
-
-* ``"n_outer"`` (default, always legal): grid ``(n/nt, nb, k/kt)`` —
-  n-tiles outermost, so each TC block's values are re-fetched once per
-  n-tile.
-* ``"block_outer"``: grid ``(nb, n/nt, k/kt)`` — each block's values are
-  fetched exactly once, profitable when ``n/nt > 1``. Only *legal* when
-  every compacted rank owns a single block (``nb == n_active``):
-  with shared ranks the output block for a rank would be revisited
-  non-consecutively across blocks, breaking Pallas' accumulation
-  contract. ``ops.spmm_apply`` downgrades to ``n_outer`` otherwise.
-
-In both orders the k-tile dimension stays fastest (the accumulator carry
-requires consecutive revisits), so on hardware the B panel is re-fetched
-per (block, n-tile) pair until streaming is decoupled from the grid with
-double-buffered async copies (see ROADMAP "real TPU hardware" item).
+occupancy-aware scheduling choice): ``"n_outer"`` is ``(n/nt, nb)``,
+``"block_outer"`` is ``(nb, n/nt)`` and fetches each block's values
+once instead of once per lane tile.
 """
 from __future__ import annotations
 
@@ -68,116 +42,66 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.formats import WINDOW
-from repro.kernels.gather import panel_gather
+from repro.kernels.gather import (default_interpret, fetch_rows, lane_tile,
+                                  row_view)
 
 GRID_ORDERS = ("n_outer", "block_outer")
 
 
-def _kernel(rank_ref, vals_ref, cols_ref, b_ref, out_ref, *, block_axis,
-            unique_ranks):
-    i = pl.program_id(block_axis)   # TC block / segment index
-    kk = pl.program_id(2)           # k-tile index (fastest)
-
-    # --- Batched gather of BK rows from the resident (kt, nt) B panel.
-    gathered, _ = panel_gather(b_ref, cols_ref[0], kk)     # (bk, nt)
-
-    # --- 8×BK @ BK×NT on the MXU, f32 accumulation.
-    acc = jax.lax.dot_general(
-        vals_ref[0],
-        gathered,
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-
-    # --- First visit of this compacted output block ⇒ store, else add.
-    # Segmented launch (unique_ranks): every step owns its own output
-    # slot, so the only revisit is the k-tile sweep. Legacy layout:
-    # first block of the rank AND first k-tile; ranks are non-decreasing.
-    # (Under block_outer ranks are unique, so the rank test is always
-    # true for i > 0 and `first` reduces to kk == 0 — correct for every
-    # (i, j).)
-    if unique_ranks:
-        first = kk == 0
-    else:
-        first = jnp.logical_and(
-            kk == 0,
-            jnp.logical_or(i == 0,
-                           rank_ref[i] != rank_ref[jnp.maximum(i - 1, 0)]),
-        )
-
-    @pl.when(first)
-    def _():
-        out_ref[...] = acc[None]
-
-    @pl.when(jnp.logical_not(first))
-    def _():
-        out_ref[...] += acc[None]
+def _kernel(cols_ref, vals_ref, b_hbm, out_ref, rows, sem, *, lane_axis):
+    nt = out_ref.shape[2]
+    lanes = lane_tile(pl.program_id(lane_axis), nt)
+    fetch_rows(b_hbm, cols_ref, lambda g, w: rows.at[w], sem, lanes)
+    bk = rows.shape[0]
+    # 8×BK @ BK×NT on the MXU, f32 accumulation.
+    out_ref[0] = jax.lax.dot_general(
+        vals_ref[0], rows[...].reshape(bk, nt), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("n_active", "nt", "kt", "grid_order", "unique_ranks",
-                     "interpret"))
-def spmm_mxu(tc_vals, tc_cols, tc_rank, b, *, n_active: int, nt: int = 128,
-             kt: int | None = None, grid_order: str = "n_outer",
-             unique_ranks: bool = False, interpret: bool = True):
-    """Compacted TC-path partial output, shape ``(n_active * 8, n)``.
+    jax.jit, static_argnames=("nt", "grid_order", "interpret"))
+def spmm_mxu(tc_vals, tc_cols, b, *, nt: int = 128,
+             grid_order: str = "n_outer", interpret: bool | None = None):
+    """Per-block TC partial output, shape ``(nb * 8, n)``.
 
     Args:
       tc_vals: (nb, 8, bk) f32 condensed blocks (zero padded). Under the
         segmented launch a "block" is one §4.3 segment — ``bk`` is then
         ``ts · bk`` flattened condensed vectors of a single window.
       tc_cols: (nb, bk) i32 source column of each condensed vector.
-      tc_rank: (nb,) i32 *non-decreasing* compacted window ranks.
-      b: (k, n) dense matrix; n must be a multiple of ``nt`` and k a
-         multiple of ``kt`` (ops.py pads both).
-      n_active: number of distinct ranks (compacted output height / 8).
-      kt: B k-tile rows per grid step (defaults to all of k resident).
-      grid_order: "n_outer" (always legal) or "block_outer" (requires
-        one block per rank, i.e. ``nb == n_active`` — caller enforces;
-        always true for the segmented launch).
-      unique_ranks: every block owns its own rank (the segmented launch
-        table guarantees this) — skips the rank-boundary carry test.
+      b: (k, n) dense matrix; n must be a multiple of ``nt`` (ops.py
+         pads).
+      grid_order: "n_outer" or "block_outer" (see module docstring).
     """
     nb, _, bk = tc_vals.shape
     k, n = b.shape
-    kt = k if kt is None else kt
     assert n % nt == 0, (n, nt)
-    assert k % kt == 0, (k, kt)
     assert grid_order in GRID_ORDERS, grid_order
-    assert not unique_ranks or nb == n_active, (nb, n_active)
 
     if grid_order == "n_outer":
-        grid = (n // nt, nb, k // kt)
-        block_axis = 1
-        vals_map = lambda j, i, kk, r: (i, 0, 0)    # noqa: E731
-        cols_map = lambda j, i, kk, r: (i, 0)       # noqa: E731
-        b_map = lambda j, i, kk, r: (kk, j)         # noqa: E731
-        out_map = lambda j, i, kk, r: (r[i], 0, j)  # noqa: E731
+        grid, lane_axis = (n // nt, nb), 0
+        cols_map = lambda j, i: (i, 0, 0)   # noqa: E731
+        vals_map = lambda j, i: (i, 0, 0)   # noqa: E731
+        out_map = lambda j, i: (i, 0, j)    # noqa: E731
     else:
-        assert nb == n_active, (
-            "block_outer requires one block per rank", nb, n_active)
-        grid = (nb, n // nt, k // kt)
-        block_axis = 0
-        vals_map = lambda i, j, kk, r: (i, 0, 0)    # noqa: E731
-        cols_map = lambda i, j, kk, r: (i, 0)       # noqa: E731
-        b_map = lambda i, j, kk, r: (kk, j)         # noqa: E731
-        out_map = lambda i, j, kk, r: (r[i], 0, j)  # noqa: E731
+        grid, lane_axis = (nb, n // nt), 1
+        cols_map = lambda i, j: (i, 0, 0)   # noqa: E731
+        vals_map = lambda i, j: (i, 0, 0)   # noqa: E731
+        out_map = lambda i, j: (i, 0, j)    # noqa: E731
 
     out = pl.pallas_call(
-        functools.partial(_kernel, block_axis=block_axis,
-                          unique_ranks=unique_ranks),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, WINDOW, bk), vals_map),
-                pl.BlockSpec((1, bk), cols_map),
-                pl.BlockSpec((kt, nt), b_map),
-            ],
-            out_specs=pl.BlockSpec((1, WINDOW, nt), out_map),
-        ),
-        out_shape=jax.ShapeDtypeStruct((n_active, WINDOW, n), jnp.float32),
-        interpret=interpret,
-    )(tc_rank, tc_vals, tc_cols, b)
-    return out.reshape(n_active * WINDOW, n)
+        functools.partial(_kernel, lane_axis=lane_axis),
+        grid=grid,
+        in_specs=[
+            pl.BlockSpec((1, 1, bk), cols_map, memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, WINDOW, bk), vals_map),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, WINDOW, nt), out_map),
+        out_shape=jax.ShapeDtypeStruct((nb, WINDOW, n), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bk, 1, nt), jnp.float32),
+                        pltpu.SemaphoreType.DMA(())],
+        interpret=default_interpret(interpret),
+    )(tc_cols.reshape(nb, 1, bk), tc_vals, row_view(b))
+    return out.reshape(nb * WINDOW, n)

@@ -65,6 +65,9 @@ def generate(cfg, batch: int, prompt_len: int, gen: int, max_len: int = 0,
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")

@@ -50,7 +50,8 @@ class GraphOps:
 
     ``backend`` selects the apply path for *every* op in the training
     graph, forward and backward: ``"xla"`` (default) runs the jnp
-    reference, ``"pallas"`` the TPU kernels (interpret mode on CPU).
+    reference, ``"pallas"`` the TPU kernels (compiled on a TPU, the
+    Pallas interpreter elsewhere).
     The tuned configs are threaded into each apply, so a tuned operator
     trains through the exact plan the tuner priced.
 
@@ -65,13 +66,12 @@ class GraphOps:
 
     def __init__(self, a: SparseCSR, mode=UNSET, spmm_threshold=UNSET,
                  sddmm_threshold=UNSET, tune=UNSET, backend=UNSET,
-                 interpret=UNSET, reorder=UNSET, *, spec=None):
+                 reorder=UNSET, *, spec=None):
         base = spec if spec is not None else ExecSpec(tune="off")
         spec = resolve_spec(base, "GraphOps", mode=mode,
                             threshold=spmm_threshold,
                             sddmm_threshold=sddmm_threshold, tune=tune,
-                            backend=backend, interpret=interpret,
-                            reorder=reorder)
+                            backend=backend, reorder=reorder)
         from repro.tune import matrix_features
 
         self.spec = spec
@@ -79,7 +79,6 @@ class GraphOps:
         self.m, self.k = a.shape
         self.nnz = a.nnz
         self.backend = spec.backend
-        self.interpret = spec.interpret
         self.nwin = num_windows(a.m)
         at, self.perm = transpose_csr(a)
         self.nwin_t = num_windows(at.m)
@@ -120,8 +119,7 @@ class GraphOps:
     def fixed_spmm(self, b, backend: str | None = None):
         """C = A @ B with the plan's baked-in values (no grad wrt values)."""
         out = spmm_apply(self.arrs, b, m=self.m, nwin=self.nwin,
-                         backend=backend or self.backend, cfg=self.cfg,
-                         interpret=self.interpret)
+                         backend=backend or self.backend, cfg=self.cfg)
         return _unreorder(out, self._unperm)
 
 
@@ -139,7 +137,7 @@ def _reorder_x(x, perm):
 def _spmm_ev(g: GraphOps, edge_vals, b):
     arrs = ref.revalue_spmm_arrays(g.arrs, edge_vals)
     out = spmm_apply(arrs, b, m=g.m, nwin=g.nwin, backend=g.backend,
-                     cfg=g.cfg, interpret=g.interpret)
+                     cfg=g.cfg)
     return _unreorder(out, g._unperm)
 
 
@@ -153,11 +151,10 @@ def _spmm_ev_bwd(g, resid, d_c):
     arrs_t = ref.revalue_spmm_arrays(g.arrs_t, edge_vals[g.perm_dev])
     d_b = _unreorder(
         spmm_apply(arrs_t, d_c, m=g.k, nwin=g.nwin_t, backend=g.backend,
-                   cfg=g.cfg_t, interpret=g.interpret), g._unperm_t)
+                   cfg=g.cfg_t), g._unperm_t)
     # dv[p] = dC[row_p] · B[col_p] — SDDMM with A's sparsity.
     d_vals = sddmm_apply(g.arrs_sd, _reorder_x(d_c, g._x_perm), b,
-                         nnz=g.nnz, backend=g.backend,
-                         cfg=g.cfg_sd, interpret=g.interpret)
+                         nnz=g.nnz, backend=g.backend, cfg=g.cfg_sd)
     return d_vals.astype(edge_vals.dtype), d_b.astype(b.dtype)
 
 
@@ -167,8 +164,7 @@ _spmm_ev.defvjp(_spmm_ev_fwd, _spmm_ev_bwd)
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _sddmm_ev(g: GraphOps, x, y):
     return sddmm_apply(g.arrs_sd, _reorder_x(x, g._x_perm), y, nnz=g.nnz,
-                       backend=g.backend, cfg=g.cfg_sd,
-                       interpret=g.interpret)
+                       backend=g.backend, cfg=g.cfg_sd)
 
 
 def _sddmm_ev_fwd(g, x, y):
@@ -181,11 +177,11 @@ def _sddmm_ev_bwd(g, resid, d_vals):
     arrs = ref.revalue_spmm_arrays(g.arrs, d_vals)
     d_x = _unreorder(
         spmm_apply(arrs, y, m=g.m, nwin=g.nwin, backend=g.backend,
-                   cfg=g.cfg, interpret=g.interpret), g._unperm)
+                   cfg=g.cfg), g._unperm)
     arrs_t = ref.revalue_spmm_arrays(g.arrs_t, d_vals[g.perm_dev])
     d_y = _unreorder(
         spmm_apply(arrs_t, x, m=g.k, nwin=g.nwin_t, backend=g.backend,
-                   cfg=g.cfg_t, interpret=g.interpret), g._unperm_t)
+                   cfg=g.cfg_t), g._unperm_t)
     return d_x.astype(x.dtype), d_y.astype(y.dtype)
 
 

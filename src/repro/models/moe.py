@@ -137,7 +137,6 @@ def _moe_ep_shardmap(p, xf, topi, topv, cfg, e, k, cap, cd, mesh, ba,
     exchange in backward, and replicated weight inputs transpose into
     the data-axis gradient psum.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     # Keep the (B, S, D) layout end to end: resharding across a *reshape*
@@ -163,10 +162,10 @@ def _moe_ep_shardmap(p, xf, topi, topv, cfg, e, k, cap, cd, mesh, ba,
         out = _local_combine(y, slots, vl.reshape(bl * sl, k), cd)
         return out.reshape(bl, sl, d)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(p_w, p_w, p_w, p_tok, p_tok, p_tok),
-        out_specs=p_tok, check_rep=False,
+        out_specs=p_tok, check_vma=False,
     )(p["wi_gate"].astype(cd), p["wi_up"].astype(cd), p["wo"].astype(cd),
       xf, topi, topv.astype(cd))
 

@@ -109,8 +109,7 @@ def _occupancy_report(cfg, plan, kind: str) -> dict | None:
     if kind == "spmm":
         step = vmem_spmm_bytes(cfg, bk=int(plan.tc.bk), ts=ts)
     else:
-        step = vmem_sddmm_bytes(cfg, bk=int(plan.tc.bk), ts=ts,
-                                m_rows=plan.m, kcols=plan.k)
+        step = vmem_sddmm_bytes(cfg, bk=int(plan.tc.bk), ts=ts)
     return occupancy_report(step)
 
 

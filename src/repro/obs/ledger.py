@@ -314,8 +314,7 @@ class _OpLedgerContext:
             else:
                 pred = _modeled_sddmm_time(self._feat, thr, kf=width,
                                            bk=bk, hw=hw)
-                step = vmem_sddmm_bytes(cfg, bk=bk, ts=ts,
-                                        m_rows=plan.m, kcols=plan.k)
+                step = vmem_sddmm_bytes(cfg, bk=bk, ts=ts)
             occ = occupancy_report(step)
             cached = self._per_width[width] = {
                 "predicted_s": float(pred),

@@ -660,8 +660,7 @@ class SparseEngine:
                     ev = jnp.concatenate(
                         [ev, jnp.zeros((p - c, entry.nnz), ev.dtype)])
                 out = self._call(fn, fn._cache, stack, backend=reg.backend,
-                                 interpret=reg.interpret, edge_vals=ev,
-                                 _site=site)
+                                 edge_vals=ev, _site=site)
                 for i, r in enumerate(chunk):
                     results[r.rid] = out[i, :, :r.width]
                 self._account_exec(fn, p, c)
@@ -672,8 +671,7 @@ class SparseEngine:
             single = fn.op
 
             def apply_one(b):
-                return single(b, backend=reg.backend,
-                              interpret=reg.interpret)
+                return single(b, backend=reg.backend)
 
             # Batched SDDMM stacks and sharded applies are excluded from
             # ledger sampling: their wall time covers p vmapped panels /
@@ -705,7 +703,7 @@ class SparseEngine:
             ys = jnp.concatenate(
                 [ys, jnp.zeros((p - c,) + ys.shape[1:], ys.dtype)])
         out = self._call(fn, fn._cache, xs, ys, backend=reg.backend,
-                         interpret=reg.interpret, _site=site)
+                         _site=site)
         for i, r in enumerate(chunk):
             results[r.rid] = out[i]
         self._account_exec(fn, p, c)
@@ -736,8 +734,7 @@ class SparseEngine:
                     return spmm_sharded(
                         fn.part, bp, mesh=fn.mesh, axis=fn.axis,
                         backend="xla", edge_vals=r.edge_vals,
-                        b_layout=fn.b_layout,
-                        interpret=fn.interpret)[:, :width]
+                        b_layout=fn.b_layout)[:, :width]
 
                 return [("single", single), ("xla", xla)]
             one = fn.op                     # the underlying LibraSpMM
@@ -754,10 +751,8 @@ class SparseEngine:
 
             def single():
                 if r.edge_vals is None:
-                    return one(bp, backend=reg.backend,
-                               interpret=reg.interpret)[:, :width]
+                    return one(bp, backend=reg.backend)[:, :width]
                 out = fn(bp[None], backend=reg.backend,
-                         interpret=reg.interpret,
                          edge_vals=r.edge_vals[None])
                 return out[0, :, :width]
 
@@ -765,8 +760,7 @@ class SparseEngine:
                 cfg = one.tune_config.replace(ts=0, cs=0)
                 return spmm_apply(arrays(reg.backend, False), bp, m=one.m,
                                   nwin=one.nwin, backend=reg.backend,
-                                  cfg=cfg,
-                                  interpret=reg.interpret)[:, :width]
+                                  cfg=cfg)[:, :width]
 
             def xla():
                 return spmm_apply(arrays("xla", True), bp, m=one.m,
@@ -787,21 +781,18 @@ class SparseEngine:
                 ("single", lambda: fn(xp, yp)),
                 ("xla", lambda: sddmm_sharded(
                     fn.part, xp, yp, mesh=fn.mesh, axis=fn.axis,
-                    backend="xla", y_layout=fn.y_layout,
-                    interpret=fn.interpret)),
+                    backend="xla", y_layout=fn.y_layout)),
             ]
         one = fn.op                         # the underlying LibraSDDMM
 
         def sd_single():
-            return one(xp, yp, backend=reg.backend,
-                       interpret=reg.interpret)
+            return one(xp, yp, backend=reg.backend)
 
         def sd_unsegmented():
             cfg = one.tune_config.replace(ts=0, cs=0)
             return sddmm_apply(
                 one.arrays.for_backend(reg.backend, segmented=False),
-                xp, yp, nnz=one.nnz, backend=reg.backend, cfg=cfg,
-                interpret=reg.interpret)
+                xp, yp, nnz=one.nnz, backend=reg.backend, cfg=cfg)
 
         def sd_xla():
             return sddmm_apply(one.arrays.for_backend("xla"), xp, yp,

@@ -179,7 +179,7 @@ def corrupt_cache_entry(cache, key: str | None = None, *,
 
         with open(path) as f:
             doc = json.load(f)
-        doc.setdefault("config", {})["kt"] = -7   # checksum now stale
+        doc.setdefault("config", {})["nt"] = -7   # checksum now stale
         with open(path, "w") as f:
             json.dump(doc, f)
     else:

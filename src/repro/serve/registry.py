@@ -108,7 +108,7 @@ class GraphRegistry:
     def __init__(self, max_graphs: int = 8, *,
                  width_buckets=DEFAULT_WIDTH_BUCKETS,
                  panel_buckets=DEFAULT_PANEL_BUCKETS,
-                 backend: str = "xla", interpret: bool = True,
+                 backend: str = "xla",
                  tune="model", tune_cache=None, faults=None,
                  metrics: MetricsRegistry | None = None,
                  max_bytes: int | None = None, mem: bool = True):
@@ -122,7 +122,6 @@ class GraphRegistry:
         self.width_buckets = tuple(sorted(width_buckets))
         self.panel_buckets = tuple(sorted(panel_buckets))
         self.backend = backend
-        self.interpret = interpret
         self.tune = tune
         self.tune_cache = tune_cache
         # Optional repro.serve.faults.FaultPlan: AOT warmup compiles
@@ -170,8 +169,8 @@ class GraphRegistry:
         its ``reorder`` field is picked up transparently — the built
         operators un-permute internally, so serving callers see original
         row/nnz order). When no spec is given, the registry's own
-        construction defaults (``tune``, ``tune_cache``, ``backend``,
-        ``interpret``) seed it; the legacy kwargs (``mode=``, ``tune=``,
+        construction defaults (``tune``, ``tune_cache``, ``backend``)
+        seed it; the legacy kwargs (``mode=``, ``tune=``,
         ``b_layout=``, …) keep working through the deprecation shim and
         override the spec.
 
@@ -182,7 +181,7 @@ class GraphRegistry:
         """
         base = spec if spec is not None else ExecSpec(
             tune=self.tune, tune_cache=self.tune_cache,
-            backend=self.backend, interpret=self.interpret)
+            backend=self.backend)
         spec = resolve_spec(
             base, "GraphRegistry.register", mode=mode, b_layout=b_layout,
             tune=UNSET if tune is None else tune, **op_kwargs)
@@ -385,8 +384,7 @@ class GraphRegistry:
                     if p > self.pack_limit(entry, w):
                         continue   # the engine will never run this shape
                     apply_one = fn if entry.sharded else (
-                        lambda b: fn.op(b, backend=self.backend,
-                                        interpret=self.interpret))
+                        lambda b: fn.op(b, backend=self.backend))
                     cache = fn._cache if entry.sharded else \
                         fn.op._apply_cache
                     before = len(cache)
@@ -403,7 +401,7 @@ class GraphRegistry:
                     before = len(cache)
                     fn(jnp.zeros((p, entry.m, w), dtype),
                        jnp.zeros((p, entry.k, w), dtype),
-                       backend=self.backend, interpret=self.interpret)
+                       backend=self.backend)
                 compiled += len(cache) > before
         entry.warmed += compiled
         self.enforce_budget()   # warmup materializes lazy views

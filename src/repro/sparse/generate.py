@@ -76,6 +76,45 @@ def block_structured_csr(m: int, k: int, block: int = 8, block_density: float = 
     return _finish(m, k, rows, cols, rng)
 
 
+def power_law_graph(n: int, nnz: int, shape: float = 1.5,
+                    seed: int = 0) -> SparseCSR:
+    """Directed graph of exactly ``nnz`` distinct edges (no self loops)
+    whose out- and in-degrees are both heavy-tailed (Pareto weights of
+    ``shape``) — the citation-graph regime, e.g. ogbn-arxiv at
+    ``n=169_343``, ``nnz=1_166_243``. Vectorized, so graph-benchmark
+    sizes build in about a second."""
+    rng = np.random.default_rng(seed)
+    w_out = rng.pareto(shape, n) + 1.0
+    w_in = rng.pareto(shape, n) + 1.0
+    keys = np.zeros(0, np.int64)
+    while keys.size < nnz:
+        draw = int(1.25 * (nnz - keys.size)) + 16
+        rows = rng.choice(n, size=draw, p=w_out / w_out.sum())
+        cols = rng.choice(n, size=draw, p=w_in / w_in.sum())
+        fresh = rows.astype(np.int64) * n + cols
+        keys = np.unique(np.concatenate([keys, fresh[rows != cols]]))
+    keys = np.sort(rng.choice(keys, size=nnz, replace=False))
+    return _finish(n, n, keys // n, keys % n, rng)
+
+
+def block_graph(n: int, nnz: int, block: int = 8, fill: float = 0.9,
+                seed: int = 0) -> SparseCSR:
+    """``n × n`` matrix of about ``nnz`` non-zeros packed into dense
+    ``block × block`` tiles on a random block grid — column vectors are
+    nearly full, so the MXU stream carries almost all of the work.
+    Vectorized counterpart of :func:`block_structured_csr` sized by
+    non-zeros."""
+    rng = np.random.default_rng(seed)
+    nb = n // block
+    nblocks = min(nb * nb, max(1, int(round(nnz / (fill * block * block)))))
+    sel = rng.choice(nb * nb, size=nblocks, replace=False)
+    off = np.arange(block * block)
+    rows = ((sel // nb) * block)[:, None] + off[None, :] // block
+    cols = ((sel % nb) * block)[:, None] + off[None, :] % block
+    keep = rng.random(rows.shape) < fill
+    return _finish(n, n, rows[keep], cols[keep], rng)
+
+
 def mixed_csr(m: int, k: int, seed: int = 0) -> SparseCSR:
     """Hybrid-region matrix: dense blocks + a sprinkle of isolated non-zeros.
 

@@ -25,6 +25,7 @@ from repro.train import optimizer as opt
 def make_train_step(cfg: ArchConfig, opt_cfg: opt.OptConfig, mesh,
                     microbatches: int = 1):
     """Returns (train_step, in_shardings, out_shardings) ready for jit."""
+    mesh = sh.auto_axes(mesh)
 
     def loss_of(params, batch):
         return api.loss_fn(params, batch, cfg)
@@ -34,8 +35,8 @@ def make_train_step(cfg: ArchConfig, opt_cfg: opt.OptConfig, mesh,
         ctx.__enter__()  # tracing is synchronous; exited below
         batch = jax.tree.map(
             lambda x: jax.lax.with_sharding_constraint(
-                x, sh.sanitize_spec(sh.batch_spec(mesh, x.ndim),
-                                    x.shape, mesh)), batch)
+                x, NamedSharding(mesh, sh.sanitize_spec(
+                    sh.batch_spec(mesh, x.ndim), x.shape, mesh))), batch)
         if microbatches == 1:
             loss, grads = jax.value_and_grad(loss_of)(params, batch)
         else:
@@ -68,6 +69,7 @@ def make_train_step(cfg: ArchConfig, opt_cfg: opt.OptConfig, mesh,
 
 def shardings_for_train(mesh, params, opt_state, batch_like,
                         replicate_params=False):
+    mesh = sh.auto_axes(mesh)
     p_sh = sh.param_shardings(mesh, params, replicate=replicate_params)
     o_sh = {
         "mu": sh.param_shardings(mesh, opt_state["mu"],
@@ -83,11 +85,13 @@ def shardings_for_train(mesh, params, opt_state, batch_like,
 
 
 def make_serve_step(cfg: ArchConfig, mesh):
+    mesh = sh.auto_axes(mesh)
+
     def serve_step(params, cache, token, cache_len):
         with sh.activation_context(mesh, sh.dp_only_of(cfg)):
             token = jax.lax.with_sharding_constraint(
-                token, sh.sanitize_spec(sh.batch_spec(mesh, 2),
-                                        token.shape, mesh))
+                token, NamedSharding(mesh, sh.sanitize_spec(
+                    sh.batch_spec(mesh, 2), token.shape, mesh)))
             logits, cache2 = api.decode_step(params, cache, token,
                                              cache_len, cfg)
             if cfg.serve_sample:
@@ -103,6 +107,7 @@ def make_serve_step(cfg: ArchConfig, mesh):
 
 def shardings_for_serve(mesh, params, cache, token_like, sample=False,
                         replicate_params=False):
+    mesh = sh.auto_axes(mesh)
     p_sh = sh.param_shardings(mesh, params, replicate=replicate_params)
     c_sh = sh.cache_shardings(mesh, cache)
     t_sh = NamedSharding(mesh, sh.sanitize_spec(

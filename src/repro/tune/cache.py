@@ -45,7 +45,7 @@ from repro.obs.metrics import MetricsRegistry
 from repro.sparse.matrix import SparseCSR
 from repro.tune.model import TuneConfig
 
-CACHE_VERSION = 5  # v5: reorder decisions in keys + cached decision docs
+CACHE_VERSION = 6  # v6: row-fetch kernels; no k/Y/X panel tiles
 _ENV_VAR = "REPRO_TUNE_CACHE_DIR"
 _ENV_MAX = "REPRO_TUNE_CACHE_MAX"
 DEFAULT_MAX_ENTRIES = 512

@@ -12,15 +12,16 @@ features:
   :mod:`repro.core.threshold` *without building a plan per candidate*;
 * a **VMEM footprint model** for each of the four kernels: the bytes a
   single pipelined grid step keeps resident (Pallas double-buffers the
-  streamed input blocks, hence the ×2 on inputs). Tile sizes (``kt``,
-  ``nt``, ``kf_tile``, ``yt``, ``xt``) are chosen as the largest
-  hardware-aligned candidates whose footprint stays inside
-  ``VMEM_BUDGET_BYTES`` — the TPU analogue of CUDA occupancy sizing;
-* a **grid-order pick** (``n_outer`` vs ``block_outer``) from the block
-  layout: ``block_outer`` fetches each condensed TC block once instead
-  of once per n-tile, but is only *legal* when every active window owns
-  a single block (otherwise output revisits stop being consecutive —
-  see :mod:`repro.kernels.spmm_mxu`).
+  pipelined blocks, hence the ×2; the DMA'd rows scratch is single).
+  Lane tiles (``nt``, ``kf_tile``) and the §4.3 segment caps are chosen
+  as the largest hardware-aligned candidates whose footprint stays
+  inside ``VMEM_BUDGET_BYTES`` — the TPU analogue of CUDA occupancy
+  sizing. The kernels fetch operand rows by id, so no footprint grows
+  with ``k``, ``m`` or ``kcols``;
+* a **grid-order pick** (``n_outer`` vs ``block_outer``): with more than
+  one lane tile, ``block_outer`` fetches each condensed TC block once
+  instead of once per lane tile (every grid step owns its output block,
+  so both orders are always legal).
 
 The result is a :class:`TuneConfig` — the single object every layer
 (preprocess, ops, kernels, benchmarks) parameterizes through.
@@ -28,7 +29,6 @@ The result is a :class:`TuneConfig` — the single object every layer
 from __future__ import annotations
 
 import dataclasses
-import itertools
 import warnings
 
 import numpy as np
@@ -42,12 +42,9 @@ from repro.sparse.matrix import SparseCSR
 VMEM_BYTES_TOTAL = 16 * 2**20
 VMEM_BUDGET_BYTES = int(VMEM_BYTES_TOTAL * 0.75)
 
-# Hardware-aligned tile candidates (lane width 128, sublane multiple 8).
-_KT_CANDIDATES = (8192, 4096, 2048, 1024, 512, 256, 128, 64, 32, 16, 8)
+# Hardware-aligned lane-tile candidates (lane width 128).
 _NT_CANDIDATES = (512, 256, 128)
 _KF_CANDIDATES = (512, 256, 128)
-_YT_CANDIDATES = (8192, 4096, 2048, 1024, 512, 256, 128, 64, 32, 16, 8)
-_XT_CANDIDATES = (8192, 4096, 2048, 1024, 512, 256, 128, 64, 32, 16, 8)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,18 +52,14 @@ class TuneConfig:
     """One plan-selection decision, consumed by every layer.
 
     ``threshold``/``bk``/``ts_tile`` parameterize preprocessing (the
-    2D-aware distribution); ``kt``/``nt``/``grid_order`` the SpMM
-    kernels; ``kf_tile``/``yt`` the SDDMM kernels. ``None`` means "the
-    operator default" so a bare ``TuneConfig()`` reproduces the
-    untuned behavior. Frozen + hashable so it can ride through
-    ``jax.jit`` as a static argument.
+    2D-aware distribution); ``nt``/``grid_order`` the SpMM kernels;
+    ``kf_tile`` the SDDMM kernels. ``None`` means "the operator default"
+    so a bare ``TuneConfig()`` reproduces the untuned behavior. Frozen +
+    hashable so it can ride through ``jax.jit`` as a static argument.
     """
 
-    kt: int = 512            # SpMM B k-tile rows resident per grid step
     nt: int = 128            # SpMM lane tile (output columns per step)
     kf_tile: int = 128       # SDDMM feature tile
-    yt: int | None = None    # SDDMM Y-row panel (None = all rows resident)
-    xt: int | None = None    # SDDMM VPU X-row panel (None = all rows resident)
     threshold: int | None = None  # TC/VPU split (None = operator default)
     bk: int | None = None    # condensed block depth (None = operator default)
     ts_tile: int | None = None    # VPU tile width (None = operator default)
@@ -166,45 +159,46 @@ def vmem_spmm_bytes(cfg: TuneConfig, *, bk: int, ts: int,
     """Resident bytes of one pipelined grid step, max over the two
     SpMM kernels (the streams are scheduled independently).
 
-    Streamed input blocks are double-buffered (×2); the revisited output
-    block is single-buffered (it is the accumulator carry). ``ts`` here
-    is the VPU *tile width* (``ts_tile``); the §4.3 segment caps
-    (``cfg.ts``/``cfg.cs``) widen the per-step operands and the gathered
-    B-row scratch, which this model charges for.
+    Both kernels keep B in HBM and DMA the rows a step's ids name into a
+    VMEM scratch, so nothing here grows with ``k``. Pipelined
+    input/output blocks are double-buffered (×2); the fetched-rows
+    scratch is single. ``ts`` here is the VPU *tile
+    width* (``ts_tile``); the §4.3 segment caps (``cfg.ts``/``cfg.cs``)
+    set the per-step widths this model charges for.
     """
     it = _itemsize(dtype)
-    kt, nt = cfg.kt, cfg.nt
+    nt = cfg.nt
     mxu_vecs, vpu_els = _seg_widths(cfg, bk=bk, ts_tile=ts)
-    # MXU step: segment vals (8, ts·bk) + cols (ts·bk,) + B panel
-    # (kt, nt), gathered-rows scratch (ts·bk, nt), output (8, nt).
-    mxu = 2 * (WINDOW * mxu_vecs * it + mxu_vecs * 4 + kt * nt * it) \
-        + mxu_vecs * nt * it + WINDOW * nt * it
-    # VPU step: segment vals/cols (cs,) each + B panel (kt, nt),
-    # gathered-rows scratch (cs, nt), output (nt,).
-    vpu = 2 * (2 * vpu_els * 4 + kt * nt * it) \
-        + vpu_els * nt * it + nt * it
+    # MXU step: one segment's vals (8, ts·bk) + its SMEM ids, fetched
+    # rows (ts·bk, nt), output (8, nt).
+    mxu = 2 * (WINDOW * mxu_vecs * it + mxu_vecs * 4 + WINDOW * nt * it) \
+        + mxu_vecs * nt * it
+    # VPU step: 8 segments' vals (8, cs) + SMEM ids, fetched rows
+    # (cs, 8, nt), output (8, nt).
+    vpu = 2 * (2 * WINDOW * vpu_els * 4 + WINDOW * nt * it) \
+        + vpu_els * WINDOW * nt * it
     return max(mxu, vpu)
 
 
-def vmem_sddmm_bytes(cfg: TuneConfig, *, bk: int, ts: int, m_rows: int,
-                     kcols: int, dtype=np.float32) -> int:
+def vmem_sddmm_bytes(cfg: TuneConfig, *, bk: int, ts: int,
+                     dtype=np.float32) -> int:
     """Resident bytes of one pipelined SDDMM grid step (max over kernels).
 
-    Every streamed operand dimension is bounded: both SDDMM kernels
-    stream Y in ``(yt, kf_tile)`` row panels, and the VPU kernel streams
-    X in ``(xt, kf_tile)`` row panels too (``xt=None`` keeps all of X
-    resident — the pre-streaming behavior). No whole-operand VMEM
-    residency remains.
+    X and Y stay in HBM; a step DMAs the rows its ids name, one
+    ``kf_tile`` feature slice at a time, so nothing here grows with the
+    operand heights.
     """
     it = _itemsize(dtype)
     kf = cfg.kf_tile
-    yt = kcols if cfg.yt is None else min(cfg.yt, kcols)
-    xt = m_rows if cfg.xt is None else min(cfg.xt, m_rows)
     mxu_vecs, vpu_els = _seg_widths(cfg, bk=bk, ts_tile=ts)
-    mxu = 2 * (WINDOW * kf * it + yt * kf * it + 2 * mxu_vecs * 4) \
-        + mxu_vecs * kf * it + WINDOW * mxu_vecs * it
-    vpu = 2 * (xt * kf * it + yt * kf * it + 2 * vpu_els * 4) \
-        + 2 * vpu_els * kf * it + vpu_els * it
+    # MXU step: ids + bitmap (8-sublane padded) + output (8, ts·bk),
+    # X window (8, kf), fetched Y rows (ts·bk, kf).
+    mxu = 2 * (mxu_vecs * 4 + 2 * WINDOW * mxu_vecs * it) \
+        + WINDOW * kf * it + mxu_vecs * kf * it
+    # VPU step: 8 tiles' row/col ids + output (8, cs), fetched X and Y
+    # rows (cs, 8, kf) each.
+    vpu = 2 * (2 * WINDOW * vpu_els * 4 + WINDOW * vpu_els * it) \
+        + 2 * vpu_els * WINDOW * kf * it
     return max(mxu, vpu)
 
 
@@ -282,14 +276,9 @@ def _modeled_sddmm_time(feat: MatrixFeatures, threshold: int, *, kf: int,
 
 
 # ------------------------------------------------------------ tuners ---
-def _pick_tiles(fits, *candidate_lists):
-    """Largest candidate tuple that fits, preferring bigger values in
-    earlier lists (more reuse per panel fetch) over later ones; falls
-    back to the smallest of everything when nothing fits."""
-    for combo in itertools.product(*candidate_lists):
-        if fits(*combo):
-            return combo
-    return tuple(c[-1] for c in candidate_lists)
+def _pick_tile(fits, candidates):
+    """The largest candidate that fits; the smallest when none does."""
+    return next((c for c in candidates if fits(c)), candidates[-1])
 
 
 _TS_SEG_CANDIDATES = (1, 2, 4, 8, 16, 32)
@@ -389,40 +378,30 @@ def model_tune_spmm(a: SparseCSR, *, n: int = 128, dtype=np.float32,
     seg_ts = _pick_seg_ts(feat, threshold, bk)
     seg_cs = _pick_seg_cs(feat, ts_tile)
 
-    # Tile sizing: largest (kt, nt) whose pipelined step fits the budget.
-    # kt beyond k buys nothing (ops clamps); nt beyond n likewise.
-    kts = [c for c in _KT_CANDIDATES if c <= max(a.k, _KT_CANDIDATES[-1])]
+    # Lane tile: the widest whose pipelined step fits the budget (nt
+    # beyond n buys nothing).
     nts = [c for c in _NT_CANDIDATES if c <= max(n, _NT_CANDIDATES[-1])]
 
-    def fits(kt, nt):
-        cfg = TuneConfig(kt=kt, nt=nt, ts=seg_ts, cs=seg_cs)
+    def fits(nt):
+        cfg = TuneConfig(nt=nt, ts=seg_ts, cs=seg_cs)
         return vmem_spmm_bytes(cfg, bk=bk, ts=ts_tile, dtype=dtype) <= budget
 
-    kt, nt = _pick_tiles(fits, kts, nts)
-    # Still over budget at the smallest tiles ⇒ narrow the segment caps
-    # before warning (a segment's gathered-rows scratch scales with
-    # them), then re-pick tiles: the narrowed caps may re-admit large
-    # kt/nt candidates that the original caps crowded out.
-    if not fits(kt, nt):
-        while not fits(kt, nt) and seg_ts > 1:
+    nt = _pick_tile(fits, nts)
+    # Still over budget at the narrowest tile ⇒ narrow the segment caps
+    # before warning (a segment's fetched-rows scratch scales with
+    # them), then re-pick: the narrowed caps may re-admit a wider tile.
+    if not fits(nt):
+        while not fits(nt) and seg_ts > 1:
             seg_ts //= 2
-        while not fits(kt, nt) and seg_cs > ts_tile:
+        while not fits(nt) and seg_cs > ts_tile:
             seg_cs //= 2
-        kt, nt = _pick_tiles(fits, kts, nts)
+        nt = _pick_tile(fits, nts)
 
-    # Grid order: block_outer fetches each TC block's values once instead
-    # of once per n-tile. On the segmented launch every segment owns its
-    # own compacted output slot, so it is always legal; unsegmented it
-    # requires one block per active window (no window with more than bk
-    # vectors above the threshold — the consecutive-revisit contract).
-    max_vec = int(feat.vectors_at_least(threshold or 1).max()) \
-        if feat.win_vec_hist.size else 0
-    multi_ntile = n > nt
-    grid_order = ("block_outer"
-                  if multi_ntile and (seg_ts > 0 or 0 < max_vec <= bk)
-                  else "n_outer")
+    # Grid order: with several lane tiles, block_outer fetches each TC
+    # block's values once instead of once per lane tile.
+    grid_order = "block_outer" if n > nt else "n_outer"
 
-    cfg = TuneConfig(kt=kt, nt=nt, threshold=threshold, bk=bk,
+    cfg = TuneConfig(nt=nt, threshold=threshold, bk=bk,
                      ts_tile=ts_tile, ts=seg_ts, cs=seg_cs,
                      grid_order=grid_order, source="model")
     step = vmem_spmm_bytes(cfg, bk=bk, ts=ts_tile, dtype=dtype)
@@ -431,8 +410,7 @@ def model_tune_spmm(a: SparseCSR, *, n: int = 128, dtype=np.float32,
             f"model_tune_spmm: smallest tile candidates need {step} B "
             f"per grid step, over the {budget} B VMEM budget",
             RuntimeWarning, stacklevel=2)
-    _sp.set(threshold=threshold, kt=kt, nt=nt,
-            vmem_step_bytes=step).close()
+    _sp.set(threshold=threshold, nt=nt, vmem_step_bytes=step).close()
     return cfg
 
 
@@ -446,8 +424,8 @@ def model_tune_sddmm(a: SparseCSR, *, kf: int = 128, dtype=np.float32,
     """Emit a full SDDMM :class:`TuneConfig` from matrix features.
 
     Warns (RuntimeWarning) when even the smallest tile candidates exceed
-    the budget (every operand dimension now streams — X included — so
-    this only happens for pathological ``bk``/``ts_tile`` overrides).
+    the budget (no footprint grows with the operands, so this only
+    happens for pathological ``bk``/``ts_tile`` overrides).
     """
     from repro.core import preprocess as P
     from repro.obs.trace import get_tracer
@@ -469,37 +447,31 @@ def model_tune_sddmm(a: SparseCSR, *, kf: int = 128, dtype=np.float32,
     seg_ts = _pick_seg_ts(feat, 1, bk)
     seg_cs = _pick_seg_cs(feat, ts_tile)
 
+    # The widest feature tile that fits.
     kfs = [c for c in _KF_CANDIDATES if c <= max(kf, _KF_CANDIDATES[-1])]
-    yts = [c for c in _YT_CANDIDATES if c <= max(a.k, _YT_CANDIDATES[-1])]
-    xts = [c for c in _XT_CANDIDATES if c <= max(a.m, _XT_CANDIDATES[-1])]
 
-    # Largest (yt, kf_tile, xt) triple that fits, preferring a bigger Y
-    # panel (shared by both kernels), then a wider feature tile, then a
-    # bigger X panel (VPU-only).
-    def fits(yt_c, kf_c, xt_c):
-        cfg = TuneConfig(kf_tile=kf_c, yt=yt_c, xt=xt_c,
-                         ts=seg_ts, cs=seg_cs)
-        return vmem_sddmm_bytes(cfg, bk=bk, ts=ts_tile, m_rows=a.m,
-                                kcols=a.k, dtype=dtype) <= budget
+    def fits(kf_c):
+        cfg = TuneConfig(kf_tile=kf_c, ts=seg_ts, cs=seg_cs)
+        return vmem_sddmm_bytes(cfg, bk=bk, ts=ts_tile,
+                                dtype=dtype) <= budget
 
-    yt, kf_tile, xt = _pick_tiles(fits, yts, kfs, xts)
-    if not fits(yt, kf_tile, xt):
-        while not fits(yt, kf_tile, xt) and seg_ts > 1:
+    kf_tile = _pick_tile(fits, kfs)
+    if not fits(kf_tile):
+        while not fits(kf_tile) and seg_ts > 1:
             seg_ts //= 2
-        while not fits(yt, kf_tile, xt) and seg_cs > ts_tile:
+        while not fits(kf_tile) and seg_cs > ts_tile:
             seg_cs //= 2
-        yt, kf_tile, xt = _pick_tiles(fits, yts, kfs, xts)
+        kf_tile = _pick_tile(fits, kfs)
 
-    cfg = TuneConfig(kf_tile=kf_tile, yt=yt, xt=xt, threshold=threshold,
+    cfg = TuneConfig(kf_tile=kf_tile, threshold=threshold,
                      bk=bk, ts_tile=ts_tile, ts=seg_ts, cs=seg_cs,
                      source="model")
-    step = vmem_sddmm_bytes(cfg, bk=bk, ts=ts_tile, m_rows=a.m, kcols=a.k,
-                            dtype=dtype)
+    step = vmem_sddmm_bytes(cfg, bk=bk, ts=ts_tile, dtype=dtype)
     if step > budget:
         warnings.warn(
             f"model_tune_sddmm: smallest tile candidates need {step} B "
             f"per grid step, over the {budget} B VMEM budget",
             RuntimeWarning, stacklevel=2)
-    _sp.set(threshold=threshold, yt=yt, kf_tile=kf_tile,
+    _sp.set(threshold=threshold, kf_tile=kf_tile,
             vmem_step_bytes=step).close()
     return cfg

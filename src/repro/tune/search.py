@@ -68,9 +68,9 @@ def spmm_candidates(a: SparseCSR, *, n: int, mode: str,
     default *plan* (default threshold/bk/ts_tile — plan parameters are
     read on every backend). On ``"xla"`` its kernel-tile fields ride on
     the model's deterministic sizing, which times identically (the
-    reference path never reads kt/nt/grid_order) while keeping the
+    reference path never reads nt/grid_order) while keeping the
     cached tiles meaningful for later Pallas runs; on ``"pallas"`` it is
-    the verbatim default config. Kernel-tile/grid-order perturbations
+    the verbatim default config. Lane-tile/grid-order perturbations
     are only emitted for ``"pallas"``, where they change the
     executable — on ``"xla"`` they'd compile identically and the argmin
     over them would be pure timer noise.
@@ -86,11 +86,11 @@ def spmm_candidates(a: SparseCSR, *, n: int, mode: str,
         cands = [model.replace(**default_plan), model]
     else:
         cands = [DEFAULT_TUNE.replace(**default_plan), model]
-        for kt in (model.kt // 2, model.kt * 2):
-            if kt >= 8:
-                cands.append(model.replace(kt=kt))
-        if model.grid_order == "block_outer":
-            cands.append(model.replace(grid_order="n_outer"))
+        if model.nt // 2 >= 128:
+            cands.append(model.replace(nt=model.nt // 2))
+        cands.append(model.replace(grid_order="n_outer"
+                                   if model.grid_order == "block_outer"
+                                   else "block_outer"))
         cands.extend(_seg_cap_perturbations(model))
     if threshold is None and mode == "hybrid" and model.threshold is not None:
         for t in (model.threshold - 1, model.threshold + 1):
@@ -132,10 +132,8 @@ def sddmm_candidates(a: SparseCSR, *, kf: int, mode: str,
         cands = [model.replace(**default_plan), model]
     else:
         cands = [DEFAULT_TUNE.replace(**default_plan), model]
-        if model.yt is not None and model.yt // 2 >= 8:
-            cands.append(model.replace(yt=model.yt // 2))
-        if model.xt is not None and model.xt // 2 >= 8:
-            cands.append(model.replace(xt=model.xt // 2))
+        if model.kf_tile // 2 >= 128:
+            cands.append(model.replace(kf_tile=model.kf_tile // 2))
         cands.extend(_seg_cap_perturbations(model))
     if threshold is None and mode == "hybrid" and model.threshold is not None:
         for t in (max(model.threshold // 2, 1), model.threshold * 2):

@@ -81,7 +81,6 @@ def test_crosspod_compressed_reduction_shardmap():
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.train import compress
         mesh = jax.make_mesh((4, 2), ('pod', 'data'))
         g = jnp.arange(32, dtype=jnp.float32).reshape(4, 8) / 100.0
@@ -90,7 +89,7 @@ def test_crosspod_compressed_reduction_shardmap():
             out, e2 = compress.crosspod_mean_compressed({'g': g}, {'g': err},
                                                         axis='pod')
             return out['g'], e2['g']
-        fn = shard_map(f, mesh=mesh, in_specs=(P('pod', 'data'), P('pod', 'data')),
+        fn = jax.shard_map(f, mesh=mesh, in_specs=(P('pod', 'data'), P('pod', 'data')),
                        out_specs=(P('pod', 'data'), P('pod', 'data')))
         out, err2 = fn(g, err)
         # each pod's shard replaced by cross-pod mean (up to int8 error)

@@ -1,6 +1,6 @@
 """Single-pass fused hybrid apply: Pallas (interpret) vs the XLA oracle.
 
-Covers the compacted TC layout, the k-tiled B streaming, and the fused
+Covers the per-block TC layout, the id-driven B row fetch, and the fused
 scatter-accumulate epilogue across modes, awkward (non-multiple-of-tile)
 shapes, empty-TC / empty-VPU plans, and large-k matrices.
 """
@@ -84,8 +84,9 @@ def test_tc_window_compaction_map(rng):
 
 @pytest.mark.parametrize("k", [4608, 16384])
 def test_fused_spmm_large_k_tiled(rng, k):
-    """k ≫ the default k-tile: the Pallas path must stream B in (kt, nt)
-    panels (never whole-k resident) and still match the oracle."""
+    """Large k: the Pallas path fetches only the B rows the plan names
+    (no k-panel sweep, never whole-k resident) and must match the
+    oracle."""
     a = random_uniform_csr(32, k, 40.0 / k, seed=k)
     b = _rand(rng, k, 128)
     oracle = ref.spmm_dense_oracle(a.to_dense(), b)

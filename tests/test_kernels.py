@@ -1,5 +1,6 @@
 """Per-kernel interpret-mode validation against the pure-jnp oracles:
 shape/dtype sweeps + end-to-end hybrid op vs dense oracle."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -23,22 +24,26 @@ def _rand(rng, *shape):
     return rng.standard_normal(shape).astype(np.float32)
 
 
-@pytest.mark.parametrize("nb,bk,k,n,nt,kt", [
+# ``col_hi`` narrows the column ids to [0, col_hi) so many ids repeat
+# (None: all of k) — the row fetch must copy a shared row once per id.
+@pytest.mark.parametrize("nb,bk,k,n,nt,col_hi", [
     (1, 8, 32, 128, 128, None),
     (5, 16, 64, 128, 64, 32),
     (9, 32, 128, 256, 128, 32),
 ])
-def test_spmm_mxu_matches_compact_ref(rng, nb, bk, k, n, nt, kt):
+def test_spmm_mxu_matches_compact_ref(rng, nb, bk, k, n, nt, col_hi):
     nwin = 4
     window = np.sort(rng.integers(0, nwin, nb)).astype(np.int32)
     active = np.unique(window)
     rank = np.searchsorted(active, window).astype(np.int32)
-    cols = rng.integers(0, k, (nb, bk)).astype(np.int32)
+    cols = rng.integers(0, col_hi or k, (nb, bk)).astype(np.int32)
     vals = _rand(rng, nb, WINDOW, bk)
     b = _rand(rng, k, n)
-    out = spmm_mxu(jnp.asarray(vals), jnp.asarray(cols), jnp.asarray(rank),
-                   jnp.asarray(b), n_active=active.size, nt=nt, kt=kt,
-                   interpret=True)
+    per_block = spmm_mxu(jnp.asarray(vals), jnp.asarray(cols),
+                         jnp.asarray(b), nt=nt, interpret=True)
+    out = jax.ops.segment_sum(per_block.reshape(nb, WINDOW, n),
+                              jnp.asarray(rank), num_segments=active.size)
+    out = out.reshape(active.size * WINDOW, n)
     expect = ref.spmm_tc_compact_ref(jnp.asarray(vals), jnp.asarray(cols),
                                      jnp.asarray(rank), jnp.asarray(b),
                                      active.size)
@@ -46,16 +51,16 @@ def test_spmm_mxu_matches_compact_ref(rng, nb, bk, k, n, nt, kt):
                                rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("ntiles,ts,k,n,kt", [
+@pytest.mark.parametrize("ntiles,ts,k,n,col_hi", [
     (1, 8, 16, 128, None),
     (7, 32, 64, 128, 16),
 ])
-def test_spmm_vpu_matches_ref(rng, ntiles, ts, k, n, kt):
+def test_spmm_vpu_matches_ref(rng, ntiles, ts, k, n, col_hi):
     vals = _rand(rng, ntiles, ts)
-    cols = rng.integers(0, k, (ntiles, ts)).astype(np.int32)
+    cols = rng.integers(0, col_hi or k, (ntiles, ts)).astype(np.int32)
     b = _rand(rng, k, n)
     out = spmm_vpu(jnp.asarray(vals), jnp.asarray(cols), jnp.asarray(b),
-                   nt=128, kt=kt, interpret=True)
+                   nt=128, interpret=True)
     gathered = b[cols]
     expect = np.einsum("tj,tjn->tn", vals, gathered)
     np.testing.assert_allclose(np.asarray(out), expect, rtol=1e-4, atol=1e-4)
@@ -150,3 +155,40 @@ def test_bitmap_mask_bit_decoding():
     mask = np.asarray(ref.bitmap_mask(bm))[0]
     assert mask[0, 0] and mask[7, 0] and not mask[1, 0]
     assert mask[1, 1] and not mask[0, 1]
+
+
+def _combine_maps(arrs):
+    """The row/window maps the SpMM combines scatter with, per layout."""
+    return {
+        "tc_rank": arrs["tc_rank"],
+        "tc_active_row": arrs["tc_active_row"],
+        "tc_seg_window": arrs["tc_seg_row"][..., ::WINDOW] // WINDOW,
+        "vpu_row": arrs["vpu_row"],
+        "vpu_seg_row": arrs["vpu_seg_row"],
+    }
+
+
+@pytest.mark.parametrize("mi", range(len(MATS)))
+@pytest.mark.parametrize("mode", ["hybrid", "tcu", "vpu"])
+@pytest.mark.parametrize("n_shards", [1, 3])
+def test_spmm_combine_maps_are_sorted(mi, mode, n_shards):
+    """The combines scatter with ``indices_are_sorted=True``, which the
+    TPU trusts without checking: every plan, sharded padding included,
+    must keep its block windows and row maps non-decreasing."""
+    from repro.api import ExecSpec
+    from repro.dist.partition import partition_spmm
+
+    a = MATS[mi]
+    spec = ExecSpec(mode=mode, tune="model")
+    if n_shards == 1:
+        arrs = LibraSpMM(a, spec=spec).arrays.for_backend("pallas")
+        arrs = dict(arrs, **LibraSpMM(a, spec=spec).arrays.for_backend(
+            "pallas", segmented=False))
+        stacks = [{k: np.asarray(v) for k, v in arrs.items()}]
+    else:
+        part = partition_spmm(a, n_shards, spec=spec)
+        stacks = [{k: np.asarray(v[p]) for k, v in part.stacked.items()}
+                  for p in range(n_shards)]
+    for arrs in stacks:
+        for name, m in _combine_maps(arrs).items():
+            assert np.all(np.diff(m) >= 0), (name, m)

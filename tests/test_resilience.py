@@ -486,7 +486,7 @@ def test_gnn_service_scoring_fails_alone(rng):
 # -------------------------------------------------------- cache quarantine ---
 def test_cache_quarantine_roundtrip(tmp_path):
     pc = PlanCache(str(tmp_path), max_entries=8)
-    cfg = TuneConfig(kt=128, nt=128, threshold=4, source="search")
+    cfg = TuneConfig(nt=128, threshold=4, source="search")
     pc.put("k1", cfg)
     assert pc.get("k1") == cfg.replace(source="cache")
     # torn write → unparseable → quarantined, not a silent miss
@@ -514,7 +514,7 @@ def test_cache_version_skew_is_silent_miss_not_quarantine(tmp_path):
     import json
 
     pc = PlanCache(str(tmp_path), max_entries=8)
-    pc.put("k", TuneConfig(kt=64))
+    pc.put("k", TuneConfig(nt=256))
     p = pc._path("k")
     with open(p) as f:
         doc = json.load(f)

@@ -262,13 +262,13 @@ def test_ts_cs_cache_roundtrip(tmp_path):
 
     assert CACHE_VERSION >= 3  # v3: ts/cs joined TuneConfig
     pc = PlanCache(str(tmp_path))
-    cfg = TuneConfig(ts=4, cs=128, kt=256, source="search")
+    cfg = TuneConfig(ts=4, cs=128, nt=256, source="search")
     key = tune_key(power_law_csr(32, 32, 4.0, seed=1), op="spmm",
                    width=128, dtype="float32", backend="xla",
                    mode="hybrid", tune="search")
     pc.put(key, cfg)
     got = pc.get(key)
-    assert got.ts == 4 and got.cs == 128 and got.kt == 256
+    assert got.ts == 4 and got.cs == 128 and got.nt == 256
 
 
 def test_search_perturbs_segment_caps():

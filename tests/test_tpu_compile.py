@@ -1,0 +1,105 @@
+"""Compile-only checks of the Pallas applies for a described TPU v5e.
+
+Nothing runs: each test lowers ``spmm_apply`` / ``sddmm_apply`` with
+``backend="pallas", interpret=False`` against shapes placed on one chip
+of a ``v5e:2x2`` topology that is described, not attached, and lets the
+TPU compiler accept or refuse it — the tiling, VMEM and memory rules
+the Pallas interpreter cannot check. Both launches (§4.3 segment tables
+and the per-block/per-tile tables) run at real widths (n = 256,
+kf = 128) with ``k`` in the tens of thousands, so B, X and Y are
+fetched row by row from HBM.
+
+The topology is described inside a module fixture (never at import):
+one process at a time may load the TPU library, and every test worker
+imports this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.api import ExecSpec
+from repro.core.sddmm import LibraSDDMM
+from repro.core.spmm import LibraSpMM
+from repro.kernels.ops import sddmm_apply, spmm_apply
+from repro.sparse.generate import block_graph, power_law_graph
+
+N, KF = 256, 128
+NODES, EDGES = 20_000, 140_000
+HBM_BYTES = 16 * 2**30
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "no TPU compiler"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A described-chip compile is written to the persistent cache but
+    # cannot be read back without a chip: keep the cache out of it.
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.fixture(scope="module")
+def graphs():
+    return {"powerlaw": power_law_graph(NODES, EDGES, seed=0),
+            "block": block_graph(NODES, EDGES, seed=1)}
+
+
+def _shapes(tree, sharding):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        dict(tree))
+
+
+def _check(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert used < HBM_BYTES, used
+
+
+@pytest.mark.parametrize("segmented", [True, False],
+                         ids=["segmented", "legacy"])
+@pytest.mark.parametrize("graph", ["powerlaw", "block"])
+def test_spmm_apply_compiles_for_v5e(one_chip, graphs, graph, segmented):
+    a = graphs[graph]
+    op = LibraSpMM(a, spec=ExecSpec(backend="pallas", tune="model",
+                                    tune_n=N))
+    cfg = op.tune_config if segmented else op.tune_config.replace(ts=0,
+                                                                  cs=0)
+    arrs = op.arrays.for_backend("pallas", segmented=segmented)
+    assert any("_seg_" in k for k in arrs) == segmented
+    b = jax.ShapeDtypeStruct((a.k, N), jnp.float32, sharding=one_chip)
+    _check(spmm_apply.lower(_shapes(arrs, one_chip), b, m=op.m,
+                            nwin=op.nwin, backend="pallas", cfg=cfg,
+                            interpret=False).compile())
+
+
+@pytest.mark.parametrize("segmented", [True, False],
+                         ids=["segmented", "legacy"])
+@pytest.mark.parametrize("graph", ["powerlaw", "block"])
+def test_sddmm_apply_compiles_for_v5e(one_chip, graphs, graph, segmented):
+    a = graphs[graph]
+    op = LibraSDDMM(a, spec=ExecSpec(backend="pallas", tune="model",
+                                     tune_kf=KF))
+    cfg = op.tune_config if segmented else op.tune_config.replace(ts=0,
+                                                                  cs=0)
+    arrs = op.arrays.for_backend("pallas", segmented=segmented)
+    x = jax.ShapeDtypeStruct((a.m, KF), jnp.float32, sharding=one_chip)
+    y = jax.ShapeDtypeStruct((a.k, KF), jnp.float32, sharding=one_chip)
+    _check(sddmm_apply.lower(_shapes(arrs, one_chip), x, y, nnz=op.nnz,
+                             backend="pallas", cfg=cfg,
+                             interpret=False).compile())

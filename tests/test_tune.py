@@ -46,7 +46,7 @@ def _sparse(m, k, nnz, seed=0):
 
 def _int_valued(a):
     """Same pattern, small-integer values: float addition is exact, so
-    any reassociation (different kt/threshold/grid order) must be
+    any reassociation (different nt/threshold/grid order) must be
     bit-identical."""
     rng = np.random.default_rng(7)
     data = rng.integers(1, 4, a.nnz).astype(np.float32)
@@ -55,7 +55,7 @@ def _int_valued(a):
 
 # ------------------------------------------------------------- model ---
 def test_model_within_budget_for_every_benchmark_matrix():
-    """Acceptance: tune="model" sizes kt/nt (and kf_tile/yt) inside the
+    """Acceptance: tune="model" sizes nt (and kf_tile) inside the
     stated VMEM budget for the whole benchmark corpus."""
     for name, a in corpus().items():
         cfg = model_tune_spmm(a)
@@ -63,13 +63,12 @@ def test_model_within_budget_for_every_benchmark_matrix():
         assert step <= VMEM_BUDGET_BYTES, (name, cfg, step)
         assert occupancy_report(step)["fits"]
         cfg_sd = model_tune_sddmm(a)
-        step_sd = vmem_sddmm_bytes(cfg_sd, bk=cfg_sd.bk, ts=cfg_sd.ts_tile,
-                                   m_rows=a.m, kcols=a.k)
+        step_sd = vmem_sddmm_bytes(cfg_sd, bk=cfg_sd.bk, ts=cfg_sd.ts_tile)
         assert step_sd <= VMEM_BUDGET_BYTES, (name, cfg_sd, step_sd)
 
 
 @pytest.mark.parametrize("m,k,nnz,n", [
-    (16, 1_000_000, 50, 128),    # huge k: kt must bound the B panel
+    (16, 1_000_000, 50, 128),    # huge k: no footprint grows with k
     (8, 8, 1, 4096),             # huge n: nt stays a lane multiple
     (4096, 4096, 2000, 512),     # big both ways
     (61, 93, 37, 37),            # nothing aligned
@@ -79,19 +78,18 @@ def test_model_spmm_budget_adversarial(m, k, nnz, n):
     cfg = model_tune_spmm(a, n=n)
     step = vmem_spmm_bytes(cfg, bk=cfg.bk, ts=cfg.ts_tile)
     assert step <= VMEM_BUDGET_BYTES, (cfg, step)
-    assert cfg.kt % 8 == 0 and cfg.nt % 128 == 0
+    assert cfg.nt % 128 == 0
 
 
 @pytest.mark.parametrize("m,k,nnz,kf", [
-    (64, 500_000, 100, 128),     # huge kcols: yt must bound the Y panel
+    (64, 500_000, 100, 128),     # huge kcols: no footprint grows with it
     (64, 64, 200, 8192),         # huge feature dim: kf_tile bounds it
     (8192, 1024, 3000, 256),     # tall X (the documented residual term)
 ])
 def test_model_sddmm_budget_adversarial(m, k, nnz, kf):
     a = _sparse(m, k, nnz, seed=m + k + kf)
     cfg = model_tune_sddmm(a, kf=kf)
-    step = vmem_sddmm_bytes(cfg, bk=cfg.bk, ts=cfg.ts_tile, m_rows=m,
-                            kcols=k)
+    step = vmem_sddmm_bytes(cfg, bk=cfg.bk, ts=cfg.ts_tile)
     assert step <= VMEM_BUDGET_BYTES, (cfg, step)
 
 
@@ -131,17 +129,15 @@ def test_explicit_bk_ts_tile_reach_tuner_and_plan():
 
 def test_tall_x_streams_inside_budget():
     """Very tall X used to be un-fittable (the VPU kernel kept full X
-    feature tiles resident); with ``xt`` streaming the model bounds the
-    X panel instead of warning."""
+    feature tiles resident); X now stays in HBM and a step fetches only
+    the rows its ids name, so the model fits without warning."""
     import warnings as _warnings
 
     a = _sparse(50_000, 64, 200, seed=1)
     with _warnings.catch_warnings():
         _warnings.simplefilter("error", RuntimeWarning)
         cfg = model_tune_sddmm(a, kf=128)
-    assert cfg.xt is not None and cfg.xt < a.m
-    step = vmem_sddmm_bytes(cfg, bk=cfg.bk, ts=cfg.ts_tile, m_rows=a.m,
-                            kcols=a.k)
+    step = vmem_sddmm_bytes(cfg, bk=cfg.bk, ts=cfg.ts_tile)
     assert step <= VMEM_BUDGET_BYTES
 
 
@@ -211,7 +207,7 @@ def test_cache_roundtrip_and_signature_invalidation(tmp_path):
     key = tune_key(a, op="spmm", width=128, dtype="float32", backend="xla",
                    mode="hybrid", tune="search")
     assert pc.get(key) is None
-    cfg = TuneConfig(kt=256, nt=128, threshold=4, source="search")
+    cfg = TuneConfig(nt=256, threshold=4, source="search")
     pc.put(key, cfg)
     got = pc.get(key)
     assert got == cfg.replace(source="cache")
@@ -274,16 +270,16 @@ def test_cache_size_cap_evicts_lru(tmp_path, monkeypatch):
 
     pc = PlanCache(str(tmp_path), max_entries=3)
     for i in range(6):
-        pc.put(f"k{i}", TuneConfig(kt=8 * (i + 1)))
+        pc.put(f"k{i}", TuneConfig(nt=128 * (i + 1)))
         _time.sleep(0.01)   # distinct mtimes on coarse filesystems
     assert pc.size() == 3
     assert pc.get("k0") is None and pc.get("k1") is None
-    assert pc.get("k5").kt == 48
+    assert pc.get("k5").nt == 768
     # a hit refreshes recency: k3 survives the next eviction, k4 goes
     _time.sleep(0.01)
     assert pc.get("k3") is not None
     _time.sleep(0.01)
-    pc.put("k6", TuneConfig(kt=64))
+    pc.put("k6", TuneConfig(nt=256))
     assert pc.get("k3") is not None and pc.get("k4") is None
     # env override for the default cap
     monkeypatch.setenv("REPRO_TUNE_CACHE_MAX", "7")
@@ -301,7 +297,7 @@ def test_cache_concurrent_writers_same_key(tmp_path):
     def writer(i):
         try:
             for j in range(25):
-                pc.put("shared", TuneConfig(kt=8 * (1 + (i + j) % 4)))
+                pc.put("shared", TuneConfig(nt=128 * (1 + (i + j) % 4)))
                 got = pc.get("shared")
                 assert got is None or got.source == "cache"
         except Exception as e:  # pragma: no cover - failure path
@@ -314,7 +310,7 @@ def test_cache_concurrent_writers_same_key(tmp_path):
         t.join()
     assert not errors
     got = pc.get("shared")
-    assert got is not None and got.kt in (8, 16, 24, 32)
+    assert got is not None and got.nt in (128, 256, 384, 512)
     assert pc.size() == 1
 
 
@@ -324,8 +320,8 @@ def test_tuned_configs_bit_identical_outputs_spmm(rng):
     b = jnp.asarray(rng.integers(-2, 3, (a.k, 160)).astype(np.float32))
     ref_out = None
     configs = ["off", "model",
-               TuneConfig(kt=16, nt=128, threshold=2),
-               TuneConfig(kt=32, nt=128, grid_order="block_outer")]
+               TuneConfig(nt=128, threshold=2),
+               TuneConfig(nt=128, grid_order="block_outer")]
     for tune in configs:
         op = LibraSpMM(a, tune=tune)
         for backend in ("xla", "pallas"):
@@ -340,10 +336,10 @@ def test_tuned_configs_bit_identical_outputs_sddmm(rng):
     x = jnp.asarray(rng.integers(-2, 3, (a.m, 64)).astype(np.float32))
     y = jnp.asarray(rng.integers(-2, 3, (a.k, 64)).astype(np.float32))
     ref_out = None
-    for tune in ("off", "model", TuneConfig(yt=16, kf_tile=128),
-                 TuneConfig(yt=8, threshold=8),
-                 TuneConfig(xt=16, yt=16),     # X+Y panels stream together
-                 TuneConfig(xt=8)):            # X streams, Y resident
+    for tune in ("off", "model", TuneConfig(kf_tile=128),
+                 TuneConfig(threshold=8),
+                 TuneConfig(threshold=1),          # everything on the MXU
+                 TuneConfig(threshold=8, ts=0, cs=0)):  # per-block launch
 
         op = LibraSDDMM(a, tune=tune)
         for backend in ("xla", "pallas"):
@@ -354,10 +350,12 @@ def test_tuned_configs_bit_identical_outputs_sddmm(rng):
 
 
 def test_block_outer_downgrade_on_shared_ranks(rng):
-    """A matrix with multi-block windows makes block_outer illegal; ops
-    must silently downgrade to n_outer and stay correct."""
+    """Multi-block windows on the per-block launch: every grid step owns
+    its output block, so block_outer stays legal (no downgrade) and the
+    combine sums the blocks that share a window."""
     a = banded_csr(64, 256, 48, 1.0, seed=10)  # 48 vecs/window > bk=32
-    op = LibraSpMM(a, tune=TuneConfig(kt=64, grid_order="block_outer"))
+    op = LibraSpMM(a, tune=TuneConfig(grid_order="block_outer", ts=0,
+                                      cs=0))
     assert op.plan.tc.nblk > op.plan.tc.n_active
     b = rng.standard_normal((a.k, 256)).astype(np.float32)
     out = np.asarray(op(jnp.asarray(b), backend="pallas"))
@@ -365,15 +363,15 @@ def test_block_outer_downgrade_on_shared_ranks(rng):
 
 
 def test_sddmm_huge_kcols_streams_y(rng):
-    """kcols ≫ yt (and not a multiple): the Y panel sweep must cover
-    every column exactly once, including the padded tail panel."""
+    """kcols in the thousands (not a tile multiple): the id-driven row
+    fetch must reach every column, the last included."""
     a = _sparse(40, 5000, 300, seed=11)
     x = rng.standard_normal((a.m, 32)).astype(np.float32)
     y = rng.standard_normal((a.k, 32)).astype(np.float32)
     from repro.kernels import ref
 
     oracle = np.asarray(ref.sddmm_dense_oracle(a.to_dense(), x, y))
-    op = LibraSDDMM(a, tune=TuneConfig(yt=256))
+    op = LibraSDDMM(a, tune="model")
     out = np.asarray(op(jnp.asarray(x), jnp.asarray(y), backend="pallas"))
     np.testing.assert_allclose(out, oracle, rtol=1e-3, atol=1e-3)
 
@@ -383,6 +381,6 @@ def test_tune_off_reproduces_legacy_defaults():
     op = LibraSpMM(a, tune="off")
     assert op.plan.threshold == preprocess.DEFAULT_SPMM_THRESHOLD
     assert op.plan.tc.bk == preprocess.DEFAULT_BK_SPMM
-    assert op.tune_config.kt == 512 and op.tune_config.nt == 128
+    assert op.tune_config.nt == 128
     with pytest.raises(ValueError):
         LibraSpMM(a, tune="bogus")
