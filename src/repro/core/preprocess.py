@@ -33,7 +33,7 @@ from repro.core.formats import (
     WINDOW,
 )
 from repro.core.windows import extract_windows, num_windows
-from repro.obs.trace import get_tracer
+from repro.obs.trace import NULL_SPAN, get_tracer
 from repro.sparse.matrix import SparseCSR
 from repro.tune.model import TuneConfig, matrix_features
 
@@ -348,8 +348,9 @@ def preprocess_spmm(
     }
     assert tc_nnz + vpu_nnz == a.nnz, (tc_nnz, vpu_nnz, a.nnz)
     ph.close()
-    root.set(tc_ratio=meta["tc_ratio"]).close()
-    return SpMMPlan(a.m, a.k, a.nnz, threshold, tc, vpu, meta)
+    plan = SpMMPlan(a.m, a.k, a.nnz, threshold, tc, vpu, meta)
+    _close_with_counts(root, plan, "spmm")
+    return plan
 
 
 def _empty_spmm_plan(a, threshold, bk, ts_tile, balance) -> SpMMPlan:
@@ -644,8 +645,21 @@ def preprocess_sddmm(
     }
     assert tc_nnz + n_el == a.nnz
     ph.close()
-    root.set(tc_ratio=meta["tc_ratio"]).close()
-    return SDDMMPlan(a.m, a.k, a.nnz, threshold, tc, tc_out_pos, vpu, meta)
+    plan = SDDMMPlan(a.m, a.k, a.nnz, threshold, tc, tc_out_pos, vpu, meta)
+    _close_with_counts(root, plan, "sddmm")
+    return plan
+
+
+def _close_with_counts(root, plan, kind: str) -> None:
+    """Close a ``preprocess.*`` root span with the plan's TC ratio and,
+    on an enabled tracer only, its stream counts
+    (:func:`repro.obs.explain.plan_counts`)."""
+    root.set(tc_ratio=plan.meta["tc_ratio"])
+    if root is not NULL_SPAN:
+        from repro.obs.explain import plan_counts
+
+        root.set(**plan_counts(plan, kind))
+    root.close()
 
 
 def preprocess_spmm_loop(a: SparseCSR, threshold: int = DEFAULT_SPMM_THRESHOLD,

@@ -89,15 +89,19 @@ class DistGraphOps:
         return self._spmm(self.part, b)
 
     # -- sharded applies with this object's mesh/backend knobs ------------
+    # Each apply runs under the operator's named scope, as on one device
+    # (repro.models.gnn).
     def _spmm(self, part, b, edge_vals=None):
-        return spmm_sharded(part, b, mesh=self.mesh, axis=self.axis,
-                            backend=self.backend, edge_vals=edge_vals,
-                            b_layout=self.b_layout)
+        with jax.named_scope("spmm"):
+            return spmm_sharded(part, b, mesh=self.mesh, axis=self.axis,
+                                backend=self.backend, edge_vals=edge_vals,
+                                b_layout=self.b_layout)
 
     def _sddmm(self, x, y):
-        return sddmm_sharded(self.part_sd, x, y, mesh=self.mesh,
-                             axis=self.axis, backend=self.backend,
-                             y_layout=self.b_layout)
+        with jax.named_scope("sddmm"):
+            return sddmm_sharded(self.part_sd, x, y, mesh=self.mesh,
+                                 axis=self.axis, backend=self.backend,
+                                 y_layout=self.b_layout)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -151,14 +155,16 @@ def gcn_loss(params, g, feats, labels, norm_edge_vals):
 
 def make_gcn_train_step(g, lr: float = 0.2):
     """Jitted SGD step: works with GraphOps (single-device) and
-    DistGraphOps (mesh) alike — the mesh rides inside the sharded ops."""
+    DistGraphOps (mesh) alike — the mesh rides inside the sharded ops.
+    Its jitted name, ``gcn_train_step``, names the step's module in a
+    profile."""
     @jax.jit
-    def step(params, feats, labels, norm_edge_vals):
+    def gcn_train_step(params, feats, labels, norm_edge_vals):
         loss, grads = jax.value_and_grad(gcn_loss)(
             params, g, feats, labels, norm_edge_vals)
         new = jax.tree.map(lambda p, gg: p - lr * gg, params, grads)
         return new, loss
-    return step
+    return gcn_train_step
 
 
 def agnn_loss(params, g, feats, labels):
@@ -170,13 +176,14 @@ def agnn_loss(params, g, feats, labels):
 
 
 def make_agnn_train_step(g, lr: float = 0.2):
-    """Jitted SGD step for AGNN (SDDMM → edge softmax → SpMM per layer)."""
+    """Jitted SGD step for AGNN (SDDMM → edge softmax → SpMM per layer),
+    named ``agnn_train_step`` in a profile."""
     @jax.jit
-    def step(params, feats, labels):
+    def agnn_train_step(params, feats, labels):
         loss, grads = jax.value_and_grad(agnn_loss)(params, g, feats, labels)
         new = jax.tree.map(lambda p, gg: p - lr * gg, params, grads)
         return new, loss
-    return step
+    return agnn_train_step
 
 
 __all__ = [

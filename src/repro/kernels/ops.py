@@ -37,6 +37,11 @@ passes:
    accumulate while non-atomic ones degenerate to stores.
    ``TuneConfig(ts=0, cs=0)`` falls back to the per-block/per-tile
    launch.
+
+In a profile, each apply's ops fall under the named scopes ``mxu``
+(padding and the MXU kernel), ``vpu`` (the VPU kernel) and ``combine``
+(the epilogue), and the kernels carry their own names (``spmm_mxu``,
+``spmm_vpu``, ``sddmm_mxu``, ``sddmm_vpu``).
 """
 from __future__ import annotations
 
@@ -173,31 +178,30 @@ def spmm_apply(arrs, b, *, m: int, nwin: int, backend: str = "xla",
     if backend == "xla":
         return ref.spmm_hybrid_ref(arrs, b, m, nwin)
     nt = cfg.nt
-    b_p = _pad_to(b, 1, nt)
-    if "tc_seg_vals" in arrs:
-        # Segment-granular launch (§4.3 Ts decomposition): one grid step
-        # per segment of ≤ ts blocks of one window.
-        tc = spmm_mxu(arrs["tc_seg_vals"], arrs["tc_seg_cols"], b_p, nt=nt,
-                      grid_order=cfg.grid_order, interpret=interpret)
-        tc_win = arrs["tc_seg_row"][::WINDOW] // WINDOW
-    else:
-        # Per-block launch: each block's 8 output rows are its window's
-        # (compacted rank → window); blocks sharing a window add up in
-        # the combine below.
-        tc = spmm_mxu(arrs["tc_vals"], arrs["tc_cols"], b_p, nt=nt,
-                      grid_order=cfg.grid_order, interpret=interpret)
-        tc_win = (arrs["tc_active_row"][::WINDOW] // WINDOW)[arrs["tc_rank"]]
-    if "vpu_seg_vals" in arrs:
-        # §4.3 Cs decomposition: one row-segment of ≤ cs residual
-        # elements per tile (same kernel, wider tiles).
-        partials = spmm_vpu(arrs["vpu_seg_vals"], arrs["vpu_seg_cols"],
-                            b_p, nt=nt, grid_order=cfg.grid_order,
-                            interpret=interpret)
-        vpu_rows = arrs["vpu_seg_row"]
-    else:
-        partials = spmm_vpu(arrs["vpu_vals"], arrs["vpu_cols"], b_p, nt=nt,
-                            grid_order=cfg.grid_order, interpret=interpret)
-        vpu_rows = arrs["vpu_row"]
+    with jax.named_scope("mxu"):
+        b_p = _pad_to(b, 1, nt)
+        if "tc_seg_vals" in arrs:
+            # Segment-granular launch (§4.3 Ts decomposition): one grid
+            # step per segment of ≤ ts blocks of one window.
+            tc = spmm_mxu(arrs["tc_seg_vals"], arrs["tc_seg_cols"], b_p,
+                          nt=nt, grid_order=cfg.grid_order,
+                          interpret=interpret)
+        else:
+            tc = spmm_mxu(arrs["tc_vals"], arrs["tc_cols"], b_p, nt=nt,
+                          grid_order=cfg.grid_order, interpret=interpret)
+    with jax.named_scope("vpu"):
+        if "vpu_seg_vals" in arrs:
+            # §4.3 Cs decomposition: one row-segment of ≤ cs residual
+            # elements per tile (same kernel, wider tiles).
+            partials = spmm_vpu(arrs["vpu_seg_vals"], arrs["vpu_seg_cols"],
+                                b_p, nt=nt, grid_order=cfg.grid_order,
+                                interpret=interpret)
+            vpu_rows = arrs["vpu_seg_row"]
+        else:
+            partials = spmm_vpu(arrs["vpu_vals"], arrs["vpu_cols"], b_p,
+                                nt=nt, grid_order=cfg.grid_order,
+                                interpret=interpret)
+            vpu_rows = arrs["vpu_row"]
     # Fused combine epilogue: the TC partials sum into their windows of
     # a zero C, then one scatter-add lays the VPU partials over it (rows
     # ≥ m from the padded last window are sliced off). Under the
@@ -209,11 +213,20 @@ def spmm_apply(arrs, b, *, m: int, nwin: int, backend: str = "xla",
     # and VPU rows non-decreasing (padding repeats the last row), so both
     # sums are sorted scatters — an unsorted one makes the TPU compiler
     # sort the updates, tens of seconds of compile at graph scale.
-    out = jax.ops.segment_sum(
-        tc.reshape(-1, WINDOW, tc.shape[-1]), tc_win, num_segments=nwin,
-        indices_are_sorted=True).reshape(nwin * WINDOW, -1)
-    out = out.at[vpu_rows].add(partials, indices_are_sorted=True)
-    return out[:m, :n0]
+    with jax.named_scope("combine"):
+        if "tc_seg_vals" in arrs:
+            tc_win = arrs["tc_seg_row"][::WINDOW] // WINDOW
+        else:
+            # Per-block launch: each block's 8 output rows are its
+            # window's (compacted rank → window); blocks sharing a window
+            # add up here.
+            tc_win = (arrs["tc_active_row"][::WINDOW]
+                      // WINDOW)[arrs["tc_rank"]]
+        out = jax.ops.segment_sum(
+            tc.reshape(-1, WINDOW, tc.shape[-1]), tc_win, num_segments=nwin,
+            indices_are_sorted=True).reshape(nwin * WINDOW, -1)
+        out = out.at[vpu_rows].add(partials, indices_are_sorted=True)
+        return out[:m, :n0]
 
 
 def map_batch(backend: str, fn, *xs):
@@ -279,40 +292,43 @@ def sddmm_apply(arrs, x, y, *, nnz: int, backend: str = "xla",
     if backend == "xla":
         return ref.sddmm_hybrid_ref(arrs, _pad_to(x, 0, WINDOW), y, nnz)
     kft = cfg.kf_tile
-    x = _pad_to(x, 1, kft)
-    y = _pad_to(y, 1, kft)
-    x_p = _pad_to(x, 0, WINDOW)
-    if "tc_seg_cols" in arrs:
-        # §4.3 Ts decomposition: one grid step scores a whole segment of
-        # ≤ ts blocks sharing a window — one 8×kf @ kf×(ts·bk) dot,
-        # bitmap-sampled (zero bitmap padding samples to zero and its
-        # out_pos −1 lands in the scatter's swallow slot).
-        s_tc = sddmm_mxu(arrs["tc_seg_cols"], arrs["tc_seg_bitmap"],
-                         arrs["tc_seg_window"], x_p, y, kf_tile=kft,
-                         interpret=interpret)
-        tc_pos_src = arrs["tc_seg_out_pos"]
-    else:
-        s_tc = sddmm_mxu(arrs["tc_cols"], arrs["tc_bitmap"],
-                         arrs["tc_window"], x_p, y, kf_tile=kft,
-                         interpret=interpret)
-        tc_pos_src = arrs["tc_out_pos"]
-    if "vpu_seg_rows" in arrs:
-        # Cs cap batches whole element tiles per VPU grid step.
-        vpu_mask = arrs["vpu_seg_mask"]
-        s_el = sddmm_vpu(arrs["vpu_seg_rows"], arrs["vpu_seg_cols"], x, y,
-                         kf_tile=kft, interpret=interpret)
-        el_pos_src = arrs["vpu_seg_out_pos"]
-    else:
-        vpu_mask = arrs["vpu_mask"]
-        s_el = sddmm_vpu(arrs["vpu_rows"], arrs["vpu_cols"], x, y,
-                         kf_tile=kft, interpret=interpret)
-        el_pos_src = arrs["vpu_out_pos"]
-    s_el = jnp.where(vpu_mask, s_el, 0.0)
+    with jax.named_scope("mxu"):
+        x = _pad_to(x, 1, kft)
+        y = _pad_to(y, 1, kft)
+        x_p = _pad_to(x, 0, WINDOW)
+        if "tc_seg_cols" in arrs:
+            # §4.3 Ts decomposition: one grid step scores a whole segment
+            # of ≤ ts blocks sharing a window — one 8×kf @ kf×(ts·bk)
+            # dot, bitmap-sampled (zero bitmap padding samples to zero
+            # and its out_pos −1 lands in the scatter's swallow slot).
+            s_tc = sddmm_mxu(arrs["tc_seg_cols"], arrs["tc_seg_bitmap"],
+                             arrs["tc_seg_window"], x_p, y, kf_tile=kft,
+                             interpret=interpret)
+            tc_pos_src = arrs["tc_seg_out_pos"]
+        else:
+            s_tc = sddmm_mxu(arrs["tc_cols"], arrs["tc_bitmap"],
+                             arrs["tc_window"], x_p, y, kf_tile=kft,
+                             interpret=interpret)
+            tc_pos_src = arrs["tc_out_pos"]
+    with jax.named_scope("vpu"):
+        if "vpu_seg_rows" in arrs:
+            # Cs cap batches whole element tiles per VPU grid step.
+            vpu_mask = arrs["vpu_seg_mask"]
+            s_el = sddmm_vpu(arrs["vpu_seg_rows"], arrs["vpu_seg_cols"], x,
+                             y, kf_tile=kft, interpret=interpret)
+            el_pos_src = arrs["vpu_seg_out_pos"]
+        else:
+            vpu_mask = arrs["vpu_mask"]
+            s_el = sddmm_vpu(arrs["vpu_rows"], arrs["vpu_cols"], x, y,
+                             kf_tile=kft, interpret=interpret)
+            el_pos_src = arrs["vpu_out_pos"]
     # Fused combine: one scatter of both streams into the canonical nnz
     # vector (slot nnz swallows -1/masked padding).
-    pos_tc = jnp.where(tc_pos_src >= 0, tc_pos_src, nnz)
-    pos_el = jnp.where(vpu_mask, el_pos_src, nnz)
-    pos = jnp.concatenate([pos_tc.reshape(-1), pos_el.reshape(-1)])
-    data = jnp.concatenate([s_tc.reshape(-1), s_el.reshape(-1)])
-    out = jnp.zeros((nnz + 1,), s_tc.dtype).at[pos].add(data)
-    return out[:nnz]
+    with jax.named_scope("combine"):
+        s_el = jnp.where(vpu_mask, s_el, 0.0)
+        pos_tc = jnp.where(tc_pos_src >= 0, tc_pos_src, nnz)
+        pos_el = jnp.where(vpu_mask, el_pos_src, nnz)
+        pos = jnp.concatenate([pos_tc.reshape(-1), pos_el.reshape(-1)])
+        data = jnp.concatenate([s_tc.reshape(-1), s_el.reshape(-1)])
+        out = jnp.zeros((nnz + 1,), s_tc.dtype).at[pos].add(data)
+        return out[:nnz]

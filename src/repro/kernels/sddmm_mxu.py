@@ -92,6 +92,7 @@ def sddmm_mxu(tc_cols, tc_bitmap, tc_window, x, y, *, kf_tile: int = 128,
 
     return pl.pallas_call(
         _kernel,
+        name="sddmm_mxu",
         grid=(nb, kf // kf_tile),
         in_specs=[
             pl.BlockSpec((1, 1, bk), lambda i, f: (i, 0, 0),

@@ -75,6 +75,7 @@ def sddmm_vpu(rows, cols, x, y, *, kf_tile: int = 128,
 
     out = pl.pallas_call(
         _kernel,
+        name="sddmm_vpu",
         grid=(ngroups, kf // kf_tile),
         in_specs=[ids, ids, pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],

@@ -92,6 +92,7 @@ def spmm_mxu(tc_vals, tc_cols, b, *, nt: int = 128,
 
     out = pl.pallas_call(
         functools.partial(_kernel, lane_axis=lane_axis),
+        name="spmm_mxu",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, bk), cols_map, memory_space=pltpu.SMEM),

@@ -87,6 +87,7 @@ def spmm_vpu(vpu_vals, vpu_cols, b, *, nt: int = 128,
 
     out = pl.pallas_call(
         functools.partial(_kernel, lane_axis=lane_axis),
+        name="spmm_vpu",
         grid=grid,
         in_specs=[
             pl.BlockSpec((GROUP, ts), tile_map,

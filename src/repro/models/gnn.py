@@ -20,6 +20,7 @@ from repro.core.formats import device_arrays
 from repro.core.windows import num_windows
 from repro.kernels import ref
 from repro.kernels.ops import sddmm_apply, spmm_apply
+from repro.obs.trace import get_tracer
 from repro.sparse.matrix import SparseCSR, coo_to_csr
 
 
@@ -62,6 +63,12 @@ class GraphOps:
     canonical order at build time and the row permutes ride inside the
     differentiable applies — so edge values, the Aᵀ edge permutation
     and the softmax segment ids never change.
+
+    Under an enabled :mod:`repro.obs.trace` tracer the build is one
+    ``graphops.build`` span whose children cover it: ``graphops.transpose``,
+    ``graphops.features``, one ``graphops.leg`` per plan (attribute
+    ``leg`` = ``spmm``, ``spmm_t`` or ``sddmm``; it holds that leg's
+    tune, preprocess and upload wrapping) and ``graphops.edges``.
     """
 
     def __init__(self, a: SparseCSR, mode=UNSET, spmm_threshold=UNSET,
@@ -80,32 +87,33 @@ class GraphOps:
         self.nnz = a.nnz
         self.backend = spec.backend
         self.nwin = num_windows(a.m)
-        at, self.perm = transpose_csr(a)
-        self.nwin_t = num_windows(at.m)
-        # One feature pass per matrix, shared by the SpMM and SDDMM tuners.
-        feat_a = matrix_features(a) if spec.tune == "model" else None
-        built = preprocess.Plan.build(a, "spmm", spec, feat=feat_a)
-        built_t = preprocess.Plan.build(at, "spmm", spec)
-        built_sd = preprocess.Plan.build(a, "sddmm", spec, feat=feat_a)
-        self.cfg, self.cfg_t = built.cfg, built_t.cfg
-        self.cfg_sd = built_sd.cfg
-        self.arrs = device_arrays(built.plan)
-        self.arrs_t = device_arrays(built_t.plan)
-        self.arrs_sd = device_arrays(built_sd.plan)
-        # Per-leg reorder epilogues/prologues (None when not reordered):
-        # the plans' nnz maps already point at each leg's own original
-        # canonical order, so values flow unchanged — only rows permute.
-        self._unperm = (None if built.reorder is None
-                        else jnp.asarray(built.reorder.row_inv))
-        self._unperm_t = (None if built_t.reorder is None
-                          else jnp.asarray(built_t.reorder.row_inv))
-        self._x_perm = (None if built_sd.reorder is None
-                        else jnp.asarray(built_sd.reorder.row_perm))
-        self.perm_dev = jnp.asarray(self.perm)
-        # Row id per edge (for softmax over incident edges).
-        rows, _, _ = a.to_coo()
-        self.edge_row = jnp.asarray(rows, jnp.int32)
-        self.edge_col = jnp.asarray(a.indices, jnp.int32)
+        tr = get_tracer()
+        with tr.span("graphops.build", m=a.m, k=a.k, nnz=a.nnz):
+            with tr.span("graphops.transpose"):
+                at, self.perm = transpose_csr(a)
+            self.nwin_t = num_windows(at.m)
+            # One feature pass per matrix, shared by the SpMM and SDDMM
+            # tuners.
+            with tr.span("graphops.features"):
+                feat_a = matrix_features(a) if spec.tune == "model" else None
+            # Per-leg reorder epilogues/prologues (None when not
+            # reordered): the plans' nnz maps already point at each leg's
+            # own original canonical order, so values flow unchanged —
+            # only rows permute.
+            built, self.arrs, self._unperm = _build_leg(
+                tr, "spmm", a, spec, feat_a)
+            built_t, self.arrs_t, self._unperm_t = _build_leg(
+                tr, "spmm_t", at, spec, None)
+            built_sd, self.arrs_sd, self._x_perm = _build_leg(
+                tr, "sddmm", a, spec, feat_a)
+            self.cfg, self.cfg_t = built.cfg, built_t.cfg
+            self.cfg_sd = built_sd.cfg
+            with tr.span("graphops.edges"):
+                self.perm_dev = jnp.asarray(self.perm)
+                # Row id per edge (for softmax over incident edges).
+                rows, _, _ = a.to_coo()
+                self.edge_row = jnp.asarray(rows, jnp.int32)
+                self.edge_col = jnp.asarray(a.indices, jnp.int32)
 
     # -- differentiable ops ------------------------------------------------
     def spmm(self, edge_vals, b):
@@ -118,9 +126,26 @@ class GraphOps:
 
     def fixed_spmm(self, b, backend: str | None = None):
         """C = A @ B with the plan's baked-in values (no grad wrt values)."""
-        out = spmm_apply(self.arrs, b, m=self.m, nwin=self.nwin,
-                         backend=backend or self.backend, cfg=self.cfg)
-        return _unreorder(out, self._unperm)
+        with jax.named_scope("spmm"):
+            out = spmm_apply(self.arrs, b, m=self.m, nwin=self.nwin,
+                             backend=backend or self.backend, cfg=self.cfg)
+            return _unreorder(out, self._unperm)
+
+
+def _build_leg(tr, leg: str, a: SparseCSR, spec, feat):
+    """One plan of a :class:`GraphOps` under its ``graphops.leg`` span:
+    the built plan, its device arrays and its row permutation on the
+    device (the inverse for an SpMM leg, the forward one for SDDMM;
+    ``None`` when not reordered)."""
+    op = "sddmm" if leg == "sddmm" else "spmm"
+    with tr.span("graphops.leg", leg=leg):
+        built = preprocess.Plan.build(a, op, spec, feat=feat)
+        arrs = device_arrays(built.plan)
+        perm = None
+        if built.reorder is not None:
+            perm = jnp.asarray(built.reorder.row_perm if op == "sddmm"
+                               else built.reorder.row_inv)
+        return built, arrs, perm
 
 
 def _unreorder(out, unperm):
@@ -133,12 +158,41 @@ def _reorder_x(x, perm):
     return x if perm is None else jnp.take(x, perm, axis=0)
 
 
+# Each sparse operator of the training step runs under one named scope —
+# ``spmm``, ``sddmm`` or ``edge_softmax`` — so a profile attributes every
+# device op to the operator that issued it. The scopes wrap each apply,
+# never a custom-VJP rule as a whole, so they never nest in one another;
+# ``spmm_apply``/``sddmm_apply`` split them further (``mxu``, ``vpu``,
+# ``combine``).
+def _spmm(g: GraphOps, edge_vals, b, *, transposed: bool = False):
+    """``A(edge_vals) @ b``, or ``A(edge_vals)ᵀ @ b`` through Aᵀ's plan
+    (``edge_vals`` in A's order), under the ``spmm`` scope: the values
+    gathered into the plan's slots (``revalue``), the apply, and the row
+    order restored (``combine``)."""
+    arrs, m, nwin, cfg, unperm = (
+        (g.arrs_t, g.k, g.nwin_t, g.cfg_t, g._unperm_t) if transposed
+        else (g.arrs, g.m, g.nwin, g.cfg, g._unperm))
+    with jax.named_scope("spmm"):
+        with jax.named_scope("revalue"):
+            if transposed:
+                edge_vals = edge_vals[g.perm_dev]
+            arrs = ref.revalue_spmm_arrays(arrs, edge_vals)
+        out = spmm_apply(arrs, b, m=m, nwin=nwin, backend=g.backend,
+                         cfg=cfg)
+        with jax.named_scope("combine"):
+            return _unreorder(out, unperm)
+
+
+def _sddmm(g: GraphOps, x, y):
+    """``vals[p] = ⟨x[row_p], y[col_p]⟩`` under the ``sddmm`` scope."""
+    with jax.named_scope("sddmm"):
+        return sddmm_apply(g.arrs_sd, _reorder_x(x, g._x_perm), y,
+                           nnz=g.nnz, backend=g.backend, cfg=g.cfg_sd)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _spmm_ev(g: GraphOps, edge_vals, b):
-    arrs = ref.revalue_spmm_arrays(g.arrs, edge_vals)
-    out = spmm_apply(arrs, b, m=g.m, nwin=g.nwin, backend=g.backend,
-                     cfg=g.cfg)
-    return _unreorder(out, g._unperm)
+    return _spmm(g, edge_vals, b)
 
 
 def _spmm_ev_fwd(g, edge_vals, b):
@@ -148,13 +202,9 @@ def _spmm_ev_fwd(g, edge_vals, b):
 def _spmm_ev_bwd(g, resid, d_c):
     edge_vals, b = resid
     # dB = A(v)^T @ dC — SpMM on the transposed plan with permuted values.
-    arrs_t = ref.revalue_spmm_arrays(g.arrs_t, edge_vals[g.perm_dev])
-    d_b = _unreorder(
-        spmm_apply(arrs_t, d_c, m=g.k, nwin=g.nwin_t, backend=g.backend,
-                   cfg=g.cfg_t), g._unperm_t)
+    d_b = _spmm(g, edge_vals, d_c, transposed=True)
     # dv[p] = dC[row_p] · B[col_p] — SDDMM with A's sparsity.
-    d_vals = sddmm_apply(g.arrs_sd, _reorder_x(d_c, g._x_perm), b,
-                         nnz=g.nnz, backend=g.backend, cfg=g.cfg_sd)
+    d_vals = _sddmm(g, d_c, b)
     return d_vals.astype(edge_vals.dtype), d_b.astype(b.dtype)
 
 
@@ -163,8 +213,7 @@ _spmm_ev.defvjp(_spmm_ev_fwd, _spmm_ev_bwd)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _sddmm_ev(g: GraphOps, x, y):
-    return sddmm_apply(g.arrs_sd, _reorder_x(x, g._x_perm), y, nnz=g.nnz,
-                       backend=g.backend, cfg=g.cfg_sd)
+    return _sddmm(g, x, y)
 
 
 def _sddmm_ev_fwd(g, x, y):
@@ -174,14 +223,8 @@ def _sddmm_ev_fwd(g, x, y):
 def _sddmm_ev_bwd(g, resid, d_vals):
     x, y = resid
     # dX = A(dv) @ Y ; dY = A(dv)^T @ X — both SpMMs through Libra plans.
-    arrs = ref.revalue_spmm_arrays(g.arrs, d_vals)
-    d_x = _unreorder(
-        spmm_apply(arrs, y, m=g.m, nwin=g.nwin, backend=g.backend,
-                   cfg=g.cfg), g._unperm)
-    arrs_t = ref.revalue_spmm_arrays(g.arrs_t, d_vals[g.perm_dev])
-    d_y = _unreorder(
-        spmm_apply(arrs_t, x, m=g.k, nwin=g.nwin_t, backend=g.backend,
-                   cfg=g.cfg_t), g._unperm_t)
+    d_x = _spmm(g, d_vals, y)
+    d_y = _spmm(g, d_vals, x, transposed=True)
     return d_x.astype(x.dtype), d_y.astype(y.dtype)
 
 
@@ -190,10 +233,11 @@ _sddmm_ev.defvjp(_sddmm_ev_fwd, _sddmm_ev_bwd)
 
 def edge_softmax(g: GraphOps, scores):
     """Numerically stable per-destination-row softmax over edge scores."""
-    mx = jax.ops.segment_max(scores, g.edge_row, num_segments=g.m)
-    e = jnp.exp(scores - mx[g.edge_row])
-    z = jax.ops.segment_sum(e, g.edge_row, num_segments=g.m)
-    return e / jnp.maximum(z[g.edge_row], 1e-9)
+    with jax.named_scope("edge_softmax"):
+        mx = jax.ops.segment_max(scores, g.edge_row, num_segments=g.m)
+        e = jnp.exp(scores - mx[g.edge_row])
+        z = jax.ops.segment_sum(e, g.edge_row, num_segments=g.m)
+        return e / jnp.maximum(z[g.edge_row], 1e-9)
 
 
 # ------------------------------------------------------------------ GCN ---

@@ -74,27 +74,45 @@ def _segment_report(plan) -> dict:
     return out
 
 
+def plan_counts(plan, kind: str) -> dict:
+    """What the condensed formats hold, stream by stream: the MXU
+    stream's non-zeros, its blocks, their width ``bk`` and their cells;
+    the VPU stream's real elements, its tiles (the segments the kernel
+    launches over), their width ``cs`` and their slots (tiles × ``cs``).
+    :func:`_padding_report` derives the padding from these, and the
+    ``preprocess.spmm``/``preprocess.sddmm`` spans of an enabled tracer
+    carry them as attributes."""
+    tc, vpu = plan.tc, plan.vpu
+    if kind == "spmm":
+        slots, vpu_nnz = vpu.vals, int(vpu.nnz)
+    else:  # COOTiles: mask marks real elements
+        slots, vpu_nnz = vpu.mask, int(vpu.mask.sum())
+    return {
+        "tc_nnz": int(tc.nnz),
+        "mxu_blocks": int(tc.cols.shape[0]),
+        "bk": int(tc.cols.shape[1]),
+        "tc_cells": int(tc.vals.size),
+        "vpu_nnz": vpu_nnz,
+        "vpu_segments": int(slots.shape[0]),
+        "cs": int(slots.shape[1]),
+        "vpu_slots": int(slots.size),
+    }
+
+
 def _padding_report(plan, kind: str) -> dict:
     """Zero padding materialized by the condensed formats (bytes the
     kernels stream but the matrix never had)."""
-    tc = plan.tc
-    tc_cells = int(tc.vals.size)
-    out = {
-        "tc_padded_zeros": int(tc.padded_zeros),
-        "tc_pad_frac": tc.padded_zeros / max(tc_cells, 1),
+    c = plan_counts(plan, kind)
+    tc_pad = c["tc_cells"] - c["tc_nnz"]
+    vpu_pad = c["vpu_slots"] - c["vpu_nnz"]
+    return {
+        "tc_padded_zeros": tc_pad,
+        "tc_pad_frac": tc_pad / max(c["tc_cells"], 1),
+        "vpu_padded_zeros": vpu_pad,
+        "vpu_pad_frac": vpu_pad / max(c["vpu_slots"], 1),
+        "total_pad_frac": (tc_pad + vpu_pad)
+        / max(c["tc_cells"] + c["vpu_slots"], 1),
     }
-    vpu = plan.vpu
-    if kind == "spmm":
-        vpu_cells = int(vpu.vals.size)
-        vpu_pad = vpu_cells - int(vpu.nnz)
-    else:  # COOTiles: mask marks real elements
-        vpu_cells = int(vpu.mask.size)
-        vpu_pad = vpu_cells - int(vpu.mask.sum())
-    out["vpu_padded_zeros"] = int(vpu_pad)
-    out["vpu_pad_frac"] = vpu_pad / max(vpu_cells, 1)
-    total_cells = tc_cells + vpu_cells
-    out["total_pad_frac"] = (tc.padded_zeros + vpu_pad) / max(total_cells, 1)
-    return out
 
 
 def _occupancy_report(cfg, plan, kind: str) -> dict | None:

@@ -19,6 +19,12 @@ Design constraints, in order:
   ``() -> float`` (the same injection idiom as
   :class:`repro.serve.faults.FaultPlan` seeding and the engine's
   ``clock=``), so tests drive deterministic timestamps.
+* **on the profiler's clock too** — once ``jax`` is imported, every span
+  of an enabled tracer also enters a ``jax.profiler.TraceAnnotation`` of
+  its name for its lifetime. Under ``jax.profiler.trace`` the spans then
+  land on the profile's host plane beside the device ops; with no
+  profile running the annotation records nothing. This module never
+  imports ``jax`` itself.
 
 Spans nest lexically through a stack (single-threaded by design — the
 whole repro stack is host-driven from one thread); exporters emit the
@@ -28,6 +34,7 @@ and a plain-dict tree.
 from __future__ import annotations
 
 import contextlib
+import sys
 import time
 from typing import Any, Callable
 
@@ -40,7 +47,7 @@ class Span:
     a zero-duration point annotation."""
 
     __slots__ = ("name", "attrs", "t0", "t1", "events", "children",
-                 "_tracer")
+                 "_tracer", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -50,10 +57,15 @@ class Span:
         self.t1: float | None = None
         self.events: list[dict] = []
         self.children: list[Span] = []
+        self._annotation = None
 
     # -- lifecycle --
     def open(self) -> "Span":
         tr = self._tracer
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is not None:
+            self._annotation = profiler.TraceAnnotation(self.name)
+            self._annotation.__enter__()
         self.t0 = tr._clock()
         stack = tr._stack
         (stack[-1].children if stack else tr.roots).append(self)
@@ -63,6 +75,9 @@ class Span:
     def close(self) -> None:
         tr = self._tracer
         self.t1 = tr._clock()
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         # Tolerate out-of-order closes (an exception skipped a close):
         # pop back to — and including — this span.
         while tr._stack:

@@ -124,3 +124,45 @@ def test_transpose_perm_roundtrip():
     np.testing.assert_array_equal(rt, cols[perm])
     np.testing.assert_array_equal(ct, rows[perm])
     np.testing.assert_allclose(vt, vals[perm])
+
+
+def _scope_names(op_name: str) -> list[str]:
+    """``jit(f)/transpose(jvp(spmm))/vpu`` → ``["f", "spmm", "vpu"]``."""
+    out = []
+    for part in op_name.split("/"):
+        while part.endswith(")") and "(" in part:
+            part = part[part.index("(") + 1:-1]
+        if part:
+            out.append(part)
+    return out
+
+
+def test_training_steps_scope_each_sparse_operator(graph):
+    """Every op of the jitted steps falls under at most one of the
+    operator scopes, and each operator the step runs has its scope."""
+    import re
+
+    from repro.dist.gnn import make_agnn_train_step, make_gcn_train_step
+
+    g = gnn.GraphOps(graph)
+    x = jnp.ones((graph.m, 8), jnp.float32)
+    labels = jnp.zeros((graph.m,), jnp.int32)
+    ev = jnp.ones((graph.nnz,), jnp.float32)
+    gcn = [{"w": jnp.ones((8, 4))}]
+    agnn = [{"w": jnp.ones((8, 4)), "beta": jnp.ones(())}]
+    texts = {
+        "gcn_train_step": make_gcn_train_step(g).lower(
+            gcn, x, labels, ev).compile().as_text(),
+        "agnn_train_step": make_agnn_train_step(g).lower(
+            agnn, x, labels).compile().as_text(),
+    }
+    operators = {"spmm", "sddmm", "edge_softmax"}
+    for name, text in texts.items():
+        seen = set()
+        for op_name in re.findall(r'op_name="([^"]*)"', text):
+            parts = _scope_names(op_name)
+            inside = operators & set(parts)
+            assert len(inside) <= 1, op_name
+            seen |= inside
+        assert f"jit({name})" in text
+        assert seen == ({"spmm"} if name == "gcn_train_step" else operators)

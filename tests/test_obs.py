@@ -560,3 +560,99 @@ class TestFlowEvents:
         evs = tr.to_chrome_trace()["traceEvents"]
         # a flow needs ≥2 points to mean anything; singletons vanish
         assert all(e.get("cat") != "repro.flow" for e in evs)
+
+
+# ---------------------------------------------- plan build and profiler ---
+class TestPlanBuildSpans:
+    @pytest.fixture(scope="class")
+    def built(self):
+        from repro.api import ExecSpec
+        from repro.models.gnn import GraphOps
+        from repro.sparse.generate import power_law_csr
+
+        a = power_law_csr(96, 96, avg_row=6.0, seed=5)
+        tr = Tracer()
+        with use_tracer(tr):
+            ops = GraphOps(a, spec=ExecSpec(tune="model"))
+        return tr, ops
+
+    def test_graphops_build_tree_has_three_legs(self, built):
+        tr, _ = built
+        (root,) = tr.roots
+        assert root.name == "graphops.build"
+        kids = root.children
+        assert [c.name for c in kids] == [
+            "graphops.transpose", "graphops.features", "graphops.leg",
+            "graphops.leg", "graphops.leg", "graphops.edges"]
+        legs = [c for c in kids if c.name == "graphops.leg"]
+        assert [c.attrs["leg"] for c in legs] == ["spmm", "spmm_t", "sddmm"]
+        for leg, pre in zip(legs, ("preprocess.spmm", "preprocess.spmm",
+                                   "preprocess.sddmm")):
+            names = [c.name for c in leg.children]
+            assert names == ["tune.model", pre], names
+        # The children tile the root in order; what is left is its self time.
+        assert all(a.t1 <= b.t0 for a, b in zip(kids, kids[1:]))
+        assert root.t0 <= kids[0].t0 and kids[-1].t1 <= root.t1
+        assert sum(c.duration for c in kids) <= root.duration
+
+    @pytest.mark.parametrize("leg", ["spmm", "spmm_t", "sddmm"])
+    def test_preprocess_counters_match_explain(self, built, leg):
+        from repro.obs.explain import explain_plan, plan_counts
+
+        tr, ops = built
+        arrs = {"spmm": ops.arrs, "spmm_t": ops.arrs_t,
+                "sddmm": ops.arrs_sd}[leg]
+        kind = "sddmm" if leg == "sddmm" else "spmm"
+        (span,) = [c.children[-1] for c in tr.roots[0].children
+                   if c.attrs.get("leg") == leg]
+        counts = plan_counts(arrs.plan, kind)
+        assert {k: span.attrs[k] for k in counts} == counts
+        pad = explain_plan(arrs.plan, kind=kind)["padding"]
+        assert counts["vpu_slots"] - counts["vpu_nnz"] \
+            == pad["vpu_padded_zeros"]
+        assert counts["tc_cells"] - counts["tc_nnz"] \
+            == pad["tc_padded_zeros"]
+        assert counts["vpu_slots"] == counts["vpu_segments"] * counts["cs"]
+        assert counts["tc_nnz"] + counts["vpu_nnz"] == arrs.plan.nnz
+
+    def test_disabled_tracer_computes_no_counters(self, monkeypatch):
+        from repro.core import preprocess
+        from repro.obs import explain
+        from repro.sparse.generate import power_law_csr
+
+        def boom(*a, **k):
+            raise AssertionError("counted with the tracer off")
+
+        monkeypatch.setattr(explain, "plan_counts", boom)
+        a = power_law_csr(64, 64, avg_row=5.0, seed=2)
+        preprocess.preprocess_spmm(a)
+        preprocess.preprocess_sddmm(a)
+
+
+def test_enabled_spans_land_on_the_profiler_host_plane(tmp_path):
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    on, off = Tracer(), Tracer(enabled=False)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with on.span("probe.enabled"):
+            with on.span("probe.inner"):
+                jnp.ones(4).block_until_ready()
+        with off.span("probe.disabled"):
+            jnp.ones(4).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    host = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    host[ev.name] = (ev.start_ns, ev.duration_ns)
+    assert "probe.enabled" in host and "probe.inner" in host
+    assert "probe.disabled" not in host
+    (s0, d0), (s1, d1) = host["probe.enabled"], host["probe.inner"]
+    assert s0 <= s1 and s1 + d1 <= s0 + d0
+    assert on.roots[0]._annotation is None    # closed with its span

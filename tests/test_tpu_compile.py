@@ -14,6 +14,7 @@ one process at a time may load the TPU library, and every test worker
 imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -103,3 +104,49 @@ def test_sddmm_apply_compiles_for_v5e(one_chip, graphs, graph, segmented):
     _check(sddmm_apply.lower(_shapes(arrs, one_chip), x, y, nnz=op.nnz,
                              backend="pallas", cfg=cfg,
                              interpret=False).compile())
+
+
+def _kernel_scopes(text: str) -> dict[str, str]:
+    """Each Pallas custom call's instruction name → its ``op_name``."""
+    out = {}
+    for line in text.splitlines():
+        if "tpu_custom_call" not in line or " = " not in line:
+            continue
+        name = line.split(" = ", 1)[0].strip().lstrip("%").removeprefix(
+            "ROOT ").lstrip("%")
+        m = re.search(r'op_name="([^"]*)"', line)
+        out[name] = m.group(1) if m else ""
+    return out
+
+
+@pytest.mark.parametrize("kind", ["spmm", "sddmm"])
+def test_applies_name_their_kernels_and_scopes(one_chip, graphs, kind):
+    """The compiled applies carry the kernel names and the ``mxu`` /
+    ``vpu`` / ``combine`` scopes that a profile is split by."""
+    a = graphs["powerlaw"]
+    if kind == "spmm":
+        op = LibraSpMM(a, spec=ExecSpec(backend="pallas", tune="model",
+                                        tune_n=N))
+        arrs = op.arrays.for_backend("pallas", segmented=True)
+        b = jax.ShapeDtypeStruct((a.k, N), jnp.float32, sharding=one_chip)
+        lowered = spmm_apply.lower(_shapes(arrs, one_chip), b, m=op.m,
+                                   nwin=op.nwin, backend="pallas",
+                                   cfg=op.tune_config, interpret=False)
+    else:
+        op = LibraSDDMM(a, spec=ExecSpec(backend="pallas", tune="model",
+                                         tune_kf=KF))
+        arrs = op.arrays.for_backend("pallas", segmented=True)
+        x = jax.ShapeDtypeStruct((a.m, KF), jnp.float32, sharding=one_chip)
+        y = jax.ShapeDtypeStruct((a.k, KF), jnp.float32, sharding=one_chip)
+        lowered = sddmm_apply.lower(_shapes(arrs, one_chip), x, y,
+                                    nnz=op.nnz, backend="pallas",
+                                    cfg=op.tune_config, interpret=False)
+    text = lowered.compile().as_text()
+    kernels = _kernel_scopes(text)
+    for stream in ("mxu", "vpu"):
+        (op_name,) = [v for k, v in kernels.items()
+                      if k.startswith(f"{kind}_{stream}")]
+        assert f"/{stream}/" in op_name, op_name
+        assert op_name.endswith(f"{kind}_{stream}/pallas_call"), op_name
+    assert len(kernels) == 2, kernels
+    assert re.search(r'op_name="[^"]*/combine/[^"]*scatter-add', text)
