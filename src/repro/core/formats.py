@@ -208,8 +208,10 @@ def _spmm_segment_arrays(plan: "SpMMPlan") -> dict[str, np.ndarray]:
     ``8×bk @ bk×n`` products equals one ``8×(ts·bk) @ (ts·bk)×n``
     product, so a segment is a single MXU dot); ``tc_seg_row`` maps its
     8 output rows to its window's rows of C. VPU: segment ``s`` owns ≤ ``cs`` residual elements (whole tiles) of
-    one row — the same kernel, a wider tile. Padding is inert: zero
-    values multiply B row 0; ``pos`` stays −1 so revaluation skips it.
+    one row — the same kernel, a wider tile; ``vpu_seg_len`` counts its
+    real elements, so the kernel fetches no B row for its padding.
+    Padding is inert: zero values, column 0, and ``pos`` −1 so
+    revaluation skips it.
     """
     out: dict[str, np.ndarray] = {}
     tc_seg = plan.meta.get("tc_segments")
@@ -250,7 +252,33 @@ def _spmm_segment_arrays(plan: "SpMMPlan") -> dict[str, np.ndarray]:
                 mask[:, :, None], vpu.pos[take], -1
             ).reshape(nseg, spt * ts).astype(np.int32)
         out["vpu_seg_row"] = row.astype(np.int32)
+        out["vpu_seg_len"] = _vpu_seg_len(vpu, take, mask)
     return out
+
+
+def _vpu_seg_len(vpu: VPUTiles, take: np.ndarray,
+                 mask: np.ndarray) -> np.ndarray:
+    """Real elements of each VPU segment, ``(nseg,)`` int32: the sum of
+    its real tiles' fills. Preprocessing fills a row's tiles from slot 0
+    and only the row's last tile is partial, so a segment's real
+    elements are a prefix of its slots and the kernel fetches B rows for
+    just that prefix. Fills come from the ``pos`` map (−1 on padding),
+    never from the values: a real edge may hold 0."""
+    if vpu.pos is None:
+        fill = np.full(vpu.ntiles, vpu.ts, np.int64)
+    else:
+        fill = (vpu.pos >= 0).sum(axis=1)
+    return (fill[take] * mask).sum(axis=1).astype(np.int32)
+
+
+def spmm_vpu_seg_len(plan: "SpMMPlan") -> np.ndarray | None:
+    """The ``vpu_seg_len`` table the segmented VPU SpMM launches with,
+    or ``None`` for a plan without VPU segment tables."""
+    vpu_seg = plan.meta.get("vpu_segments")
+    if vpu_seg is None:
+        return None
+    take, mask = _seg_take_map(vpu_seg, plan.vpu.ntiles)
+    return _vpu_seg_len(plan.vpu, take, mask)
 
 
 def _sddmm_segment_arrays(plan: "SDDMMPlan") -> dict[str, np.ndarray]:
@@ -352,7 +380,8 @@ def _host_arrays(plan) -> dict[str, np.ndarray]:
 _SPMM_TC = ("tc_vals", "tc_cols", "tc_rank", "tc_active_row")
 _SPMM_TC_SEG = ("tc_seg_vals", "tc_seg_cols", "tc_seg_row")
 _SPMM_VPU = ("vpu_vals", "vpu_cols", "vpu_row")
-_SPMM_VPU_SEG = ("vpu_seg_vals", "vpu_seg_cols", "vpu_seg_row")
+_SPMM_VPU_SEG = ("vpu_seg_vals", "vpu_seg_cols", "vpu_seg_row",
+                 "vpu_seg_len")
 _SDDMM_TC = ("tc_cols", "tc_bitmap", "tc_window", "tc_out_pos")
 _SDDMM_TC_SEG = ("tc_seg_cols", "tc_seg_bitmap", "tc_seg_window",
                  "tc_seg_out_pos")

@@ -339,8 +339,8 @@ def _stack_spmm_segments(plans, shards, n_shards,
                          pad_row: int) -> dict[str, np.ndarray]:
     """Pad/stack each shard's §4.3 segment launch tables on the leading
     shard axis. Padding segments are inert: zero values scatter zeros
-    onto local row ``pad_row`` (the last, so rows stay non-decreasing)
-    and pos −1 skips revaluation."""
+    onto local row ``pad_row`` (the last, so rows stay non-decreasing),
+    pos −1 skips revaluation and length 0 fetches no B row."""
     seg_list = [_spmm_segment_arrays(p) for p in plans]
     out: dict[str, np.ndarray] = {}
     if "tc_seg_vals" in seg_list[0]:
@@ -365,14 +365,16 @@ def _stack_spmm_segments(plans, shards, n_shards,
         cols = np.zeros((n_shards, ns, w), np.int32)
         pos = np.full((n_shards, ns, w), -1, np.int32)
         row = np.full((n_shards, ns), pad_row, np.int32)
+        seg_len = np.zeros((n_shards, ns), np.int32)
         for p, (s, sh) in enumerate(zip(seg_list, shards)):
             k = s["vpu_seg_row"].shape[0]
             vals[p, :k] = s["vpu_seg_vals"]
             cols[p, :k] = s["vpu_seg_cols"]
             pos[p, :k] = _offset_pos(s["vpu_seg_pos"], sh.nnz_start)
             row[p, :k] = s["vpu_seg_row"]
+            seg_len[p, :k] = s["vpu_seg_len"]
         out.update(vpu_seg_vals=vals, vpu_seg_cols=cols, vpu_seg_pos=pos,
-                   vpu_seg_row=row)
+                   vpu_seg_row=row, vpu_seg_len=seg_len)
     return out
 
 

@@ -9,7 +9,8 @@ plan's padded nnz, not with ``k`` — no k-panel sweep, no in-kernel
 gather on a resident panel. Each (id, lane tile) pair is copied once
 per grid step, so the exactly-once accounting is the plan's: padding
 ids carry zero values (SpMM) or are routed to the combine's swallow
-slot (SDDMM).
+slot (SDDMM). Where the plan knows that a row of ids ends in padding
+(the VPU SpMM's segment lengths), the fetch stops at its real prefix.
 
 The dense operand is viewed as ``(rows, 1, width)`` (:func:`row_view`)
 so one row is a whole trailing ``(1, width)`` tile slice — a single-row
@@ -37,21 +38,29 @@ def row_view(x: jnp.ndarray) -> jnp.ndarray:
     return x.reshape(x.shape[0], 1, x.shape[1])
 
 
-def fetch_rows(src, ids, dst_of, sem, lanes) -> None:
+def fetch_rows(src, ids, dst_of, sem, lanes, lens=None) -> None:
     """DMA ``src[ids[..., g, w], :, lanes]`` into ``dst_of(g, w)`` for
     every entry of the SMEM id block ``ids`` (shape ``(G, W)``, or
     ``(1, G, W)`` for a one-segment block), then wait for all of them.
     Ids are clamped into ``src`` so a corrupt plan can never address
-    past the operand."""
+    past the operand.
+
+    ``lens`` (optional: ``G`` scalar lengths, read from SMEM) bounds the
+    fetch to the first ``lens[g]`` entries of row ``g`` of ids (clamped
+    to ``[0, W]``): the real elements of a segment whose padding is a
+    suffix. The caller must not read ``dst_of(g, w)`` for
+    ``w ≥ lens[g]``."""
     *lead, g_n, w_n = ids.shape
     lead = (0,) * len(lead)
     hi = src.shape[0] - 1
 
-    def start(t, carry):
-        g, w = t // w_n, t % w_n
+    def start(g, w):
         row = jnp.minimum(jnp.maximum(ids[lead + (g, w)], 0), hi)
         pltpu.make_async_copy(src.at[row, :, lanes], dst_of(g, w),
                               sem).start()
+
+    def start_flat(t, carry):
+        start(t // w_n, t % w_n)
         return carry
 
     def wait(t, carry):
@@ -61,8 +70,17 @@ def fetch_rows(src, ids, dst_of, sem, lanes) -> None:
                               sem).wait()
         return carry
 
-    jax.lax.fori_loop(0, g_n * w_n, start, 0)
-    jax.lax.fori_loop(0, g_n * w_n, wait, 0)
+    if lens is None:
+        count = g_n * w_n
+        jax.lax.fori_loop(0, count, start_flat, 0)
+    else:
+        count = jnp.int32(0)
+        for g, n_g in enumerate(lens):
+            n_g = jnp.minimum(jnp.maximum(n_g, 0), w_n)
+            jax.lax.fori_loop(
+                0, n_g, lambda w, carry, g=g: (start(g, w), carry)[1], 0)
+            count = count + n_g
+    jax.lax.fori_loop(0, count, wait, 0)
 
 
 def lane_tile(j, width: int):

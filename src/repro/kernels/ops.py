@@ -192,9 +192,11 @@ def spmm_apply(arrs, b, *, m: int, nwin: int, backend: str = "xla",
     with jax.named_scope("vpu"):
         if "vpu_seg_vals" in arrs:
             # §4.3 Cs decomposition: one row-segment of ≤ cs residual
-            # elements per tile (same kernel, wider tiles).
+            # elements per tile (same kernel, wider tiles); a table with
+            # segment lengths fetches B rows for real elements only.
             partials = spmm_vpu(arrs["vpu_seg_vals"], arrs["vpu_seg_cols"],
-                                b_p, nt=nt, grid_order=cfg.grid_order,
+                                b_p, arrs.get("vpu_seg_len"), nt=nt,
+                                grid_order=cfg.grid_order,
                                 interpret=interpret)
             vpu_rows = arrs["vpu_seg_row"]
         else:

@@ -79,12 +79,19 @@ def plan_counts(plan, kind: str) -> dict:
     stream's non-zeros, its blocks, their width ``bk`` and their cells;
     the VPU stream's real elements, its tiles (the segments the kernel
     launches over), their width ``cs`` and their slots (tiles × ``cs``).
+    ``vpu_fetches`` is the B rows the VPU kernel fetches a lane tile:
+    the sum of the segment lengths it launches with, or every slot for
+    a plan without a ``vpu_seg_len`` table.
     :func:`_padding_report` derives the padding from these, and the
     ``preprocess.spmm``/``preprocess.sddmm`` spans of an enabled tracer
     carry them as attributes."""
+    from repro.core.formats import spmm_vpu_seg_len
+
     tc, vpu = plan.tc, plan.vpu
+    seg_len = None
     if kind == "spmm":
         slots, vpu_nnz = vpu.vals, int(vpu.nnz)
+        seg_len = spmm_vpu_seg_len(plan)
     else:  # COOTiles: mask marks real elements
         slots, vpu_nnz = vpu.mask, int(vpu.mask.sum())
     return {
@@ -96,6 +103,8 @@ def plan_counts(plan, kind: str) -> dict:
         "vpu_segments": int(slots.shape[0]),
         "cs": int(slots.shape[1]),
         "vpu_slots": int(slots.size),
+        "vpu_fetches": (int(slots.size) if seg_len is None
+                        else int(seg_len.sum())),
     }
 
 

@@ -66,6 +66,73 @@ def test_spmm_vpu_matches_ref(rng, ntiles, ts, k, n, col_hi):
     np.testing.assert_allclose(np.asarray(out), expect, rtol=1e-4, atol=1e-4)
 
 
+def _prefix_tiles(rng, lens, ts, k, cols_lo=0):
+    """Tiles whose first ``lens[t]`` slots are real (values and columns
+    in ``[cols_lo, k)``) and whose padding is value 0, column 0."""
+    real = np.arange(ts)[None, :] < np.asarray(lens)[:, None]
+    vals = np.where(real, _rand(rng, len(lens), ts), 0.0).astype(np.float32)
+    cols = np.where(real, rng.integers(cols_lo, k, (len(lens), ts)),
+                    0).astype(np.int32)
+    return vals, cols
+
+
+# Segment lengths at cs = 32: every case holds 0, 1, cs − 1 and cs;
+# "zero_group" has a whole group of 8 empty segments, "pad_rows" 13
+# segments, so the wrapper pads 3 rows, and "many_blocks" 1100, so the
+# lengths span two SMEM blocks.
+BOUNDED_LENS = {
+    "ragged": [0, 1, 31, 32, 5, 17, 0, 32, 2, 32, 31, 1, 0, 9, 16, 3],
+    "zero_group": [32, 1, 0, 31, 7, 7, 32, 2] + [0] * 8
+                  + [31, 0, 1, 32, 12, 30, 0, 4],
+    "pad_rows": [1, 0, 32, 31, 3, 29, 0, 32, 1, 31, 8, 0, 32],
+    "many_blocks": [7 * t % 33 for t in range(1100)],
+}
+
+
+@pytest.mark.parametrize("n", [128, 256])
+@pytest.mark.parametrize("grid_order", ["n_outer", "block_outer"])
+@pytest.mark.parametrize("case", sorted(BOUNDED_LENS))
+def test_spmm_vpu_bounded_fetch_bit_identical(rng, case, grid_order, n):
+    """With finite B, fetching only each segment's real prefix gives the
+    every-slot kernel's output bit for bit."""
+    lens = np.asarray(BOUNDED_LENS[case], np.int32)
+    ts, k = 32, 48
+    vals, cols = _prefix_tiles(rng, lens, ts, k)
+    b = _rand(rng, k, n)
+    args = (jnp.asarray(vals), jnp.asarray(cols), jnp.asarray(b))
+    every = spmm_vpu(*args, nt=128, grid_order=grid_order, interpret=True)
+    bounded = spmm_vpu(*args, jnp.asarray(lens), nt=128,
+                       grid_order=grid_order, interpret=True)
+    assert np.array_equal(np.asarray(every), np.asarray(bounded))
+    expect = np.einsum("tj,tjn->tn", vals, b[cols])
+    np.testing.assert_allclose(np.asarray(bounded), expect, rtol=1e-4,
+                               atol=1e-4)
+
+
+@pytest.mark.parametrize("bad", [np.inf, np.nan], ids=["inf", "nan"])
+@pytest.mark.parametrize("grid_order", ["n_outer", "block_outer"])
+def test_spmm_vpu_bounded_fetch_ignores_stale_rows(rng, grid_order, bad):
+    """Slots past a segment's length keep whatever an earlier grid step
+    fetched: here a B row of ``inf``/``nan`` that only the first group's
+    real slots name. Every later group stays finite and exact."""
+    ts, k, n, poison = 32, 40, 256, 3
+    later = [0, 1, 31, 2, 0, 5, 1, 0, 4, 0, 0, 1, 30, 0, 2, 7]
+    lens = np.asarray([ts] * 8 + later, np.int32)
+    vals, cols = _prefix_tiles(rng, lens, ts, k, cols_lo=poison + 1)
+    cols[:8] = poison
+    b = _rand(rng, k, n)
+    b[poison] = bad
+    args = (jnp.asarray(vals), jnp.asarray(cols), jnp.asarray(b))
+    bounded = np.asarray(spmm_vpu(*args, jnp.asarray(lens), nt=128,
+                                  grid_order=grid_order, interpret=True))
+    every = np.asarray(spmm_vpu(*args, nt=128, grid_order=grid_order,
+                                interpret=True))
+    assert np.isfinite(bounded[8:]).all()
+    assert np.array_equal(bounded[8:], every[8:])
+    expect = np.einsum("tj,tjn->tn", vals[8:], b[cols[8:]])
+    np.testing.assert_allclose(bounded[8:], expect, rtol=1e-4, atol=1e-4)
+
+
 @pytest.mark.parametrize("nb,bk,kf", [(3, 16, 128), (6, 16, 256), (2, 8, 128)])
 def test_sddmm_mxu_matches_ref(rng, nb, bk, kf):
     nwin = 3

@@ -47,7 +47,10 @@ class TestLazyBitIdentity:
             eager = dict(PlanArrays(plan).materialize_all())
             y_e = spmm_apply(eager, jnp.asarray(b), m=a.shape[0],
                              nwin=nwin, backend=backend, interpret=True)
-            y_l = spmm_apply(pa.for_backend(backend), jnp.asarray(b),
+            lazy = pa.for_backend(backend)
+            # only the Pallas segmented apply reads segment lengths
+            assert ("vpu_seg_len" in lazy) == (backend == "pallas")
+            y_l = spmm_apply(lazy, jnp.asarray(b),
                              m=a.shape[0], nwin=nwin, backend=backend,
                              interpret=True)
             assert np.array_equal(np.asarray(y_e), np.asarray(y_l))
@@ -112,6 +115,25 @@ class TestLazyBitIdentity:
         # SDDMM scatter maps are structural, not revalue
         assert view_of_key("tc_out_pos") == "compact"
         assert view_of_key("vpu_seg_out_pos") == "segment"
+
+    def test_vpu_seg_len_in_pallas_segment_view_only(self, corpus):
+        a = next(iter(corpus.values()))
+        pa = PlanArrays(preprocess_spmm(a))
+        assert view_of_key("vpu_seg_len") == "segment"
+        assert "vpu_seg_len" in pa.backend_keys("pallas")
+        assert "vpu_seg_len" in pa.backend_keys("pallas", revalue=True)
+        assert "vpu_seg_len" not in pa.backend_keys("pallas",
+                                                    segmented=False)
+        assert "vpu_seg_len" not in pa.backend_keys("xla")
+        assert "vpu_seg_len" not in PlanArrays(
+            preprocess_sddmm(a)).backend_keys("pallas")
+        nbytes = int(pa._host["vpu_seg_len"].nbytes)
+        assert nbytes == 4 * pa._host["vpu_seg_row"].shape[0]
+        assert pa.projected_nbytes("pallas") == sum(
+            int(pa._host[k].nbytes) for k in pa.backend_keys("pallas"))
+        pa.for_backend("pallas")
+        assert pa._uploads["vpu_seg_len"] == ("segment", nbytes, "int32")
+        assert pa.resident_nbytes() == pa.projected_nbytes("pallas")
 
     def test_tc_bitmap_not_in_spmm_backend_views(self, corpus):
         a = next(iter(corpus.values()))

@@ -614,6 +614,31 @@ class TestPlanBuildSpans:
             == pad["tc_padded_zeros"]
         assert counts["vpu_slots"] == counts["vpu_segments"] * counts["cs"]
         assert counts["tc_nnz"] + counts["vpu_nnz"] == arrs.plan.nnz
+        fetched = (int(np.asarray(arrs["vpu_seg_len"]).sum())
+                   if "vpu_seg_len" in arrs else counts["vpu_slots"])
+        assert counts["vpu_fetches"] == fetched <= counts["vpu_slots"]
+
+    @pytest.mark.parametrize("segmented", [True, False],
+                             ids=["segmented", "per_tile"])
+    def test_vpu_fetches_counts_the_launched_lengths(self, segmented):
+        from repro.core.formats import PlanArrays
+        from repro.core.preprocess import preprocess_spmm
+        from repro.obs.explain import plan_counts
+        from repro.sparse.generate import power_law_csr
+        from repro.tune import TuneConfig
+
+        a = power_law_csr(96, 96, avg_row=6.0, seed=5)
+        cfg = TuneConfig() if segmented else TuneConfig(ts=0, cs=0)
+        plan = preprocess_spmm(a, cfg=cfg)
+        arrs = PlanArrays(plan)
+        counts = plan_counts(plan, "spmm")
+        if segmented:
+            fetched = int(np.asarray(arrs["vpu_seg_len"]).sum())
+            assert counts["vpu_fetches"] == fetched == counts["vpu_nnz"]
+            assert counts["vpu_fetches"] < counts["vpu_slots"]
+        else:
+            assert "vpu_seg_len" not in arrs
+            assert counts["vpu_fetches"] == counts["vpu_slots"]
 
     def test_disabled_tracer_computes_no_counters(self, monkeypatch):
         from repro.core import preprocess

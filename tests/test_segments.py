@@ -165,6 +165,9 @@ def _check_bitident_spmm(a, cfg, n=64):
     op = LibraSpMM(a, tune=cfg)
     op0 = LibraSpMM(a, tune=cfg.replace(ts=0, cs=0))
     assert "tc_seg_vals" in op.arrays and "tc_seg_vals" not in op0.arrays
+    # The segmented stream fetches real elements only, the per-tile
+    # stream every slot.
+    assert "vpu_seg_len" in op.arrays and "vpu_seg_len" not in op0.arrays
     oracle = np.asarray(a.to_dense() @ np.asarray(b), np.float32)
     outs = [np.asarray(op(b, backend=be)) for be in ("xla", "pallas")]
     outs += [np.asarray(op0(b, backend=be)) for be in ("xla", "pallas")]
@@ -238,6 +241,63 @@ def test_segmented_revalue_matches_rebaked_plan(rng):
     r, c, _ = a.to_coo()
     dense[r, c] = ev
     assert np.array_equal(out, np.asarray(dense @ np.asarray(b), np.float32))
+
+
+# ------------------------------------------------- segment lengths ---
+def _len_corpus():
+    from repro.sparse import suitesparse_like_corpus
+
+    mats = dict(suitesparse_like_corpus(n_small=4, seed=7))
+    mats["skewed"] = _skewed()
+    return mats
+
+
+@pytest.mark.parametrize("name", sorted(_len_corpus()))
+def test_vpu_seg_len_counts_a_real_prefix(name):
+    """``vpu_seg_len`` counts each segment's real elements, they fill a
+    prefix of its slots (what lets the kernel fetch just that prefix),
+    and the reorder remap of the plan's nnz maps leaves it unchanged."""
+    from repro.core.formats import _host_arrays, spmm_vpu_seg_len
+
+    a = _len_corpus()[name]
+    for cfg in (TuneConfig(), TuneConfig(ts=2, cs=16, ts_tile=8)):
+        plan = preprocess.preprocess_spmm(a, cfg=cfg)
+        host = _host_arrays(plan)
+        lens, real = host["vpu_seg_len"], host["vpu_seg_pos"] >= 0
+        assert lens.dtype == np.int32
+        assert lens.shape == host["vpu_seg_row"].shape
+        assert np.array_equal(lens, real.sum(axis=1))
+        slots = np.arange(real.shape[1])[None, :]
+        assert np.array_equal(real, slots < lens[:, None])
+        assert np.array_equal(spmm_vpu_seg_len(plan), lens)
+        assert int(lens.sum()) == plan.vpu.nnz
+        perm = np.random.default_rng(3).permutation(a.nnz)
+        remapped = preprocess._remap_spmm_plan(plan, perm)
+        assert np.array_equal(_host_arrays(remapped)["vpu_seg_len"], lens)
+
+
+def test_vpu_seg_len_of_a_plan_without_vpu_work():
+    a = _int_valued(mixed_csr(72, 64, seed=5))
+    op = LibraSpMM(a, mode="tcu", tune=TuneConfig(ts=2, cs=64))
+    assert op.plan.meta["vpu_segments"].nseg == 0
+    # the dummy all-padding segment fetches nothing
+    assert np.array_equal(np.asarray(op.arrays["vpu_seg_len"]), [0])
+
+
+def test_partition_stacks_vpu_seg_len_with_zero_padding():
+    from repro.dist.partition import partition_spmm
+
+    a = _skewed(seed=11)
+    part = partition_spmm(a, 3, tune="off")
+    st = {k: np.asarray(v) for k, v in part.stacked.items()}
+    lens, real = st["vpu_seg_len"], st["vpu_seg_pos"] >= 0
+    assert lens.shape == st["vpu_seg_row"].shape
+    assert np.array_equal(lens, real.sum(axis=-1))
+    slots = np.arange(real.shape[-1])
+    assert np.array_equal(real, slots < lens[..., None])
+    # shards with fewer segments are padded with empty ones: length 0
+    empty = ~real.any(axis=-1)
+    assert empty.any() and (lens[empty] == 0).all()
 
 
 # --------------------------------------------------- tuner / cache ---
