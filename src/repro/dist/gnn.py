@@ -5,8 +5,10 @@ same differentiable ``spmm``/``sddmm`` surface, same gradient duality —
 but every apply (forward *and* both VJP legs) runs through the
 ``shard_map`` ops in :mod:`repro.dist.sparse` on a device mesh. The
 model code is unchanged: ``gcn_forward`` / ``agnn_forward`` /
-``edge_softmax`` from :mod:`repro.models.gnn` duck-type over either
-ops object, so going multi-device is a one-line swap.
+``unimp_forward`` / ``edge_softmax`` from :mod:`repro.models.gnn`
+duck-type over either ops object, so going multi-device is a one-line
+swap. Multi-head edge values, ``(nnz, H)``, ride through the same
+sharded applies.
 
 Partitions built once per graph (paper §4.5 — preprocess-once,
 apply-many, now shard-once too): A for the forward SpMM, Aᵀ for the
@@ -31,7 +33,8 @@ from repro.api import UNSET, ExecSpec, resolve_spec
 from repro.dist.partition import partition_sddmm, partition_spmm
 from repro.dist.sparse import (SHARD_AXIS, place_partition, sddmm_sharded,
                                spmm_sharded)
-from repro.models.gnn import edge_softmax, gcn_forward, transpose_csr
+from repro.models.gnn import (edge_heads, edge_softmax, gcn_forward,
+                              head_span, transpose_csr)
 from repro.sparse.matrix import SparseCSR
 
 
@@ -80,9 +83,10 @@ class DistGraphOps:
         """C = A(edge_vals) @ B, differentiable in (edge_vals, b)."""
         return _dist_spmm_ev(self, edge_vals, b)
 
-    def sddmm(self, x, y):
-        """vals[p] = ⟨X[row_p], Y[col_p]⟩, differentiable in (x, y)."""
-        return _dist_sddmm_ev(self, x, y)
+    def sddmm(self, x, y, heads: int | None = None):
+        """vals[p] = ⟨X[row_p], Y[col_p]⟩, differentiable in (x, y); with
+        ``heads`` = H, one score per head, ``(nnz, H)``."""
+        return _dist_sddmm_ev(self, x, y, heads)
 
     def fixed_spmm(self, b):
         """C = A @ B with the plans' baked-in values (no value grads)."""
@@ -92,16 +96,20 @@ class DistGraphOps:
     # Each apply runs under the operator's named scope, as on one device
     # (repro.models.gnn).
     def _spmm(self, part, b, edge_vals=None):
-        with jax.named_scope("spmm"):
+        heads = None if edge_vals is None else edge_heads(edge_vals)
+        with head_span("spmm", heads, b.shape[1], part.run_cfg.nt), \
+                jax.named_scope("spmm"):
             return spmm_sharded(part, b, mesh=self.mesh, axis=self.axis,
                                 backend=self.backend, edge_vals=edge_vals,
                                 b_layout=self.b_layout)
 
-    def _sddmm(self, x, y):
-        with jax.named_scope("sddmm"):
+    def _sddmm(self, x, y, heads=None):
+        with head_span("sddmm", heads, x.shape[1],
+                       self.part_sd.run_cfg.kf_tile), \
+                jax.named_scope("sddmm"):
             return sddmm_sharded(self.part_sd, x, y, mesh=self.mesh,
                                  axis=self.axis, backend=self.backend,
-                                 y_layout=self.b_layout)
+                                 y_layout=self.b_layout, heads=heads)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -118,23 +126,23 @@ def _dist_spmm_ev_bwd(g, resid, d_c):
     # dB = A(v)ᵀ @ dC — sharded SpMM on the transposed partition.
     d_b = g._spmm(g.part_t, d_c, edge_vals=edge_vals[g.perm_dev])
     # dv[p] = dC[row_p] · B[col_p] — sharded SDDMM with A's sparsity.
-    d_vals = g._sddmm(d_c, b)
+    d_vals = g._sddmm(d_c, b, edge_heads(edge_vals))
     return d_vals.astype(edge_vals.dtype), d_b.astype(b.dtype)
 
 
 _dist_spmm_ev.defvjp(_dist_spmm_ev_fwd, _dist_spmm_ev_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _dist_sddmm_ev(g: DistGraphOps, x, y):
-    return g._sddmm(x, y)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 3))
+def _dist_sddmm_ev(g: DistGraphOps, x, y, heads):
+    return g._sddmm(x, y, heads)
 
 
-def _dist_sddmm_ev_fwd(g, x, y):
-    return _dist_sddmm_ev(g, x, y), (x, y)
+def _dist_sddmm_ev_fwd(g, x, y, heads):
+    return _dist_sddmm_ev(g, x, y, heads), (x, y)
 
 
-def _dist_sddmm_ev_bwd(g, resid, d_vals):
+def _dist_sddmm_ev_bwd(g, heads, resid, d_vals):
     x, y = resid
     # dX = A(dv) @ Y ; dY = A(dv)ᵀ @ X — both sharded SpMMs.
     d_x = g._spmm(g.part, y, edge_vals=d_vals)
@@ -186,6 +194,27 @@ def make_agnn_train_step(g, lr: float = 0.2):
     return agnn_train_step
 
 
+def unimp_loss(params, g, feats, labels):
+    from repro.models.gnn import unimp_forward
+
+    logits = unimp_forward(params, g, feats)
+    lp = jax.nn.log_softmax(logits)
+    return -jnp.take_along_axis(lp, labels[:, None], axis=1).mean()
+
+
+def make_unimp_train_step(g, lr: float = 0.2):
+    """Jitted SGD step for UniMP (per layer one multi-head SDDMM → edge
+    softmax → SpMM, heads fused), named ``unimp_train_step`` in a
+    profile."""
+    @jax.jit
+    def unimp_train_step(params, feats, labels):
+        loss, grads = jax.value_and_grad(unimp_loss)(params, g, feats,
+                                                     labels)
+        new = jax.tree.map(lambda p, gg: p - lr * gg, params, grads)
+        return new, loss
+    return unimp_train_step
+
+
 __all__ = [
     "DistGraphOps",
     "agnn_loss",
@@ -193,4 +222,6 @@ __all__ = [
     "gcn_loss",
     "make_agnn_train_step",
     "make_gcn_train_step",
+    "make_unimp_train_step",
+    "unimp_loss",
 ]

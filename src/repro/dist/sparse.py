@@ -106,8 +106,9 @@ def spmm_sharded(part: SpMMPartition, b: jnp.ndarray, *, mesh: Mesh,
     if edge_vals is not None and part.edge_perm is not None:
         # Reordered partition: shard plan positions index the reordered
         # canonical nnz order — gather the caller's original-order
-        # values into it once, before the replicated broadcast.
-        edge_vals = jnp.take(edge_vals, part.edge_perm)
+        # values (one per head, where they carry heads) into it once,
+        # before the replicated broadcast.
+        edge_vals = jnp.take(edge_vals, part.edge_perm, axis=0)
 
     def body(stacked, b_in, *ev):
         local, halo = _local(stacked)
@@ -137,8 +138,8 @@ def spmm_sharded(part: SpMMPartition, b: jnp.ndarray, *, mesh: Mesh,
 
 def sddmm_sharded(part: SDDMMPartition, x: jnp.ndarray, y: jnp.ndarray, *,
                   mesh: Mesh, axis: str = SHARD_AXIS,
-                  backend: str = "xla", y_layout: str = "replicated"
-                  ) -> jnp.ndarray:
+                  backend: str = "xla", y_layout: str = "replicated",
+                  heads: int | None = None) -> jnp.ndarray:
     """values = sample(X·Yᵀ, sparsity(A)) over a mesh axis, canonical
     global nnz order.
 
@@ -146,7 +147,8 @@ def sddmm_sharded(part: SDDMMPartition, x: jnp.ndarray, y: jnp.ndarray, *,
     global rows out in padded per-shard panels before the shard_map);
     Y follows ``y_layout`` like B in :func:`spmm_sharded`. Each shard
     scatters into its local nnz slice; ``part.nnz_gather`` reassembles
-    the canonical global vector — again no cross-device combine.
+    the canonical global vector — again no cross-device combine. With
+    ``heads`` = H the values are ``(nnz, H)``, one score per head.
     """
     assert y_layout in _LAYOUTS, y_layout
     assert int(mesh.shape[axis]) == part.n_shards, (mesh.shape, part.n_shards)
@@ -159,7 +161,7 @@ def sddmm_sharded(part: SDDMMPartition, x: jnp.ndarray, y: jnp.ndarray, *,
                   if rowshard else y_in)
         y_halo = jnp.take(y_full, halo, axis=0)
         out = sddmm_apply(local, x_in, y_halo, nnz=part.nnz_pad,
-                          backend=backend, cfg=part.run_cfg)
+                          backend=backend, cfg=part.run_cfg, heads=heads)
         out = jax.lax.all_gather(out, axis, axis=0, tiled=True)
         return jnp.take(out, part.nnz_gather, axis=0)
 

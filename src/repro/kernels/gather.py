@@ -15,6 +15,17 @@ slot (SDDMM). Where the plan knows that a row of ids ends in padding
 The dense operand is viewed as ``(rows, 1, width)`` (:func:`row_view`)
 so one row is a whole trailing ``(1, width)`` tile slice — a single-row
 slice of an ``(8, 128)``-tiled 2-D HBM array is refused by Mosaic.
+
+**Multi-head layout.** With ``H`` heads of width ``c`` the dense
+operands hold the heads contiguously in the feature axis: head ``h``
+owns features ``[h·c, (h+1)·c)``, with no padding between heads, and
+the edge values carry one value per head. A lane tile may hold several
+heads, or part of one (at ``c = 40`` head 3 straddles the first two
+128-lane tiles): each kernel tells a lane's head by a per-lane map
+(:func:`head_masks`) from its global feature index, so SpMM scales each
+lane by its own head's value and SDDMM sums each head's lanes apart,
+accumulating a straddling head's partial sums across feature tiles.
+Lanes past ``H·c`` (the padding to whole tiles) belong to no head.
 """
 from __future__ import annotations
 
@@ -81,6 +92,15 @@ def fetch_rows(src, ids, dst_of, sem, lanes, lens=None) -> None:
                 0, n_g, lambda w, carry, g=g: (start(g, w), carry)[1], 0)
             count = count + n_g
     jax.lax.fori_loop(0, count, wait, 0)
+
+
+def head_masks(shape, first: int, heads: int, head_dim: int) -> list:
+    """One boolean mask of ``shape`` per head: lane ``l`` (the last
+    axis) holds feature ``first + l``, which head ``h`` owns when it is
+    in ``[h·head_dim, (h+1)·head_dim)``."""
+    f = first + jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+    return [(f >= h * head_dim) & (f < (h + 1) * head_dim)
+            for h in range(heads)]
 
 
 def lane_tile(j, width: int):

@@ -28,7 +28,7 @@ passes:
    TPU-deterministic analogue of the paper's atomicAdd combine. Both are
    sorted scatters (plans keep windows and rows non-decreasing). SDDMM
    combines both streams' scores with a single scatter into the
-   canonical nnz vector.
+   canonical nnz vector (multi-head: a gather by the inverse map).
 4. **Segment-granular launch (§4.3).** Plans carrying the hybrid
    balancer's Ts/Cs launch tables (``*_seg_*`` device arrays — the
    default) run the kernels one *segment* per grid step: bounded work
@@ -38,10 +38,18 @@ passes:
    ``TuneConfig(ts=0, cs=0)`` falls back to the per-block/per-tile
    launch.
 
+5. **Heads.** Edge values may carry a head axis, ``(nnz, H)``, against
+   dense operands of ``H·c`` columns that hold the heads contiguously
+   (:mod:`repro.kernels.gather` states the layout). One call runs all
+   heads fused: each kernel fetches an operand row once per lane tile
+   and splits its lanes by head. ``(nnz,)`` is the single-head case, and
+   ``(nnz, 1)`` runs the same single-head kernels.
+
 In a profile, each apply's ops fall under the named scopes ``mxu``
 (padding and the MXU kernel), ``vpu`` (the VPU kernel) and ``combine``
 (the epilogue), and the kernels carry their own names (``spmm_mxu``,
-``spmm_vpu``, ``sddmm_mxu``, ``sddmm_vpu``).
+``spmm_vpu``, ``sddmm_mxu``, ``sddmm_vpu``; ``_mh`` appended for the
+multi-head kernels).
 """
 from __future__ import annotations
 
@@ -159,6 +167,19 @@ def _pad_to(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
     return jnp.pad(x, widths)
 
 
+#: A plan's value tensors: a revalued plan gives them a trailing head
+#: axis when its edge values carry one.
+_VALUE_KEYS = ("tc_vals", "vpu_vals", "tc_seg_vals", "vpu_seg_vals")
+
+
+def _plan_heads(arrs) -> int | None:
+    """Heads of a plan's values: ``H`` when its VPU value tensor carries
+    a trailing head axis (``(tiles, width, H)``), else ``None``."""
+    vals = arrs["vpu_seg_vals"] if "vpu_seg_vals" in arrs \
+        else arrs["vpu_vals"]
+    return vals.shape[2] if vals.ndim == 3 else None
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("m", "nwin", "backend", "cfg", "interpret"),
@@ -171,12 +192,23 @@ def spmm_apply(arrs, b, *, m: int, nwin: int, backend: str = "xla",
     :class:`repro.tune.model.TuneConfig`); callers that pass nothing get
     the library default — module constants no longer exist.
     ``interpret`` defaults to compiled kernels on a TPU and the Pallas
-    interpreter elsewhere.
+    interpreter elsewhere. A plan revalued with ``(nnz, H)`` edge values
+    (:func:`repro.kernels.ref.revalue_spmm_arrays`) multiplies ``B``'s
+    ``H·c`` columns head by head (head ``h``'s columns by its values).
     """
     cfg = DEFAULT_TUNE if cfg is None else cfg
+    heads = _plan_heads(arrs)
+    if heads == 1:
+        arrs = {k: v[..., 0] if k in _VALUE_KEYS else v
+                for k, v in arrs.items()}
+        heads = None
     n0 = b.shape[1]
     if backend == "xla":
         return ref.spmm_hybrid_ref(arrs, b, m, nwin)
+    head_dim = None
+    if heads:
+        assert n0 % heads == 0, (n0, heads)
+        head_dim = n0 // heads
     nt = cfg.nt
     with jax.named_scope("mxu"):
         b_p = _pad_to(b, 1, nt)
@@ -185,10 +217,11 @@ def spmm_apply(arrs, b, *, m: int, nwin: int, backend: str = "xla",
             # step per segment of ≤ ts blocks of one window.
             tc = spmm_mxu(arrs["tc_seg_vals"], arrs["tc_seg_cols"], b_p,
                           nt=nt, grid_order=cfg.grid_order,
-                          interpret=interpret)
+                          head_dim=head_dim, interpret=interpret)
         else:
             tc = spmm_mxu(arrs["tc_vals"], arrs["tc_cols"], b_p, nt=nt,
-                          grid_order=cfg.grid_order, interpret=interpret)
+                          grid_order=cfg.grid_order, head_dim=head_dim,
+                          interpret=interpret)
     with jax.named_scope("vpu"):
         if "vpu_seg_vals" in arrs:
             # §4.3 Cs decomposition: one row-segment of ≤ cs residual
@@ -197,12 +230,12 @@ def spmm_apply(arrs, b, *, m: int, nwin: int, backend: str = "xla",
             partials = spmm_vpu(arrs["vpu_seg_vals"], arrs["vpu_seg_cols"],
                                 b_p, arrs.get("vpu_seg_len"), nt=nt,
                                 grid_order=cfg.grid_order,
-                                interpret=interpret)
+                                head_dim=head_dim, interpret=interpret)
             vpu_rows = arrs["vpu_seg_row"]
         else:
             partials = spmm_vpu(arrs["vpu_vals"], arrs["vpu_cols"], b_p,
                                 nt=nt, grid_order=cfg.grid_order,
-                                interpret=interpret)
+                                head_dim=head_dim, interpret=interpret)
             vpu_rows = arrs["vpu_row"]
     # Fused combine epilogue: the TC partials sum into their windows of
     # a zero C, then one scatter-add lays the VPU partials over it (rows
@@ -280,19 +313,30 @@ def sddmm_apply_stack(arrs, x_stack, y_stack, *, nnz: int,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("nnz", "backend", "cfg", "interpret")
+    jax.jit, static_argnames=("nnz", "backend", "cfg", "heads", "interpret")
 )
 def sddmm_apply(arrs, x, y, *, nnz: int, backend: str = "xla",
-                cfg: TuneConfig | None = None, interpret: bool | None = None):
+                cfg: TuneConfig | None = None, heads: int | None = None,
+                interpret: bool | None = None):
     """Hybrid SDDMM: values[nnz] = sample(X @ Yᵀ) in canonical CSR order.
 
     ``cfg.kf_tile`` tiles the feature dimension (padded here to whole
     tiles — a lane-dense row is 128 wide in HBM regardless; padded
-    features are zeros).
+    features are zeros). With ``heads`` = H, X and Y hold H heads of
+    ``kf / H`` columns each and the values are ``(nnz, H)``, one score
+    per head.
     """
     cfg = DEFAULT_TUNE if cfg is None else cfg
+    if heads == 1:
+        return sddmm_apply(arrs, x, y, nnz=nnz, backend=backend, cfg=cfg,
+                           interpret=interpret)[:, None]
     if backend == "xla":
-        return ref.sddmm_hybrid_ref(arrs, _pad_to(x, 0, WINDOW), y, nnz)
+        return ref.sddmm_hybrid_ref(arrs, _pad_to(x, 0, WINDOW), y, nnz,
+                                    heads)
+    head_dim = None
+    if heads:
+        assert x.shape[1] % heads == 0, (x.shape, heads)
+        head_dim = x.shape[1] // heads
     kft = cfg.kf_tile
     with jax.named_scope("mxu"):
         x = _pad_to(x, 1, kft)
@@ -305,11 +349,13 @@ def sddmm_apply(arrs, x, y, *, nnz: int, backend: str = "xla",
             # and its out_pos −1 lands in the scatter's swallow slot).
             s_tc = sddmm_mxu(arrs["tc_seg_cols"], arrs["tc_seg_bitmap"],
                              arrs["tc_seg_window"], x_p, y, kf_tile=kft,
+                             heads=heads, head_dim=head_dim,
                              interpret=interpret)
             tc_pos_src = arrs["tc_seg_out_pos"]
         else:
             s_tc = sddmm_mxu(arrs["tc_cols"], arrs["tc_bitmap"],
                              arrs["tc_window"], x_p, y, kf_tile=kft,
+                             heads=heads, head_dim=head_dim,
                              interpret=interpret)
             tc_pos_src = arrs["tc_out_pos"]
     with jax.named_scope("vpu"):
@@ -317,20 +363,35 @@ def sddmm_apply(arrs, x, y, *, nnz: int, backend: str = "xla",
             # Cs cap batches whole element tiles per VPU grid step.
             vpu_mask = arrs["vpu_seg_mask"]
             s_el = sddmm_vpu(arrs["vpu_seg_rows"], arrs["vpu_seg_cols"], x,
-                             y, kf_tile=kft, interpret=interpret)
+                             y, kf_tile=kft, heads=heads, head_dim=head_dim,
+                             interpret=interpret)
             el_pos_src = arrs["vpu_seg_out_pos"]
         else:
             vpu_mask = arrs["vpu_mask"]
             s_el = sddmm_vpu(arrs["vpu_rows"], arrs["vpu_cols"], x, y,
-                             kf_tile=kft, interpret=interpret)
+                             kf_tile=kft, heads=heads, head_dim=head_dim,
+                             interpret=interpret)
             el_pos_src = arrs["vpu_out_pos"]
     # Fused combine: one scatter of both streams into the canonical nnz
     # vector (slot nnz swallows -1/masked padding).
     with jax.named_scope("combine"):
-        s_el = jnp.where(vpu_mask, s_el, 0.0)
+        if not heads:
+            s_el = jnp.where(vpu_mask, s_el, 0.0)
         pos_tc = jnp.where(tc_pos_src >= 0, tc_pos_src, nnz)
         pos_el = jnp.where(vpu_mask, el_pos_src, nnz)
         pos = jnp.concatenate([pos_tc.reshape(-1), pos_el.reshape(-1)])
+        if heads:
+            # Each non-zero's scores sit at exactly one output position,
+            # so each is gathered from there, by the position map's
+            # inverse (a function of the plan alone, shared by every call
+            # of a step). On a v5e a scatter of rows of H scores cost
+            # ~11× the single-head scatter per call.
+            src = jnp.zeros((nnz + 1,), jnp.int32).at[pos].set(
+                jnp.arange(pos.shape[0], dtype=jnp.int32))[:nnz]
+            data = jnp.concatenate(
+                [jnp.moveaxis(s_tc, 1, 0).reshape(heads, -1),
+                 s_el.reshape(heads, -1)], axis=1)     # (H, positions)
+            return jnp.take(data, src, axis=1).T
         data = jnp.concatenate([s_tc.reshape(-1), s_el.reshape(-1)])
         out = jnp.zeros((nnz + 1,), s_tc.dtype).at[pos].add(data)
         return out[:nnz]

@@ -24,6 +24,16 @@ once per segment instead of once per block. Zero-bitmap cap padding
 samples to zero and its ``out_pos`` −1 lands in the combine's swallow
 slot, so the kernel body is layout-agnostic (this docstring's "block"
 then reads "segment").
+
+**Heads.** With ``H`` heads of width ``c`` (the layout of
+:mod:`repro.kernels.gather`) the step stacks the X window once per
+head, each copy masked to its head's lanes
+(:func:`repro.kernels.gather.head_masks`), into ``(H·8, kf)``: one
+``(H·8)×KF @ KF×BK`` dot gives each head's scores in its own 8
+sublanes, ``(nb, H·8, bk)``, accumulated over feature tiles (a head
+that straddles two tiles sums both parts). The bitmap samples every
+head's sublanes alike. The multi-head kernel is named ``sddmm_mxu_mh``;
+the single-head one is unchanged.
 """
 from __future__ import annotations
 
@@ -35,12 +45,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.formats import WINDOW
-from repro.kernels.gather import (default_interpret, fetch_rows, lane_tile,
-                                  row_view)
+from repro.kernels.gather import (default_interpret, fetch_rows,
+                                  head_masks, lane_tile, row_view)
 
 
 def _kernel(cols_ref, win_ref, bitmap_ref, x_hbm, y_hbm, out_ref, xw, rows,
-            sem):
+            sem, *, heads, head_dim):
     f = pl.program_id(1)   # feature tile index (fastest)
     bk, _, kft = rows.shape
     lanes = lane_tile(f, kft)
@@ -50,8 +60,14 @@ def _kernel(cols_ref, win_ref, bitmap_ref, x_hbm, y_hbm, out_ref, xw, rows,
     fetch_rows(y_hbm, cols_ref, lambda g, w: rows.at[w], sem.at[0], lanes)
     win_copy.wait()
 
-    # 8×KFt @ KFt×BK on the MXU.
-    s = jax.lax.dot_general(xw[...], rows[...].reshape(bk, kft),
+    x_win = xw[...]
+    if heads:
+        # (H·8, kft): head h's 8 sublanes keep head h's lanes only.
+        masks = head_masks((WINDOW, kft), f * kft, heads, head_dim)
+        x_win = jnp.concatenate([jnp.where(m, x_win, 0.0) for m in masks],
+                                axis=0)
+    # 8×KFt @ KFt×BK on the MXU ((H·8)×KFt @ KFt×BK).
+    s = jax.lax.dot_general(x_win, rows[...].reshape(bk, kft),
                             (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
 
@@ -66,16 +82,21 @@ def _kernel(cols_ref, win_ref, bitmap_ref, x_hbm, y_hbm, out_ref, xw, rows,
     @pl.when(f == pl.num_programs(1) - 1)
     def _():
         # Bit-Decoding sample: sublane r keeps column j iff bit r of
-        # bitmap[j] is set.
+        # bitmap[j] is set (in every head's 8 sublanes).
         sub = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape[1:], 0)
+        if heads:
+            sub = sub & (WINDOW - 1)
         bits = (bitmap_ref[0] >> sub) & 1
         out_ref[0] = jnp.where(bits > 0, out_ref[0], 0.0)
 
 
-@functools.partial(jax.jit, static_argnames=("kf_tile", "interpret"))
+@functools.partial(jax.jit, static_argnames=("kf_tile", "heads", "head_dim",
+                                             "interpret"))
 def sddmm_mxu(tc_cols, tc_bitmap, tc_window, x, y, *, kf_tile: int = 128,
+              heads: int | None = None, head_dim: int | None = None,
               interpret: bool | None = None):
-    """Bitmap-sampled block scores, shape ``(nb, 8, bk)``.
+    """Bitmap-sampled block scores, shape ``(nb, 8, bk)``, or
+    ``(nb, H, 8, bk)`` with ``heads`` heads of width ``head_dim``.
 
     Args:
       tc_cols: (nb, bk) i32 sparse-block column indices.
@@ -89,10 +110,11 @@ def sddmm_mxu(tc_cols, tc_bitmap, tc_window, x, y, *, kf_tile: int = 128,
     assert kf % kf_tile == 0, (kf, kf_tile)
     xw = x.reshape(-1, WINDOW, kf)
     bitmap = tc_bitmap.astype(jnp.int32).reshape(nb, 1, bk)
+    rows_out = WINDOW * (heads or 1)
 
-    return pl.pallas_call(
-        _kernel,
-        name="sddmm_mxu",
+    out = pl.pallas_call(
+        functools.partial(_kernel, heads=heads, head_dim=head_dim),
+        name="sddmm_mxu_mh" if heads else "sddmm_mxu",
         grid=(nb, kf // kf_tile),
         in_specs=[
             pl.BlockSpec((1, 1, bk), lambda i, f: (i, 0, 0),
@@ -103,11 +125,12 @@ def sddmm_mxu(tc_cols, tc_bitmap, tc_window, x, y, *, kf_tile: int = 128,
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, WINDOW, bk), lambda i, f: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, WINDOW, bk), jnp.float32),
+        out_specs=pl.BlockSpec((1, rows_out, bk), lambda i, f: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb, rows_out, bk), jnp.float32),
         scratch_shapes=[pltpu.VMEM((WINDOW, kf_tile), jnp.float32),
                         pltpu.VMEM((bk, 1, kf_tile), jnp.float32),
                         pltpu.SemaphoreType.DMA((2,))],
         interpret=default_interpret(interpret),
     )(tc_cols.reshape(nb, 1, bk), tc_window.reshape(nb, 1, 1), bitmap, xw,
       row_view(y))
+    return out.reshape(nb, heads, WINDOW, bk) if heads else out

@@ -27,6 +27,15 @@ TPU adaptation of the paper's TCU stream (§4.4):
   own their rows, so the add degenerates to a store for them. The
   per-block (unsegmented) table runs through the same kernel.
 
+**Heads.** With ``H`` heads of width ``c`` (the layout of
+:mod:`repro.kernels.gather`) a block carries one ``8×bk`` value matrix
+per head, laid out head-major as ``(8, H·bk)``. The step stacks the
+fetched rows once per head, each masked to its head's lanes
+(:func:`repro.kernels.gather.head_masks`), into ``(H·bk, nt)``: one
+``8×(H·bk) @ (H·bk)×nt`` dot gives every lane its own head's sum. The
+multi-head kernel is named ``spmm_mxu_mh``; the single-head one is
+unchanged.
+
 Grid order (``grid_order``, tuner-selected — paper §4.2's
 occupancy-aware scheduling choice): ``"n_outer"`` is ``(n/nt, nb)``,
 ``"block_outer"`` is ``(nb, n/nt)`` and fetches each block's values
@@ -42,39 +51,59 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.formats import WINDOW
-from repro.kernels.gather import (default_interpret, fetch_rows, lane_tile,
-                                  row_view)
+from repro.kernels.gather import (default_interpret, fetch_rows,
+                                  head_masks, lane_tile, row_view)
 
 GRID_ORDERS = ("n_outer", "block_outer")
 
 
-def _kernel(cols_ref, vals_ref, b_hbm, out_ref, rows, sem, *, lane_axis):
+def _kernel(cols_ref, vals_ref, b_hbm, out_ref, rows, sem, *, lane_axis,
+            heads, head_dim):
     nt = out_ref.shape[2]
     lanes = lane_tile(pl.program_id(lane_axis), nt)
     fetch_rows(b_hbm, cols_ref, lambda g, w: rows.at[w], sem, lanes)
     bk = rows.shape[0]
-    # 8×BK @ BK×NT on the MXU, f32 accumulation.
+    vals = vals_ref[0]
+    b_rows = rows[...].reshape(bk, nt)
+    if heads:
+        # (H·bk, nt): head h's block of rows keeps head h's lanes only.
+        masks = head_masks((bk, nt), pl.program_id(lane_axis) * nt, heads,
+                           head_dim)
+        b_rows = jnp.concatenate([jnp.where(m, b_rows, 0.0) for m in masks],
+                                 axis=0)
+    # 8×BK @ BK×NT on the MXU (8×(H·BK) @ (H·BK)×NT), f32 accumulation.
     out_ref[0] = jax.lax.dot_general(
-        vals_ref[0], rows[...].reshape(bk, nt), (((1,), (0,)), ((), ())),
+        vals, b_rows, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("nt", "grid_order", "interpret"))
+    jax.jit, static_argnames=("nt", "grid_order", "head_dim", "interpret"))
 def spmm_mxu(tc_vals, tc_cols, b, *, nt: int = 128,
-             grid_order: str = "n_outer", interpret: bool | None = None):
+             grid_order: str = "n_outer", head_dim: int | None = None,
+             interpret: bool | None = None):
     """Per-block TC partial output, shape ``(nb * 8, n)``.
 
     Args:
       tc_vals: (nb, 8, bk) f32 condensed blocks (zero padded). Under the
         segmented launch a "block" is one §4.3 segment — ``bk`` is then
         ``ts · bk`` flattened condensed vectors of a single window.
+        Multi-head: (nb, 8, bk, H), one value per head (``head_dim``
+        given).
       tc_cols: (nb, bk) i32 source column of each condensed vector.
       b: (k, n) dense matrix; n must be a multiple of ``nt`` (ops.py
          pads).
       grid_order: "n_outer" or "block_outer" (see module docstring).
+      head_dim: width ``c`` of each of the H heads of ``b``'s columns
+        (multi-head values only).
     """
-    nb, _, bk = tc_vals.shape
+    heads = None
+    if tc_vals.ndim == 4:
+        heads = tc_vals.shape[3]
+        tc_vals = jnp.moveaxis(tc_vals, 3, 2).reshape(
+            tc_vals.shape[0], WINDOW, -1)
+    nb, bk = tc_cols.shape
+    vk = tc_vals.shape[2]
     k, n = b.shape
     assert n % nt == 0, (n, nt)
     assert grid_order in GRID_ORDERS, grid_order
@@ -91,12 +120,13 @@ def spmm_mxu(tc_vals, tc_cols, b, *, nt: int = 128,
         out_map = lambda i, j: (i, 0, j)    # noqa: E731
 
     out = pl.pallas_call(
-        functools.partial(_kernel, lane_axis=lane_axis),
-        name="spmm_mxu",
+        functools.partial(_kernel, lane_axis=lane_axis, heads=heads,
+                          head_dim=head_dim),
+        name="spmm_mxu_mh" if heads else "spmm_mxu",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, bk), cols_map, memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, WINDOW, bk), vals_map),
+            pl.BlockSpec((1, WINDOW, vk), vals_map),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, WINDOW, nt), out_map),

@@ -1,10 +1,15 @@
-"""GNN models (GCN, AGNN) on Libra hybrid sparse operators.
+"""GNN models (GCN, AGNN, UniMP) on Libra hybrid sparse operators.
 
 This is the paper's end-to-end application (§5.5): SpMM performs feature
 aggregation, SDDMM computes per-edge attention. Gradients follow the
 classic duality — the VJP of a value-parameterized SpMM is an SpMM with
 the transposed plan (for features) plus an SDDMM with the same sparsity
 (for edge values) — so *every* matmul in training runs through Libra ops.
+
+Edge values are ``(nnz,)``, or ``(nnz, H)`` for multi-head attention
+(UniMP): the dense operands then hold H heads of ``c`` features
+contiguously, and every sparse call runs all heads fused
+(:mod:`repro.kernels.ops`).
 """
 from __future__ import annotations
 
@@ -20,7 +25,8 @@ from repro.core.formats import device_arrays
 from repro.core.windows import num_windows
 from repro.kernels import ref
 from repro.kernels.ops import sddmm_apply, spmm_apply
-from repro.obs.trace import get_tracer
+from repro.obs.trace import NULL_SPAN, get_tracer
+from repro.tune.model import DEFAULT_TUNE
 from repro.sparse.matrix import SparseCSR, coo_to_csr
 
 
@@ -120,9 +126,10 @@ class GraphOps:
         """C = A(edge_vals) @ B, differentiable in (edge_vals, b)."""
         return _spmm_ev(self, edge_vals, b)
 
-    def sddmm(self, x, y):
-        """vals[p] = ⟨X[row_p], Y[col_p]⟩, differentiable in (x, y)."""
-        return _sddmm_ev(self, x, y)
+    def sddmm(self, x, y, heads: int | None = None):
+        """vals[p] = ⟨X[row_p], Y[col_p]⟩, differentiable in (x, y); with
+        ``heads`` = H, one score per head, ``(nnz, H)``."""
+        return _sddmm_ev(self, x, y, heads)
 
     def fixed_spmm(self, b, backend: str | None = None):
         """C = A @ B with the plan's baked-in values (no grad wrt values)."""
@@ -158,6 +165,26 @@ def _reorder_x(x, perm):
     return x if perm is None else jnp.take(x, perm, axis=0)
 
 
+def head_span(op: str, heads: int | None, width: int, tile: int):
+    """A ``graphops.heads`` span around one multi-head sparse call, with
+    its head layout (:func:`repro.obs.explain.head_counts`) as
+    attributes; computed only on an enabled tracer, and no span for a
+    single-head call. Calls are traced once per compile, so the spans
+    count call sites of a traced step."""
+    tr = get_tracer()
+    if not tr.enabled or not heads or heads == 1:
+        return NULL_SPAN
+    from repro.obs.explain import head_counts
+
+    return tr.span("graphops.heads", op=op,
+                   **head_counts(heads, width // heads, tile))
+
+
+def edge_heads(edge_vals) -> int | None:
+    """``H`` for ``(nnz, H)`` edge values, ``None`` for ``(nnz,)``."""
+    return edge_vals.shape[1] if edge_vals.ndim == 2 else None
+
+
 # Each sparse operator of the training step runs under one named scope —
 # ``spmm``, ``sddmm`` or ``edge_softmax`` — so a profile attributes every
 # device op to the operator that issued it. The scopes wrap each apply,
@@ -172,7 +199,8 @@ def _spmm(g: GraphOps, edge_vals, b, *, transposed: bool = False):
     arrs, m, nwin, cfg, unperm = (
         (g.arrs_t, g.k, g.nwin_t, g.cfg_t, g._unperm_t) if transposed
         else (g.arrs, g.m, g.nwin, g.cfg, g._unperm))
-    with jax.named_scope("spmm"):
+    with head_span("spmm", edge_heads(edge_vals), b.shape[1],
+                   (cfg or DEFAULT_TUNE).nt), jax.named_scope("spmm"):
         with jax.named_scope("revalue"):
             if transposed:
                 edge_vals = edge_vals[g.perm_dev]
@@ -183,11 +211,15 @@ def _spmm(g: GraphOps, edge_vals, b, *, transposed: bool = False):
             return _unreorder(out, unperm)
 
 
-def _sddmm(g: GraphOps, x, y):
-    """``vals[p] = ⟨x[row_p], y[col_p]⟩`` under the ``sddmm`` scope."""
-    with jax.named_scope("sddmm"):
+def _sddmm(g: GraphOps, x, y, heads: int | None = None):
+    """``vals[p] = ⟨x[row_p], y[col_p]⟩`` (per head with ``heads``) under
+    the ``sddmm`` scope."""
+    with head_span("sddmm", heads, x.shape[1],
+                   (g.cfg_sd or DEFAULT_TUNE).kf_tile), \
+            jax.named_scope("sddmm"):
         return sddmm_apply(g.arrs_sd, _reorder_x(x, g._x_perm), y,
-                           nnz=g.nnz, backend=g.backend, cfg=g.cfg_sd)
+                           nnz=g.nnz, backend=g.backend, cfg=g.cfg_sd,
+                           heads=heads)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -203,24 +235,24 @@ def _spmm_ev_bwd(g, resid, d_c):
     edge_vals, b = resid
     # dB = A(v)^T @ dC — SpMM on the transposed plan with permuted values.
     d_b = _spmm(g, edge_vals, d_c, transposed=True)
-    # dv[p] = dC[row_p] · B[col_p] — SDDMM with A's sparsity.
-    d_vals = _sddmm(g, d_c, b)
+    # dv[p] = dC[row_p] · B[col_p] — SDDMM with A's sparsity (per head).
+    d_vals = _sddmm(g, d_c, b, edge_heads(edge_vals))
     return d_vals.astype(edge_vals.dtype), d_b.astype(b.dtype)
 
 
 _spmm_ev.defvjp(_spmm_ev_fwd, _spmm_ev_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _sddmm_ev(g: GraphOps, x, y):
-    return _sddmm(g, x, y)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 3))
+def _sddmm_ev(g: GraphOps, x, y, heads):
+    return _sddmm(g, x, y, heads)
 
 
-def _sddmm_ev_fwd(g, x, y):
-    return _sddmm_ev(g, x, y), (x, y)
+def _sddmm_ev_fwd(g, x, y, heads):
+    return _sddmm_ev(g, x, y, heads), (x, y)
 
 
-def _sddmm_ev_bwd(g, resid, d_vals):
+def _sddmm_ev_bwd(g, heads, resid, d_vals):
     x, y = resid
     # dX = A(dv) @ Y ; dY = A(dv)^T @ X — both SpMMs through Libra plans.
     d_x = _spmm(g, d_vals, y)
@@ -232,7 +264,8 @@ _sddmm_ev.defvjp(_sddmm_ev_fwd, _sddmm_ev_bwd)
 
 
 def edge_softmax(g: GraphOps, scores):
-    """Numerically stable per-destination-row softmax over edge scores."""
+    """Numerically stable per-destination-row softmax over edge scores,
+    ``(nnz,)`` or ``(nnz, H)`` (each head apart)."""
     with jax.named_scope("edge_softmax"):
         mx = jax.ops.segment_max(scores, g.edge_row, num_segments=g.m)
         e = jnp.exp(scores - mx[g.edge_row])
@@ -289,4 +322,90 @@ def agnn_forward(params, g: GraphOps, x):
         h = h @ lp["w"]
         if i < len(params) - 1:
             h = jax.nn.relu(h)
+    return h
+
+
+# ---------------------------------------------------------------- UniMP ---
+def init_unimp(rng, dims: list[int], heads: int):
+    """UniMP layers (Shi et al., arXiv:2009.03509; PyG's
+    ``TransformerConv`` with ``beta=True``), widths ``dims``. Every layer
+    but the last concatenates ``heads`` heads of ``dims[i+1] // heads``
+    features; the last averages ``heads`` heads of ``dims[-1]``. Query,
+    key, value and skip projections carry biases, the gate none, as PyG's
+    defaults; weights are Glorot-scaled normal, biases uniform in
+    ``±1/√d_in`` (PyG ``Linear``'s bias init); each layer but the last
+    has a LayerNorm (scale 1, shift 0) after it.
+
+    Weights are ``(d_in, H, c)`` and biases ``(H, c)`` for the query,
+    key and value, so a layer's parameters give its head layout."""
+    layers = []
+    for i, key in enumerate(jax.random.split(rng, len(dims) - 1)):
+        d_in, d_out = dims[i], dims[i + 1]
+        last = i == len(dims) - 2
+        c = d_out if last else d_out // heads
+        ks = jax.random.split(key, 9)
+        bound = 1.0 / np.sqrt(d_in)
+
+        def weight(k, shape, fan):
+            return jax.random.normal(k, shape, jnp.float32) / np.sqrt(fan)
+
+        def bias(k, shape):
+            return jax.random.uniform(k, shape, jnp.float32, -bound, bound)
+
+        lp = {"q_w": weight(ks[0], (d_in, heads, c), d_in),
+              "q_b": bias(ks[1], (heads, c)),
+              "k_w": weight(ks[2], (d_in, heads, c), d_in),
+              "k_b": bias(ks[3], (heads, c)),
+              "v_w": weight(ks[4], (d_in, heads, c), d_in),
+              "v_b": bias(ks[5], (heads, c)),
+              "r_w": weight(ks[6], (d_in, d_out), d_in),
+              "r_b": bias(ks[7], (d_out,)),
+              "beta_w": weight(ks[8], (3 * d_out,), 3 * d_out)}
+        if not last:
+            lp["ln_g"] = jnp.ones((d_out,), jnp.float32)
+            lp["ln_b"] = jnp.zeros((d_out,), jnp.float32)
+        layers.append(lp)
+    return layers
+
+
+def _unimp_layer(lp, g, x, concat: bool):
+    """One ``TransformerConv`` (``beta=True``): per head ``h``,
+    ``α_ij = softmax_j(q_i·k_j / √c)`` over row ``i``'s edges (SDDMM →
+    edge softmax), ``m_i = Σ_j α_ij v_j`` (SpMM), all heads in one fused
+    call each; heads concatenated or averaged; then the gate
+    ``β = σ(w_βᵀ[r ‖ m ‖ r − m])`` mixes the skip path ``r`` with ``m``."""
+    d_in, heads, c = lp["q_w"].shape
+    with jax.named_scope("qkv"):
+        def proj(name):
+            return x @ lp[f"{name}_w"].reshape(d_in, -1) \
+                + lp[f"{name}_b"].reshape(-1)
+        # 1/√c rides on q, so the scores leave the SDDMM scaled.
+        q = proj("q") * (1.0 / np.sqrt(c))
+        k, v = proj("k"), proj("v")
+        r = x @ lp["r_w"] + lp["r_b"]
+    att = edge_softmax(g, g.sddmm(q, k, heads=heads))
+    m = g.spmm(att, v)
+    if not concat:
+        m = m.reshape(-1, heads, c).mean(axis=1)
+    with jax.named_scope("gate"):
+        beta = jax.nn.sigmoid(
+            jnp.concatenate([r, m, r - m], axis=-1) @ lp["beta_w"])[:, None]
+        return beta * r + (1.0 - beta) * m
+
+
+def _layer_norm(h, scale, shift, eps: float = 1e-5):
+    mu = h.mean(axis=-1, keepdims=True)
+    var = ((h - mu) ** 2).mean(axis=-1, keepdims=True)
+    return (h - mu) * jax.lax.rsqrt(var + eps) * scale + shift
+
+
+def unimp_forward(params, g: GraphOps, x):
+    """UniMP: ``TransformerConv`` layers (:func:`init_unimp`), each but
+    the last followed by LayerNorm and ReLU."""
+    h = x
+    for i, lp in enumerate(params):
+        last = i == len(params) - 1
+        h = _unimp_layer(lp, g, h, concat=not last)
+        if not last:
+            h = jax.nn.relu(_layer_norm(h, lp["ln_g"], lp["ln_b"]))
     return h

@@ -108,6 +108,22 @@ def plan_counts(plan, kind: str) -> dict:
     }
 
 
+def head_counts(heads: int, head_dim: int, tile: int) -> dict:
+    """The head layout of one multi-head sparse call: ``heads`` heads of
+    ``head_dim`` features each, laid contiguously (a head starts
+    ``head_stride`` features after the one before it, which equals
+    ``head_dim`` by construction: the layout pads no head, only the last
+    lane tile), padded to whole
+    ``tile``-lane tiles; ``lane_fill`` is the share of the padded width
+    that holds heads, in %. The multi-head calls of
+    :class:`repro.models.gnn.GraphOps` carry these as the attributes of
+    their ``graphops.heads`` spans on an enabled tracer."""
+    width = heads * head_dim
+    padded = -(-width // tile) * tile
+    return {"heads": heads, "head_dim": head_dim, "head_stride": head_dim,
+            "lane_fill": 100.0 * width / padded}
+
+
 def _padding_report(plan, kind: str) -> dict:
     """Zero padding materialized by the condensed formats (bytes the
     kernels stream but the matrix never had)."""

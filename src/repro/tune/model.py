@@ -25,6 +25,14 @@ features:
 
 The result is a :class:`TuneConfig` — the single object every layer
 (preprocess, ops, kernels, benchmarks) parameterizes through.
+
+Heads. A plan is tuned once per graph and serves every width and head
+count (:mod:`repro.kernels.gather` states the multi-head layout). Any
+lane tile fits that layout: the kernels map each lane to its head, so a
+head may straddle two tiles and no tile has to hold whole heads. What
+grows with ``H`` heads is a step's value or score block (``H`` values
+per slot, ``H`` score rows), a few KiB at the tuned caps, inside the
+budget's headroom; the footprint model prices one head.
 """
 from __future__ import annotations
 

@@ -142,7 +142,8 @@ def test_training_steps_scope_each_sparse_operator(graph):
     operator scopes, and each operator the step runs has its scope."""
     import re
 
-    from repro.dist.gnn import make_agnn_train_step, make_gcn_train_step
+    from repro.dist.gnn import (make_agnn_train_step, make_gcn_train_step,
+                                make_unimp_train_step)
 
     g = gnn.GraphOps(graph)
     x = jnp.ones((graph.m, 8), jnp.float32)
@@ -155,14 +156,105 @@ def test_training_steps_scope_each_sparse_operator(graph):
             gcn, x, labels, ev).compile().as_text(),
         "agnn_train_step": make_agnn_train_step(g).lower(
             agnn, x, labels).compile().as_text(),
+        "unimp_train_step": make_unimp_train_step(g).lower(
+            gnn.init_unimp(jax.random.PRNGKey(0), [8, 8, 4], 2), x,
+            labels).compile().as_text(),
     }
     operators = {"spmm", "sddmm", "edge_softmax"}
+    dense = {"qkv", "gate"}
     for name, text in texts.items():
         seen = set()
         for op_name in re.findall(r'op_name="([^"]*)"', text):
             parts = _scope_names(op_name)
-            inside = operators & set(parts)
+            inside = (operators | dense) & set(parts)
             assert len(inside) <= 1, op_name
             seen |= inside
         assert f"jit({name})" in text
-        assert seen == ({"spmm"} if name == "gcn_train_step" else operators)
+        assert seen == ({"spmm"} if name == "gcn_train_step" else operators
+                        | (dense if name == "unimp_train_step" else set()))
+
+
+# ---------------------------------------------------------------- UniMP ---
+@pytest.fixture(scope="module")
+def looped():
+    """A small power-law graph with a self loop on every node, as the
+    benchmark's graphs have."""
+    a = power_law_csr(64, 64, 4.0, seed=9)
+    rows, cols, _ = a.to_coo()
+    keep = rows != cols
+    n = np.arange(a.m)
+    from repro.sparse.matrix import coo_to_csr
+
+    r = np.concatenate([rows[keep], n])
+    c = np.concatenate([cols[keep], n])
+    return coo_to_csr(a.m, a.k, r, c, np.ones(r.size, np.float32))
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_unimp_matches_the_plain_reference(looped, backend):
+    """Logits and every parameter's gradient of ``unimp_forward`` through
+    ``GraphOps`` against ``bench/configs/unimp.py``'s COO reference, on
+    seeded random weights, at ``highest`` precision. The key bias's true
+    gradient is 0 (a shift of every key of a row shifts its scores
+    alike), so each leaf is held to the largest gradient's scale."""
+    from bench.configs import unimp
+
+    from repro.api import ExecSpec
+
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.standard_normal((looped.m, 12)), jnp.float32)
+    labels = jnp.asarray(rng.integers(0, 5, looped.m))
+    params = gnn.init_unimp(jax.random.PRNGKey(4), [12, 16, 16, 5], 4)
+    rows, cols, _ = looped.to_coo()
+    graph = {"rows": jnp.asarray(rows), "cols": jnp.asarray(cols),
+             "nodes": looped.m}
+    g = gnn.GraphOps(looped, spec=ExecSpec(backend=backend, tune="model"))
+
+    def nll(logits):
+        lp = jax.nn.log_softmax(logits)
+        return -jnp.take_along_axis(lp, labels[:, None], axis=1).mean()
+
+    def program(p):
+        return gnn.unimp_forward(p, g, x)
+
+    def reference(p):
+        return unimp.reference_logits(p, graph, {"feats": x})
+
+    with jax.default_matmul_precision("highest"):
+        got, want = program(params), reference(params)
+        g_got = jax.grad(lambda p: nll(program(p)))(params)
+        g_want = jax.grad(lambda p: nll(reference(p)))(params)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-5)
+    scale = max(float(jnp.abs(v).max()) for v in jax.tree.leaves(g_want))
+    for u, v in zip(jax.tree.leaves(g_got), jax.tree.leaves(g_want)):
+        np.testing.assert_allclose(np.asarray(u), np.asarray(v), rtol=1e-4,
+                                   atol=1e-5 * scale)
+
+
+def test_unimp_head_counters_on_an_enabled_tracer(looped):
+    """Each multi-head call site of a traced step opens a
+    ``graphops.heads`` span with its layout; a disabled tracer records
+    nothing."""
+    from repro.dist.gnn import make_unimp_train_step
+    from repro.obs.trace import Tracer, use_tracer
+
+    g = gnn.GraphOps(looped)
+    x = jnp.ones((looped.m, 8), jnp.float32)
+    labels = jnp.zeros((looped.m,), jnp.int32)
+    params = gnn.init_unimp(jax.random.PRNGKey(0), [8, 256, 40], 4)
+    tr = Tracer()
+    with use_tracer(tr):
+        make_unimp_train_step(g).lower(params, x, labels)
+    spans = [s.attrs for s in tr.roots if s.name == "graphops.heads"]
+    # Per layer: forward SDDMM and SpMM; backward SpMM (into v), SDDMM
+    # (dα), and the SDDMM's two SpMMs (into q and k).
+    assert len(spans) == 2 * 6
+    assert sorted(s["op"] for s in spans) == ["sddmm"] * 4 + ["spmm"] * 8
+    widths = {(s["heads"], s["head_dim"], s["head_stride"], s["lane_fill"])
+              for s in spans}
+    assert widths == {(4, 64, 64, 100.0), (4, 40, 40, 62.5)}
+    quiet = Tracer(enabled=False)
+    with use_tracer(quiet):
+        make_unimp_train_step(g).lower(params, x, labels)
+    assert not quiet.roots

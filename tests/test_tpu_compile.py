@@ -150,3 +150,48 @@ def test_applies_name_their_kernels_and_scopes(one_chip, graphs, kind):
         assert op_name.endswith(f"{kind}_{stream}/pallas_call"), op_name
     assert len(kernels) == 2, kernels
     assert re.search(r'op_name="[^"]*/combine/[^"]*scatter-add', text)
+
+
+@pytest.mark.parametrize("heads,head_dim", [(4, 64), (4, 40)])
+def test_multihead_applies_compile_for_v5e(one_chip, graphs, heads,
+                                           head_dim):
+    """UniMP's layouts: 4 heads of 64 (256 lanes, two whole heads a lane
+    tile) and 4 heads of 40 (160 features padded to 256; head 3
+    straddles the first two tiles). Both applies compile with their
+    kernels named ``_mh``, inside the ``mxu``/``vpu`` scopes."""
+    from repro.kernels import ref
+
+    a = graphs["powerlaw"]
+    width = heads * head_dim
+    sp = LibraSpMM(a, spec=ExecSpec(backend="pallas", tune="model",
+                                    tune_n=N))
+    arrs = sp.arrays.for_backend("pallas", revalue=True, segmented=True)
+    ev = jax.ShapeDtypeStruct((a.nnz, heads), jnp.float32,
+                              sharding=one_chip)
+    b = jax.ShapeDtypeStruct((a.k, width), jnp.float32, sharding=one_chip)
+
+    def spmm(arrs, ev, b):
+        return spmm_apply(ref.revalue_spmm_arrays(arrs, ev), b, m=sp.m,
+                          nwin=sp.nwin, backend="pallas",
+                          cfg=sp.tune_config, interpret=False)
+
+    sd = LibraSDDMM(a, spec=ExecSpec(backend="pallas", tune="model",
+                                     tune_kf=KF))
+    x = jax.ShapeDtypeStruct((a.m, width), jnp.float32, sharding=one_chip)
+    y = jax.ShapeDtypeStruct((a.k, width), jnp.float32, sharding=one_chip)
+    compiled = {
+        "spmm": jax.jit(spmm).lower(_shapes(arrs, one_chip), ev,
+                                    b).compile(),
+        "sddmm": sddmm_apply.lower(
+            _shapes(sd.arrays.for_backend("pallas", segmented=True),
+                    one_chip), x, y, nnz=sd.nnz, backend="pallas",
+            cfg=sd.tune_config, heads=heads, interpret=False).compile(),
+    }
+    for kind, exe in compiled.items():
+        _check(exe)
+        kernels = _kernel_scopes(exe.as_text())
+        for stream in ("mxu", "vpu"):
+            (op_name,) = [v for k, v in kernels.items()
+                          if k.startswith(f"{kind}_{stream}_mh")]
+            assert f"/{stream}/" in op_name, op_name
+        assert len(kernels) == 2, kernels
