@@ -20,8 +20,8 @@ def main() -> None:
     b = jnp.asarray(rng.standard_normal((a.k, 128)).astype(np.float32))
     spmm = LibraSpMM(a)                       # preprocess + autotune once
     cfg = spmm.tune_config                    # the model-tuned plan choice
-    print(f"tuned: threshold={cfg.threshold} nt={cfg.nt} "
-          f"grid_order={cfg.grid_order} (source={cfg.source})")
+    print(f"tuned: threshold={cfg.threshold} lane-tile cap={cfg.nt} "
+          f"(source={cfg.source})")
     c = spmm(b)                               # fast XLA path
     c_pallas = spmm(b, backend="pallas")      # Pallas kernels (interpreted on CPU)
     oracle = ref.spmm_dense_oracle(a.to_dense(), np.asarray(b))

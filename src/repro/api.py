@@ -60,8 +60,9 @@ class ExecSpec:
     Tuning:
       tune:             "model" | "search" | "off" | TuneConfig
       tune_backend:     backend the empirical search times
-      tune_n / tune_kf: dense width the tuner prices (SpMM B cols /
-                        SDDMM feature dim)
+      tune_n / tune_kf: dense width the tuner prices the TC/VPU split
+                        at (SpMM B cols / SDDMM feature dim); each
+                        call's lane tile follows its own width
       tune_cache:       PlanCache instance or cache-dir path
 
     Execution:

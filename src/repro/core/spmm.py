@@ -26,7 +26,7 @@ per matrix instead of hardcoded):
 
 * ``tune="model"`` (default) — the analytical occupancy model in
   :mod:`repro.tune` picks the TC/VPU threshold from the matrix's vector
-  histogram and sizes ``nt``/grid order to the VMEM budget.
+  histogram and sizes the ``nt`` cap to the VMEM budget.
   Cheap (one feature pass, no timing).
 * ``tune="search"`` — empirically times a small candidate grid through
   this apply path and keeps the argmin; memoized in the persistent

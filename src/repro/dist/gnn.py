@@ -33,8 +33,8 @@ from repro.api import UNSET, ExecSpec, resolve_spec
 from repro.dist.partition import partition_sddmm, partition_spmm
 from repro.dist.sparse import (SHARD_AXIS, place_partition, sddmm_sharded,
                                spmm_sharded)
-from repro.models.gnn import (edge_heads, edge_softmax, gcn_forward,
-                              head_span, transpose_csr)
+from repro.models.gnn import (call_spans, edge_heads, edge_softmax,
+                              gcn_forward, transpose_csr)
 from repro.sparse.matrix import SparseCSR
 
 
@@ -97,15 +97,14 @@ class DistGraphOps:
     # (repro.models.gnn).
     def _spmm(self, part, b, edge_vals=None):
         heads = None if edge_vals is None else edge_heads(edge_vals)
-        with head_span("spmm", heads, b.shape[1], part.run_cfg.nt), \
+        with call_spans("spmm", heads, b.shape[1], part.run_cfg), \
                 jax.named_scope("spmm"):
             return spmm_sharded(part, b, mesh=self.mesh, axis=self.axis,
                                 backend=self.backend, edge_vals=edge_vals,
                                 b_layout=self.b_layout)
 
     def _sddmm(self, x, y, heads=None):
-        with head_span("sddmm", heads, x.shape[1],
-                       self.part_sd.run_cfg.kf_tile), \
+        with call_spans("sddmm", heads, x.shape[1], self.part_sd.run_cfg), \
                 jax.named_scope("sddmm"):
             return sddmm_sharded(self.part_sd, x, y, mesh=self.mesh,
                                  axis=self.axis, backend=self.backend,

@@ -21,12 +21,10 @@ Each shard is then a self-contained Libra problem:
   matrix get different TC/VPU thresholds and tile sizes. Preprocessing
   consumes the per-shard config; the kernel-tile fields are combined
   conservatively (min across shards) into one ``run_cfg``, because a
-  ``shard_map`` body is a single program. ``tune="search"`` keeps the
-  per-shard *thresholds* model-tuned but times candidate ``run_cfg``
-  kernel tiles through the sharded apply itself (a real mesh when one
-  is passed, otherwise a vmap-over-shards emulation of the shard_map
-  body — the identical per-device program), memoized under a
-  partition-level key in the persistent plan cache.
+  ``shard_map`` body is a single program. ``tune="search"`` builds the
+  model-tuned partition: the ``run_cfg`` holds only lane-tile caps, a
+  VMEM bound (each call derives its tile from its width), so a
+  partition has nothing to time.
 * **padded stacking** — per-shard device arrays are padded to common
   shapes and stacked on a leading shard axis so ``shard_map`` can split
   them over a mesh axis. Padding is *semantically inert by
@@ -218,121 +216,13 @@ def _combine_run_cfg(cfgs: list[TuneConfig], bk, ts_tile,
         nt=min(c.nt for c in cfgs),
         kf_tile=min(c.kf_tile for c in cfgs),
         threshold=None, bk=bk, ts_tile=ts_tile,
-        ts=seg_ts, cs=seg_cs,
-        grid_order="n_outer", source="dist",
+        ts=seg_ts, cs=seg_cs, source="dist",
     )
 
 
 def _offset_pos(pos: np.ndarray, off: int) -> np.ndarray:
     """Shift shard-local canonical nnz positions to global (−1 stays)."""
     return np.where(pos >= 0, pos + off, -1).astype(np.int32)
-
-
-# ------------------------------------------------- run_cfg search (dist) ---
-def _run_cfg_candidates(base: TuneConfig, op: str,
-                        backend: str) -> list[TuneConfig]:
-    """Candidate run_cfgs around the model-combined base (candidate #0,
-    the floor search can't lose to). Kernel-tile perturbations only
-    matter on ``"pallas"`` — the XLA reference path never reads them, so
-    its grid is the base alone (ties resolve to it)."""
-    cands = [base]
-    if backend != "pallas":
-        return cands
-    if op == "spmm":
-        cands.append(base.replace(grid_order="block_outer"))
-        if base.nt // 2 >= 128:
-            cands.append(base.replace(nt=base.nt // 2))
-    elif base.kf_tile // 2 >= 128:
-        cands.append(base.replace(kf_tile=base.kf_tile // 2))
-    seen, out = set(), []
-    for c in cands:
-        if c not in seen:
-            seen.add(c)
-            out.append(c)
-    return out
-
-
-def _search_run_cfg(part, op: str, a: SparseCSR, *, width: int,
-                    mode: str, threshold, bk, ts_tile, backend: str,
-                    mesh, timer, cache, reorder=None) -> TuneConfig:
-    """Time candidate run_cfgs through the sharded apply (real mesh) or
-    its vmap-over-shards emulation (no mesh — the same per-device
-    program), memoized under a partition-level plan-cache key."""
-    from repro.tune import PlanCache, median_timer, tune_key
-
-    pc = cache if isinstance(cache, PlanCache) else PlanCache(cache)
-    key = tune_key(a, op=f"{op}#p{part.n_shards}", width=width,
-                   dtype="float32", backend=backend, mode=mode,
-                   tune="search", threshold=threshold, bk=bk,
-                   ts_tile=ts_tile, reorder=reorder)
-    hit = pc.get(key)
-    if hit is not None:
-        return hit
-    timer = timer or median_timer()
-    rng = np.random.default_rng(0)
-    if op == "spmm":
-        operands = (jnp.asarray(
-            rng.standard_normal((a.k, width)).astype(np.float32)),)
-    else:
-        operands = (
-            jnp.asarray(rng.standard_normal((a.m, width)).astype(np.float32)),
-            jnp.asarray(rng.standard_normal((a.k, width)).astype(np.float32)))
-    candidates = _run_cfg_candidates(part.run_cfg, op, backend)
-    best_i, timings = 0, {}
-    for i, cand in enumerate(candidates):
-        fn = _timed_apply(dataclasses.replace(part, run_cfg=cand), op,
-                          backend=backend, mesh=mesh)
-        timings[i] = timer(lambda: fn(*operands))
-        if timings[i] < timings[best_i]:
-            best_i = i
-    cfg = candidates[best_i].replace(source="search")
-    pc.put(key, cfg, meta={"timings_s": {str(i): t
-                                         for i, t in timings.items()},
-                           "n_shards": part.n_shards})
-    return cfg
-
-
-def _timed_apply(part, op: str, *, backend: str, mesh):
-    """Jitted sharded apply for one candidate partition: the real
-    ``shard_map`` op when a mesh is given, otherwise a ``vmap`` over the
-    stacked shard axis running the identical per-device program."""
-    import jax
-
-    if mesh is not None:
-        from repro.dist.sparse import sddmm_sharded, spmm_sharded
-
-        if op == "spmm":
-            return jax.jit(lambda b: spmm_sharded(part, b, mesh=mesh,
-                                                  backend=backend))
-        return jax.jit(lambda x, y: sddmm_sharded(part, x, y, mesh=mesh,
-                                                  backend=backend))
-    from repro.kernels.ops import map_batch, sddmm_apply, spmm_apply
-
-    if op == "spmm":
-        def apply_spmm(b):
-            def body(local):
-                arrs = {k: v for k, v in local.items() if k != "halo"}
-                b_halo = jnp.take(b, local["halo"], axis=0)
-                return spmm_apply(arrs, b_halo, m=part.rows_pad,
-                                  nwin=part.wmax, backend=backend,
-                                  cfg=part.run_cfg)
-            out = map_batch(backend, body, part.stacked)
-            return jnp.take(out.reshape(-1, b.shape[1]),
-                            part.out_gather, axis=0)
-        return jax.jit(apply_spmm)
-
-    def apply_sddmm(x, y):
-        x_panels = jnp.take(x, part.x_take, axis=0).reshape(
-            part.n_shards, part.rows_pad, x.shape[1])
-
-        def body(local, xx):
-            arrs = {k: v for k, v in local.items() if k != "halo"}
-            y_halo = jnp.take(y, local["halo"], axis=0)
-            return sddmm_apply(arrs, xx, y_halo, nnz=part.nnz_pad,
-                               backend=backend, cfg=part.run_cfg)
-        out = map_batch(backend, body, part.stacked, x_panels)
-        return jnp.take(out.reshape(-1), part.nnz_gather, axis=0)
-    return jax.jit(apply_sddmm)
 
 
 def _stack_spmm_segments(plans, shards, n_shards,
@@ -420,20 +310,14 @@ class SpMMPartition:
 def partition_spmm(a: SparseCSR, n_shards: int, *, mode=UNSET,
                    threshold=UNSET, tune=UNSET, bk=UNSET, ts_tile=UNSET,
                    tune_n=UNSET, tune_cache=UNSET, tune_backend=UNSET,
-                   mesh=None, timer=None,
                    spec: ExecSpec | None = None) -> SpMMPartition:
     """Split + per-shard tune + preprocess + pad/stack for sharded SpMM.
 
     Execution knobs live on one :class:`repro.api.ExecSpec` (``spec=``;
     the legacy kwargs keep working through the deprecation shim).
     ``spec.tune`` accepts ``"model"``/``"search"``/``"off"``/a
-    :class:`TuneConfig`. ``"search"`` keeps per-shard thresholds
-    model-tuned but empirically times candidate ``run_cfg`` kernel
-    tiles through the sharded apply (on ``mesh`` when given, else a
-    vmap-over-shards emulation of the same per-device program) and
-    memoizes the winner under a partition-level key in the persistent
-    plan cache (``spec.tune_cache``); ``spec.tune_backend`` selects the
-    timed backend (tile candidates only differ on ``"pallas"``).
+    :class:`TuneConfig`; ``"search"`` tunes as ``"model"`` (the module
+    docstring says why).
     ``bk``/``ts_tile`` are unified across shards (stacked block shapes
     must agree); each shard still gets its own threshold and tiles.
 
@@ -450,17 +334,8 @@ def partition_spmm(a: SparseCSR, n_shards: int, *, mode=UNSET,
                         tune_cache=tune_cache, tune_backend=tune_backend)
     mode, threshold, tune = spec.mode, spec.threshold, spec.tune
     bk, ts_tile = spec.bk, spec.ts_tile
-    tune_n, tune_cache = spec.tune_n, spec.tune_cache
-    tune_backend = spec.tune_backend
-    if tune == "search":
-        part = partition_spmm(a, n_shards, spec=spec.replace(tune="model"))
-        cfg = _search_run_cfg(part, "spmm", a, width=tune_n, mode=mode,
-                              threshold=threshold, bk=part.run_cfg.bk,
-                              ts_tile=part.run_cfg.ts_tile,
-                              backend=tune_backend, mesh=mesh, timer=timer,
-                              cache=tune_cache, reorder=spec.reorder)
-        meta = {**part.meta, "run_cfg_source": cfg.source}
-        return dataclasses.replace(part, run_cfg=cfg, meta=meta)
+    tune_n = spec.tune_n
+    tune = "model" if tune == "search" else tune
     # One global feature pass fixes the common block geometry (shared by
     # the base tune and the segment curve — no second O(nnz) pass).
     from repro.tune.model import matrix_features
@@ -638,13 +513,11 @@ class SDDMMPartition:
 def partition_sddmm(a: SparseCSR, n_shards: int, *, mode=UNSET,
                     threshold=UNSET, tune=UNSET, bk=UNSET, ts_tile=UNSET,
                     tune_kf=UNSET, tune_cache=UNSET, tune_backend=UNSET,
-                    mesh=None, timer=None,
                     spec: ExecSpec | None = None) -> SDDMMPartition:
     """SDDMM flavour of :func:`partition_spmm` (same sharding geometry;
     scores come back in canonical global nnz order via ``nnz_gather``;
-    same partition-level ``tune="search"`` and ``spec.reorder``
-    semantics — the legacy ``threshold=`` kwarg maps to
-    ``ExecSpec.sddmm_threshold``). Under reordering, ``x_take`` is
+    same ``tune="search"`` and ``spec.reorder`` semantics — the legacy
+    ``threshold=`` kwarg maps to ``ExecSpec.sddmm_threshold``). Under reordering, ``x_take`` is
     pre-composed with the row permutation and ``nnz_gather`` with the
     inverse nnz permutation, so X arrives and scores return in original
     order at zero extra runtime cost."""
@@ -654,17 +527,8 @@ def partition_sddmm(a: SparseCSR, n_shards: int, *, mode=UNSET,
                         tune_cache=tune_cache, tune_backend=tune_backend)
     mode, threshold, tune = spec.mode, spec.sddmm_threshold, spec.tune
     bk, ts_tile = spec.bk, spec.ts_tile
-    tune_kf, tune_cache = spec.tune_kf, spec.tune_cache
-    tune_backend = spec.tune_backend
-    if tune == "search":
-        part = partition_sddmm(a, n_shards, spec=spec.replace(tune="model"))
-        cfg = _search_run_cfg(part, "sddmm", a, width=tune_kf, mode=mode,
-                              threshold=threshold, bk=part.run_cfg.bk,
-                              ts_tile=part.run_cfg.ts_tile,
-                              backend=tune_backend, mesh=mesh, timer=timer,
-                              cache=tune_cache, reorder=spec.reorder)
-        meta = {**part.meta, "run_cfg_source": cfg.source}
-        return dataclasses.replace(part, run_cfg=cfg, meta=meta)
+    tune_kf = spec.tune_kf
+    tune = "model" if tune == "search" else tune
     from repro.tune.model import matrix_features
 
     feat = matrix_features(a)

@@ -266,15 +266,15 @@ class ShardedSpMM:
     """
 
     def __init__(self, a, mesh: Mesh, *, axis: str = SHARD_AXIS,
-                 spec: ExecSpec | None = None, timer=None, **part_kwargs):
+                 spec: ExecSpec | None = None, **part_kwargs):
         if part_kwargs:
             spec = resolve_spec(spec, "ShardedSpMM", **part_kwargs)
         spec = ExecSpec() if spec is None else spec
         self.spec = spec
         self.part = place_partition(
             a if isinstance(a, SpMMPartition)
-            else partition_spmm(a, int(mesh.shape[axis]), spec=spec,
-                                timer=timer), mesh, axis)
+            else partition_spmm(a, int(mesh.shape[axis]), spec=spec),
+            mesh, axis)
         assert int(mesh.shape[axis]) == self.part.n_shards
         self.mesh, self.axis = mesh, axis
         self.backend, self.b_layout = spec.backend, spec.b_layout
@@ -306,7 +306,7 @@ class ShardedSDDMM:
     """Engine-callable sharded SDDMM — see :class:`ShardedSpMM`."""
 
     def __init__(self, a, mesh: Mesh, *, axis: str = SHARD_AXIS,
-                 spec: ExecSpec | None = None, timer=None, **part_kwargs):
+                 spec: ExecSpec | None = None, **part_kwargs):
         if part_kwargs:
             if "y_layout" in part_kwargs:
                 part_kwargs["b_layout"] = part_kwargs.pop("y_layout")
@@ -317,8 +317,8 @@ class ShardedSDDMM:
         self.spec = spec
         self.part = place_partition(
             a if isinstance(a, SDDMMPartition)
-            else partition_sddmm(a, int(mesh.shape[axis]), spec=spec,
-                                 timer=timer), mesh, axis)
+            else partition_sddmm(a, int(mesh.shape[axis]), spec=spec),
+            mesh, axis)
         assert int(mesh.shape[axis]) == self.part.n_shards
         self.mesh, self.axis = mesh, axis
         self.backend, self.y_layout = spec.backend, spec.b_layout

@@ -19,10 +19,16 @@ passes:
    step DMAs exactly the rows its column/row ids name into VMEM
    (:mod:`repro.kernels.gather`), so operand traffic scales with the
    plan's padded non-zeros, not with ``k`` — there is no k-panel sweep.
-2. **Tuned tiling.** Every lane-tile / segment-cap / grid-order decision
-   arrives as one static :class:`repro.tune.model.TuneConfig` — emitted
+2. **Tuned tiling.** Every segment-cap decision and the lane-tile caps
+   arrive as one static :class:`repro.tune.model.TuneConfig` — emitted
    by the occupancy-aware tuner in :mod:`repro.tune` (or its defaults
-   when callers pass nothing). No module constants.
+   when callers pass nothing). No module constants. Each apply derives
+   its lane tile from the operand's width under those caps
+   (:func:`repro.tune.model.lane_tile`), so a call copies each dense
+   row once where they allow, not once per 128 lanes. With several
+   lane tiles SpMM runs ``block_outer``, which fetches each condensed
+   TC block once instead of once per lane tile; with one, both grid
+   orders run the same steps.
 3. **Fused combine epilogue.** The TC partials sum into their windows
    of C and the row-sorted VPU partials scatter-add over them — the
    TPU-deterministic analogue of the paper's atomicAdd combine. Both are
@@ -42,7 +48,8 @@ passes:
    dense operands of ``H·c`` columns that hold the heads contiguously
    (:mod:`repro.kernels.gather` states the layout). One call runs all
    heads fused: each kernel fetches an operand row once per lane tile
-   and splits its lanes by head. ``(nnz,)`` is the single-head case, and
+   (once per call where the width fits one tile) and splits its lanes
+   by head. ``(nnz,)`` is the single-head case, and
    ``(nnz, 1)`` runs the same single-head kernels.
 
 In a profile, each apply's ops fall under the named scopes ``mxu``
@@ -64,7 +71,7 @@ from repro.kernels.sddmm_mxu import sddmm_mxu
 from repro.kernels.sddmm_vpu import sddmm_vpu
 from repro.kernels.spmm_mxu import spmm_mxu
 from repro.kernels.spmm_vpu import spmm_vpu
-from repro.tune.model import DEFAULT_TUNE, TuneConfig
+from repro.tune.model import DEFAULT_TUNE, TuneConfig, lane_tile
 
 
 class ApplyError(RuntimeError):
@@ -188,9 +195,11 @@ def spmm_apply(arrs, b, *, m: int, nwin: int, backend: str = "xla",
                cfg: TuneConfig | None = None, interpret: bool | None = None):
     """Hybrid SpMM: C[m, n] = A_sp @ B using a preprocessed Libra plan.
 
-    ``cfg`` carries every tile-size / grid-order decision (a
+    ``cfg`` carries the plan's tile caps and segment caps (a
     :class:`repro.tune.model.TuneConfig`); callers that pass nothing get
-    the library default — module constants no longer exist.
+    the library default — module constants no longer exist. The lane
+    tile follows ``b``'s width under ``cfg.nt``
+    (:func:`repro.tune.model.lane_tile`).
     ``interpret`` defaults to compiled kernels on a TPU and the Pallas
     interpreter elsewhere. A plan revalued with ``(nnz, H)`` edge values
     (:func:`repro.kernels.ref.revalue_spmm_arrays`) multiplies ``B``'s
@@ -209,18 +218,19 @@ def spmm_apply(arrs, b, *, m: int, nwin: int, backend: str = "xla",
     if heads:
         assert n0 % heads == 0, (n0, heads)
         head_dim = n0 // heads
-    nt = cfg.nt
+    nt = lane_tile("spmm", n0, cfg, heads=heads)
+    order = "block_outer" if n0 > nt else "n_outer"
     with jax.named_scope("mxu"):
         b_p = _pad_to(b, 1, nt)
         if "tc_seg_vals" in arrs:
             # Segment-granular launch (§4.3 Ts decomposition): one grid
             # step per segment of ≤ ts blocks of one window.
             tc = spmm_mxu(arrs["tc_seg_vals"], arrs["tc_seg_cols"], b_p,
-                          nt=nt, grid_order=cfg.grid_order,
+                          nt=nt, grid_order=order,
                           head_dim=head_dim, interpret=interpret)
         else:
             tc = spmm_mxu(arrs["tc_vals"], arrs["tc_cols"], b_p, nt=nt,
-                          grid_order=cfg.grid_order, head_dim=head_dim,
+                          grid_order=order, head_dim=head_dim,
                           interpret=interpret)
     with jax.named_scope("vpu"):
         if "vpu_seg_vals" in arrs:
@@ -229,12 +239,12 @@ def spmm_apply(arrs, b, *, m: int, nwin: int, backend: str = "xla",
             # segment lengths fetches B rows for real elements only.
             partials = spmm_vpu(arrs["vpu_seg_vals"], arrs["vpu_seg_cols"],
                                 b_p, arrs.get("vpu_seg_len"), nt=nt,
-                                grid_order=cfg.grid_order,
+                                grid_order=order,
                                 head_dim=head_dim, interpret=interpret)
             vpu_rows = arrs["vpu_seg_row"]
         else:
             partials = spmm_vpu(arrs["vpu_vals"], arrs["vpu_cols"], b_p,
-                                nt=nt, grid_order=cfg.grid_order,
+                                nt=nt, grid_order=order,
                                 head_dim=head_dim, interpret=interpret)
             vpu_rows = arrs["vpu_row"]
     # Fused combine epilogue: the TC partials sum into their windows of
@@ -320,9 +330,10 @@ def sddmm_apply(arrs, x, y, *, nnz: int, backend: str = "xla",
                 interpret: bool | None = None):
     """Hybrid SDDMM: values[nnz] = sample(X @ Yᵀ) in canonical CSR order.
 
-    ``cfg.kf_tile`` tiles the feature dimension (padded here to whole
+    The feature dimension is tiled under ``cfg.kf_tile`` as its width
+    allows (:func:`repro.tune.model.lane_tile`), padded here to whole
     tiles — a lane-dense row is 128 wide in HBM regardless; padded
-    features are zeros). With ``heads`` = H, X and Y hold H heads of
+    features are zeros. With ``heads`` = H, X and Y hold H heads of
     ``kf / H`` columns each and the values are ``(nnz, H)``, one score
     per head.
     """
@@ -337,7 +348,7 @@ def sddmm_apply(arrs, x, y, *, nnz: int, backend: str = "xla",
     if heads:
         assert x.shape[1] % heads == 0, (x.shape, heads)
         head_dim = x.shape[1] // heads
-    kft = cfg.kf_tile
+    kft = lane_tile("sddmm", x.shape[1], cfg, heads=heads)
     with jax.named_scope("mxu"):
         x = _pad_to(x, 1, kft)
         y = _pad_to(y, 1, kft)

@@ -36,10 +36,11 @@ fetched rows once per head, each masked to its head's lanes
 multi-head kernel is named ``spmm_mxu_mh``; the single-head one is
 unchanged.
 
-Grid order (``grid_order``, tuner-selected — paper §4.2's
-occupancy-aware scheduling choice): ``"n_outer"`` is ``(n/nt, nb)``,
-``"block_outer"`` is ``(nb, n/nt)`` and fetches each block's values
-once instead of once per lane tile.
+Grid order (``grid_order``, chosen by
+:func:`repro.kernels.ops.spmm_apply` from the call's lane-tile count —
+paper §4.2's occupancy-aware scheduling choice): ``"n_outer"`` is
+``(n/nt, nb)``, ``"block_outer"`` is ``(nb, n/nt)`` and fetches each
+block's values once instead of once per lane tile.
 """
 from __future__ import annotations
 

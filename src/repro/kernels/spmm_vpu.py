@@ -31,7 +31,8 @@ lane (:func:`repro.kernels.gather.head_masks`), so one call fetches
 each B row once per lane tile for all heads. The multi-head kernel is
 named ``spmm_vpu_mh``; the single-head one is unchanged.
 
-``grid_order`` (tuner-selected) permutes the two grid dimensions:
+``grid_order`` (chosen by :func:`repro.kernels.ops.spmm_apply` from the
+call's lane-tile count) permutes the two grid dimensions:
 ``"n_outer"`` walks all tile groups per lane tile, ``"block_outer"``
 all lane tiles per group. Both are legal: every step owns its output
 block.

@@ -13,6 +13,7 @@ contiguously, and every sparse call runs all heads fused
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -26,7 +27,7 @@ from repro.core.windows import num_windows
 from repro.kernels import ref
 from repro.kernels.ops import sddmm_apply, spmm_apply
 from repro.obs.trace import NULL_SPAN, get_tracer
-from repro.tune.model import DEFAULT_TUNE
+from repro.tune.model import DEFAULT_TUNE, lane_tile
 from repro.sparse.matrix import SparseCSR, coo_to_csr
 
 
@@ -165,19 +166,27 @@ def _reorder_x(x, perm):
     return x if perm is None else jnp.take(x, perm, axis=0)
 
 
-def head_span(op: str, heads: int | None, width: int, tile: int):
-    """A ``graphops.heads`` span around one multi-head sparse call, with
-    its head layout (:func:`repro.obs.explain.head_counts`) as
-    attributes; computed only on an enabled tracer, and no span for a
-    single-head call. Calls are traced once per compile, so the spans
-    count call sites of a traced step."""
+@contextlib.contextmanager
+def call_spans(op: str, heads: int | None, width: int, cfg):
+    """The spans of one sparse call site, on an enabled tracer only: a
+    ``graphops.tile`` span with the lane tile the call takes under
+    ``cfg`` (:func:`repro.tune.model.lane_tile`) as attributes
+    (:func:`repro.obs.explain.tile_counts`), inside a ``graphops.heads``
+    span with its head layout (:func:`repro.obs.explain.head_counts`)
+    for a multi-head call. Calls are traced once per compile, so the
+    spans count call sites of a traced step."""
     tr = get_tracer()
-    if not tr.enabled or not heads or heads == 1:
-        return NULL_SPAN
-    from repro.obs.explain import head_counts
+    if not tr.enabled:
+        yield
+        return
+    from repro.obs.explain import head_counts, tile_counts
 
-    return tr.span("graphops.heads", op=op,
-                   **head_counts(heads, width // heads, tile))
+    tile = lane_tile(op, width, cfg or DEFAULT_TUNE, heads=heads)
+    heads_span = NULL_SPAN if not heads or heads == 1 else tr.span(
+        "graphops.heads", op=op, **head_counts(heads, width // heads, tile))
+    with heads_span, tr.span("graphops.tile", op=op,
+                             **tile_counts(width, tile)):
+        yield
 
 
 def edge_heads(edge_vals) -> int | None:
@@ -199,8 +208,8 @@ def _spmm(g: GraphOps, edge_vals, b, *, transposed: bool = False):
     arrs, m, nwin, cfg, unperm = (
         (g.arrs_t, g.k, g.nwin_t, g.cfg_t, g._unperm_t) if transposed
         else (g.arrs, g.m, g.nwin, g.cfg, g._unperm))
-    with head_span("spmm", edge_heads(edge_vals), b.shape[1],
-                   (cfg or DEFAULT_TUNE).nt), jax.named_scope("spmm"):
+    with call_spans("spmm", edge_heads(edge_vals), b.shape[1], cfg), \
+            jax.named_scope("spmm"):
         with jax.named_scope("revalue"):
             if transposed:
                 edge_vals = edge_vals[g.perm_dev]
@@ -214,8 +223,7 @@ def _spmm(g: GraphOps, edge_vals, b, *, transposed: bool = False):
 def _sddmm(g: GraphOps, x, y, heads: int | None = None):
     """``vals[p] = ⟨x[row_p], y[col_p]⟩`` (per head with ``heads``) under
     the ``sddmm`` scope."""
-    with head_span("sddmm", heads, x.shape[1],
-                   (g.cfg_sd or DEFAULT_TUNE).kf_tile), \
+    with call_spans("sddmm", heads, x.shape[1], g.cfg_sd), \
             jax.named_scope("sddmm"):
         return sddmm_apply(g.arrs_sd, _reorder_x(x, g._x_perm), y,
                            nnz=g.nnz, backend=g.backend, cfg=g.cfg_sd,

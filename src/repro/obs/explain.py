@@ -124,6 +124,15 @@ def head_counts(heads: int, head_dim: int, tile: int) -> dict:
             "lane_fill": 100.0 * width / padded}
 
 
+def tile_counts(width: int, tile: int) -> dict:
+    """The lane tiling of one sparse call of ``width`` dense columns at
+    lane tile ``tile``: ``lane_tiles`` tiles cover the width, and each
+    real non-zero costs that many row copies in the call. The sparse
+    calls of :class:`repro.models.gnn.GraphOps` carry these as the
+    attributes of their ``graphops.tile`` spans on an enabled tracer."""
+    return {"width": width, "tile": tile, "lane_tiles": -(-width // tile)}
+
+
 def _padding_report(plan, kind: str) -> dict:
     """Zero padding materialized by the condensed formats (bytes the
     kernels stream but the matrix never had)."""

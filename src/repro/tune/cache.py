@@ -45,7 +45,7 @@ from repro.obs.metrics import MetricsRegistry
 from repro.sparse.matrix import SparseCSR
 from repro.tune.model import TuneConfig
 
-CACHE_VERSION = 6  # v6: row-fetch kernels; no k/Y/X panel tiles
+CACHE_VERSION = 7  # v7: nt/kf_tile are caps; no grid_order (per call)
 _ENV_VAR = "REPRO_TUNE_CACHE_DIR"
 _ENV_MAX = "REPRO_TUNE_CACHE_MAX"
 DEFAULT_MAX_ENTRIES = 512
@@ -65,7 +65,7 @@ def default_cache_dir() -> str:
 
 def matrix_signature(a: SparseCSR) -> str:
     """Hash of the sparsity *pattern* (not the values): plan selection —
-    threshold split, tiling, grid order — depends only on the pattern."""
+    threshold split, tiling — depends only on the pattern."""
     h = hashlib.blake2b(digest_size=16)
     h.update(f"{a.m}:{a.k}:{a.nnz}:".encode())
     h.update(a.indptr.astype("int64").tobytes())

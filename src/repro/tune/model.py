@@ -11,17 +11,19 @@ features:
   prices every candidate threshold through the same roofline formulas as
   :mod:`repro.core.threshold` *without building a plan per candidate*;
 * a **VMEM footprint model** for each of the four kernels: the bytes a
-  single pipelined grid step keeps resident (Pallas double-buffers the
-  pipelined blocks, hence the ×2; the DMA'd rows scratch is single).
-  Lane tiles (``nt``, ``kf_tile``) and the §4.3 segment caps are chosen
-  as the largest hardware-aligned candidates whose footprint stays
-  inside ``VMEM_BUDGET_BYTES`` — the TPU analogue of CUDA occupancy
-  sizing. The kernels fetch operand rows by id, so no footprint grows
-  with ``k``, ``m`` or ``kcols``;
-* a **grid-order pick** (``n_outer`` vs ``block_outer``): with more than
-  one lane tile, ``block_outer`` fetches each condensed TC block once
-  instead of once per lane tile (every grid step owns its output block,
-  so both orders are always legal).
+  single pipelined grid step keeps resident, as Mosaic lays them out
+  (Pallas double-buffers the pipelined blocks, hence the ×2, in whole
+  (8, 128) tiles; the DMA'd rows scratch is single, one sublane per
+  row, and the SpMM kernels add a relayout of it). Lane-tile caps
+  (``nt``, ``kf_tile``) and the §4.3 segment caps are chosen as the
+  largest hardware-aligned candidates whose footprint stays inside
+  ``VMEM_BUDGET_BYTES`` — the TPU analogue of CUDA occupancy sizing.
+  The kernels fetch operand rows by id, so no footprint grows with
+  ``k``, ``m`` or ``kcols``;
+* a **per-call tile** (:func:`lane_tile`): each apply takes the tile
+  under the cap that covers its own width in the fewest tiles, so a
+  dense row is copied once per call wherever the cap and the budget
+  allow, whatever width the plan was tuned at.
 
 The result is a :class:`TuneConfig` — the single object every layer
 (preprocess, ops, kernels, benchmarks) parameterizes through.
@@ -31,8 +33,10 @@ count (:mod:`repro.kernels.gather` states the multi-head layout). Any
 lane tile fits that layout: the kernels map each lane to its head, so a
 head may straddle two tiles and no tile has to hold whole heads. What
 grows with ``H`` heads is a step's value or score block (``H`` values
-per slot, ``H`` score rows), a few KiB at the tuned caps, inside the
-budget's headroom; the footprint model prices one head.
+per slot, ``H`` score rows), a few KiB at the tuned caps, and
+``spmm_mxu``'s stacked dot operand (a copy of the fetched rows a head).
+The tuner prices one head; :func:`lane_tile` prices the call's heads,
+and narrows the tile where their copies do not fit.
 """
 from __future__ import annotations
 
@@ -60,14 +64,16 @@ class TuneConfig:
     """One plan-selection decision, consumed by every layer.
 
     ``threshold``/``bk``/``ts_tile`` parameterize preprocessing (the
-    2D-aware distribution); ``nt``/``grid_order`` the SpMM kernels;
-    ``kf_tile`` the SDDMM kernels. ``None`` means "the operator default"
-    so a bare ``TuneConfig()`` reproduces the untuned behavior. Frozen +
-    hashable so it can ride through ``jax.jit`` as a static argument.
+    2D-aware distribution); ``nt`` the SpMM kernels; ``kf_tile`` the
+    SDDMM kernels (``nt`` and ``kf_tile`` cap the tile :func:`lane_tile`
+    derives from each call's width). ``None`` means "the operator
+    default" so a bare ``TuneConfig()`` reproduces the untuned behavior.
+    Frozen + hashable so it can ride through ``jax.jit`` as a static
+    argument.
     """
 
-    nt: int = 128            # SpMM lane tile (output columns per step)
-    kf_tile: int = 128       # SDDMM feature tile
+    nt: int = 128            # widest SpMM lane tile a call may take
+    kf_tile: int = 128       # widest SDDMM feature tile a call may take
     threshold: int | None = None  # TC/VPU split (None = operator default)
     bk: int | None = None    # condensed block depth (None = operator default)
     ts_tile: int | None = None    # VPU tile width (None = operator default)
@@ -77,7 +83,6 @@ class TuneConfig:
     # 0 disables segmentation (the pre-§4.3 per-block/per-tile launch).
     ts: int | None = None
     cs: int | None = None
-    grid_order: str = "n_outer"   # SpMM grid order (see kernel docstrings)
     source: str = "default"  # default | model | search | cache
 
     def replace(self, **kw) -> "TuneConfig":
@@ -162,52 +167,95 @@ def _seg_widths(cfg: TuneConfig, *, bk: int, ts_tile: int) -> tuple[int, int]:
     return mxu_vecs, vpu_els
 
 
+# Mosaic's own temporaries in a step beyond the blocks and scratches
+# charged below: up to 88 KiB in compiles for a described v5e, at every
+# shape ``tests/test_tpu_compile.py`` checks.
+_MOSAIC_STEP_BYTES = 128 * 1024
+_LANES = 128
+
+
+def _tiled(rows: int, lanes: int, it: int) -> int:
+    """Bytes of a 2-D VMEM block in Mosaic's (8, 128) tiles."""
+    return -(-rows // WINDOW) * WINDOW * -(-lanes // _LANES) * _LANES * it
+
+
+# Each kernel DMAs its rows into a scratch that Mosaic lays out one
+# sublane per row: ``(rows, 1, tile)`` takes ``rows · tile`` words, not
+# an (8, 128) tile a row. The SpMM kernels then read the rows as one
+# ``(rows, tile)`` value (``spmm_mxu``: stacked once per head for the
+# dot) or as ``(8, tile)`` slices (``spmm_vpu``), and Mosaic keeps a
+# relayout of them in VMEM too: up to 1.6× the scratch alone in those
+# compiles, charged here as a second copy (a copy per head for the
+# stacked operand). The SDDMM kernels need no such copy.
+def spmm_mxu_step_bytes(vecs: int, nt: int, *, heads: int = 1,
+                        it: int = 4) -> int:
+    """One ``spmm_mxu`` grid step: the ``(8, H·vecs)`` value block and
+    the ``(8, nt)`` output block, double-buffered, the ``(vecs, 1, nt)``
+    fetched rows and the dot's ``(H·vecs, nt)`` operand."""
+    return (2 * (_tiled(WINDOW, heads * vecs, it) + _tiled(WINDOW, nt, it))
+            + (1 + heads) * vecs * nt * it + _MOSAIC_STEP_BYTES)
+
+
+def spmm_vpu_step_bytes(els: int, nt: int, *, heads: int = 1,
+                        it: int = 4) -> int:
+    """One ``spmm_vpu`` grid step: 8 segments' ``(8, H·els)`` values and
+    the ``(8, nt)`` output, double-buffered, and the ``(els, 8, 1, nt)``
+    fetched rows with their relayout."""
+    return (2 * (_tiled(WINDOW, heads * els, it) + _tiled(WINDOW, nt, it))
+            + 2 * els * WINDOW * nt * it + _MOSAIC_STEP_BYTES)
+
+
+def sddmm_mxu_step_bytes(vecs: int, kf: int, *, heads: int = 1,
+                         it: int = 4) -> int:
+    """One ``sddmm_mxu`` grid step: the ``(1, vecs)`` bitmap (a whole
+    tile) and the ``(8·H, vecs)`` scores, double-buffered, the
+    ``(8, kf)`` X window and the ``(vecs, 1, kf)`` fetched Y rows."""
+    return (2 * (_tiled(1, vecs, it) + _tiled(WINDOW * heads, vecs, it))
+            + _tiled(WINDOW, kf, it) + vecs * kf * it + _MOSAIC_STEP_BYTES)
+
+
+def sddmm_vpu_step_bytes(els: int, kf: int, *, heads: int = 1,
+                         it: int = 4) -> int:
+    """One ``sddmm_vpu`` grid step: the ``(H·8, els)`` scores,
+    double-buffered, and the fetched X and Y rows, ``(els, 8, 1, kf)``
+    each."""
+    return (2 * _tiled(WINDOW * heads, els, it)
+            + 2 * els * WINDOW * kf * it + _MOSAIC_STEP_BYTES)
+
+
 def vmem_spmm_bytes(cfg: TuneConfig, *, bk: int, ts: int,
-                    dtype=np.float32) -> int:
-    """Resident bytes of one pipelined grid step, max over the two
-    SpMM kernels (the streams are scheduled independently).
+                    dtype=np.float32, heads: int = 1) -> int:
+    """Resident bytes of one pipelined grid step at lane tile
+    ``cfg.nt`` and ``heads`` heads, max over the two SpMM kernels (the
+    streams are scheduled independently).
 
     Both kernels keep B in HBM and DMA the rows a step's ids name into a
-    VMEM scratch, so nothing here grows with ``k``. Pipelined
-    input/output blocks are double-buffered (×2); the fetched-rows
-    scratch is single. ``ts`` here is the VPU *tile
-    width* (``ts_tile``); the §4.3 segment caps (``cfg.ts``/``cfg.cs``)
-    set the per-step widths this model charges for.
+    VMEM scratch, so nothing here grows with ``k``. The ids sit in SMEM.
+    ``ts`` here is the VPU *tile width* (``ts_tile``); the §4.3 segment
+    caps (``cfg.ts``/``cfg.cs``) set the per-step widths this model
+    charges for.
     """
     it = _itemsize(dtype)
-    nt = cfg.nt
     mxu_vecs, vpu_els = _seg_widths(cfg, bk=bk, ts_tile=ts)
-    # MXU step: one segment's vals (8, ts·bk) + its SMEM ids, fetched
-    # rows (ts·bk, nt), output (8, nt).
-    mxu = 2 * (WINDOW * mxu_vecs * it + mxu_vecs * 4 + WINDOW * nt * it) \
-        + mxu_vecs * nt * it
-    # VPU step: 8 segments' vals (8, cs) + SMEM ids, fetched rows
-    # (cs, 8, nt), output (8, nt).
-    vpu = 2 * (2 * WINDOW * vpu_els * 4 + WINDOW * nt * it) \
-        + vpu_els * WINDOW * nt * it
-    return max(mxu, vpu)
+    return max(spmm_mxu_step_bytes(mxu_vecs, cfg.nt, heads=heads, it=it),
+               spmm_vpu_step_bytes(vpu_els, cfg.nt, heads=heads, it=it))
 
 
 def vmem_sddmm_bytes(cfg: TuneConfig, *, bk: int, ts: int,
-                     dtype=np.float32) -> int:
-    """Resident bytes of one pipelined SDDMM grid step (max over kernels).
+                     dtype=np.float32, heads: int = 1) -> int:
+    """Resident bytes of one pipelined SDDMM grid step at feature tile
+    ``cfg.kf_tile`` and ``heads`` heads (max over the two kernels).
 
     X and Y stay in HBM; a step DMAs the rows its ids name, one
     ``kf_tile`` feature slice at a time, so nothing here grows with the
     operand heights.
     """
     it = _itemsize(dtype)
-    kf = cfg.kf_tile
     mxu_vecs, vpu_els = _seg_widths(cfg, bk=bk, ts_tile=ts)
-    # MXU step: ids + bitmap (8-sublane padded) + output (8, ts·bk),
-    # X window (8, kf), fetched Y rows (ts·bk, kf).
-    mxu = 2 * (mxu_vecs * 4 + 2 * WINDOW * mxu_vecs * it) \
-        + WINDOW * kf * it + mxu_vecs * kf * it
-    # VPU step: 8 tiles' row/col ids + output (8, cs), fetched X and Y
-    # rows (cs, 8, kf) each.
-    vpu = 2 * (2 * WINDOW * vpu_els * 4 + WINDOW * vpu_els * it) \
-        + 2 * vpu_els * WINDOW * kf * it
-    return max(mxu, vpu)
+    return max(sddmm_mxu_step_bytes(mxu_vecs, cfg.kf_tile, heads=heads,
+                                    it=it),
+               sddmm_vpu_step_bytes(vpu_els, cfg.kf_tile, heads=heads,
+                                    it=it))
 
 
 def occupancy_report(step_bytes: int,
@@ -363,7 +411,7 @@ def model_tune_spmm(a: SparseCSR, *, n: int = 128, dtype=np.float32,
     """Emit a full SpMM :class:`TuneConfig` from matrix features.
 
     Explicit ``threshold`` (or a forcing ``mode``) is respected — the
-    model then only sizes tiles and picks the grid order. Explicit
+    model then only sizes the tile caps and segment caps. Explicit
     ``bk``/``ts_tile`` are likewise kept (and priced), so the emitted
     config always describes the plan that will actually be built.
     """
@@ -386,9 +434,10 @@ def model_tune_spmm(a: SparseCSR, *, n: int = 128, dtype=np.float32,
     seg_ts = _pick_seg_ts(feat, threshold, bk)
     seg_cs = _pick_seg_cs(feat, ts_tile)
 
-    # Lane tile: the widest whose pipelined step fits the budget (nt
-    # beyond n buys nothing).
-    nts = [c for c in _NT_CANDIDATES if c <= max(n, _NT_CANDIDATES[-1])]
+    # Lane tile cap: the widest whose pipelined step fits the budget.
+    # ``n`` prices the threshold only: each call takes the widest tile
+    # under this cap that its own width allows (:func:`lane_tile`).
+    nts = _NT_CANDIDATES
 
     def fits(nt):
         cfg = TuneConfig(nt=nt, ts=seg_ts, cs=seg_cs)
@@ -405,13 +454,8 @@ def model_tune_spmm(a: SparseCSR, *, n: int = 128, dtype=np.float32,
             seg_cs //= 2
         nt = _pick_tile(fits, nts)
 
-    # Grid order: with several lane tiles, block_outer fetches each TC
-    # block's values once instead of once per lane tile.
-    grid_order = "block_outer" if n > nt else "n_outer"
-
     cfg = TuneConfig(nt=nt, threshold=threshold, bk=bk,
-                     ts_tile=ts_tile, ts=seg_ts, cs=seg_cs,
-                     grid_order=grid_order, source="model")
+                     ts_tile=ts_tile, ts=seg_ts, cs=seg_cs, source="model")
     step = vmem_spmm_bytes(cfg, bk=bk, ts=ts_tile, dtype=dtype)
     if step > budget:  # smallest candidates still don't fit
         warnings.warn(
@@ -455,8 +499,9 @@ def model_tune_sddmm(a: SparseCSR, *, kf: int = 128, dtype=np.float32,
     seg_ts = _pick_seg_ts(feat, 1, bk)
     seg_cs = _pick_seg_cs(feat, ts_tile)
 
-    # The widest feature tile that fits.
-    kfs = [c for c in _KF_CANDIDATES if c <= max(kf, _KF_CANDIDATES[-1])]
+    # Feature tile cap: the widest that fits (``kf`` prices the
+    # threshold only; each call's tile follows its width).
+    kfs = _KF_CANDIDATES
 
     def fits(kf_c):
         cfg = TuneConfig(kf_tile=kf_c, ts=seg_ts, cs=seg_cs)
@@ -483,3 +528,36 @@ def model_tune_sddmm(a: SparseCSR, *, kf: int = 128, dtype=np.float32,
     _sp.set(threshold=threshold, kf_tile=kf_tile,
             vmem_step_bytes=step).close()
     return cfg
+
+
+# ------------------------------------------------------ per-call tile ---
+def lane_tile(op: str, width: int, cfg: TuneConfig, *,
+              heads: int | None = None) -> int:
+    """The lane tile of one ``op`` call of ``width`` dense columns.
+
+    Of the candidates (``_NT_CANDIDATES`` for ``op`` = ``"spmm"``,
+    ``_KF_CANDIDATES`` for ``"sddmm"``) at most the plan's cap
+    (``cfg.nt`` / ``cfg.kf_tile``) whose grid step at ``heads`` heads
+    fits ``VMEM_BUDGET_BYTES``, the one that covers the width in the
+    fewest tiles, and the narrowest of those, so no lanes are padded
+    that a wider tile would not need. Every row copy of the kernels
+    moves one tile of a row: a call makes ``ceil(width / tile)`` copies
+    per real non-zero. Plain Python on static shapes: the applies call
+    it at trace time.
+    """
+    from repro.core import preprocess as P
+
+    spmm = op == "spmm"
+    field, cap, cands, vmem, bk = (
+        ("nt", cfg.nt, _NT_CANDIDATES, vmem_spmm_bytes, P.DEFAULT_BK_SPMM)
+        if spmm else ("kf_tile", cfg.kf_tile, _KF_CANDIDATES,
+                      vmem_sddmm_bytes, P.DEFAULT_BK_SDDMM))
+    bk = cfg.bk or bk
+    ts_tile = cfg.ts_tile or 32
+
+    def fits(tile):
+        return vmem(cfg.replace(**{field: tile}), bk=bk, ts=ts_tile,
+                    heads=heads or 1) <= VMEM_BUDGET_BYTES
+
+    tiles = [c for c in cands if c <= cap and fits(c)] or [cands[-1]]
+    return min(tiles, key=lambda c: (-(-width // c), c))

@@ -2,12 +2,12 @@
 path and keep the argmin (the paper's Fig.-11 protocol, generalized from
 the threshold alone to the whole :class:`TuneConfig`).
 
-The grid is deliberately tiny — the *hardcoded default* config, the
-analytical model's pick, and a handful of tile/threshold perturbations
-around it — because every candidate pays a full preprocess + compile.
-The default config is always candidate #0 and ties resolve to the
-earliest candidate, so search can never lose to the defaults it
-replaces. Results are meant to be memoized through
+The grid is deliberately tiny — the *hardcoded default* plan, the
+analytical model's pick, and a handful of segment-cap/threshold
+perturbations around it — because every candidate pays a full
+preprocess + compile. The default plan is always candidate #0 and ties
+resolve to the earliest candidate, so search can never lose to the
+defaults it replaces. Results are meant to be memoized through
 :class:`repro.tune.cache.PlanCache` (see :func:`repro.tune.tune_spmm`).
 
 Timing is injectable (``timer(fn) -> seconds``) so tests drive the
@@ -66,14 +66,16 @@ def spmm_candidates(a: SparseCSR, *, n: int, mode: str,
 
     Candidate #0 is the floor search can't lose to: the hardcoded
     default *plan* (default threshold/bk/ts_tile — plan parameters are
-    read on every backend). On ``"xla"`` its kernel-tile fields ride on
-    the model's deterministic sizing, which times identically (the
-    reference path never reads nt/grid_order) while keeping the
-    cached tiles meaningful for later Pallas runs; on ``"pallas"`` it is
-    the verbatim default config. Lane-tile/grid-order perturbations
-    are only emitted for ``"pallas"``, where they change the
-    executable — on ``"xla"`` they'd compile identically and the argmin
-    over them would be pure timer noise.
+    read on every backend). On ``"xla"`` its segment caps ride on the
+    model's deterministic sizing, which times identically (the
+    reference path never reads them); on ``"pallas"`` they are the
+    default config's. Segment-cap perturbations are only emitted for
+    ``"pallas"``, where they change the executable — on ``"xla"``
+    they'd compile identically and the argmin over them would be pure
+    timer noise. Every candidate carries the model's lane-tile cap: the
+    cap is a VMEM bound, not a timed choice (each call's tile follows
+    its own width, :func:`repro.tune.model.lane_tile`), and at the
+    timed width ``n`` caps above it build the same program.
     """
     from repro.core import preprocess as P
 
@@ -85,12 +87,7 @@ def spmm_candidates(a: SparseCSR, *, n: int, mode: str,
     if backend == "xla":
         cands = [model.replace(**default_plan), model]
     else:
-        cands = [DEFAULT_TUNE.replace(**default_plan), model]
-        if model.nt // 2 >= 128:
-            cands.append(model.replace(nt=model.nt // 2))
-        cands.append(model.replace(grid_order="n_outer"
-                                   if model.grid_order == "block_outer"
-                                   else "block_outer"))
+        cands = [DEFAULT_TUNE.replace(nt=model.nt, **default_plan), model]
         cands.extend(_seg_cap_perturbations(model))
     if threshold is None and mode == "hybrid" and model.threshold is not None:
         for t in (model.threshold - 1, model.threshold + 1):
@@ -131,9 +128,8 @@ def sddmm_candidates(a: SparseCSR, *, kf: int, mode: str,
     if backend == "xla":
         cands = [model.replace(**default_plan), model]
     else:
-        cands = [DEFAULT_TUNE.replace(**default_plan), model]
-        if model.kf_tile // 2 >= 128:
-            cands.append(model.replace(kf_tile=model.kf_tile // 2))
+        cands = [DEFAULT_TUNE.replace(kf_tile=model.kf_tile,
+                                      **default_plan), model]
         cands.extend(_seg_cap_perturbations(model))
     if threshold is None and mode == "hybrid" and model.threshold is not None:
         for t in (max(model.threshold // 2, 1), model.threshold * 2):

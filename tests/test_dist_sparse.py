@@ -12,6 +12,7 @@ import textwrap
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core.formats import WINDOW
 from repro.core.windows import num_windows
@@ -92,84 +93,33 @@ def test_partition_global_gather_maps():
     assert part.meta["balance"]["max_over_mean"] >= 1.0
 
 
-def test_partition_search_times_run_cfgs_and_memoizes(tmp_path, rng):
-    """Per-shard tune='search': candidate run_cfgs are timed through the
-    (emulated) sharded apply, the winner is memoized under a
-    partition-level cache key, and a second construction re-times
-    nothing."""
-    from repro.core.spmm import LibraSpMM
-    from repro.dist import spmm_sharded
-
-    a = mixed_csr(120, 96, seed=9)
-    calls = {"n": 0}
-
-    def timer(fn):
-        calls["n"] += 1
-        fn()
-        return 1.0 / calls["n"]   # later candidates always "win"
-
-    # pallas grid has tile perturbations; a non-default candidate can win
-    part = partition_spmm(a, 4, tune="search", tune_cache=str(tmp_path),
-                          timer=timer, tune_backend="pallas")
-    assert calls["n"] >= 2
-    assert part.run_cfg.source == "search"
-    assert part.meta["run_cfg_source"] == "search"
-    base = partition_spmm(a, 4, tune="model")
-    # the perturbation won
-    assert part.run_cfg.replace(source="x") != base.run_cfg.replace(source="x")
-
-    # memoized: second construction takes the cache hit, zero timings
-    n0 = calls["n"]
-    part2 = partition_spmm(a, 4, tune="search", tune_cache=str(tmp_path),
-                           timer=timer, tune_backend="pallas")
-    assert calls["n"] == n0
-    assert part2.run_cfg.source == "cache"
-    assert part2.run_cfg.replace(source="x") == \
-        part.run_cfg.replace(source="x")
-    # a different shard count is a different partition-level key
-    partition_spmm(a, 2, tune="search", tune_cache=str(tmp_path),
-                   timer=timer, tune_backend="pallas")
-    assert calls["n"] > n0
-
-    # the searched partition still computes the right answer
-    mesh = jax.make_mesh((1,), ("shards",))
-    p1 = partition_spmm(a, 1, tune="search", tune_cache=str(tmp_path),
-                        timer=timer)
-    b = jnp.asarray(rng.standard_normal((a.k, 24)).astype(np.float32))
-    got = np.asarray(spmm_sharded(p1, b, mesh=mesh))
-    want = np.asarray(LibraSpMM(a, tune="model")(b))
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-
-
-def test_partition_search_sddmm_and_mesh_path(tmp_path, rng):
-    """SDDMM flavour + timing through the real shard_map apply when a
-    mesh is provided."""
-    from repro.dist import sddmm_sharded
+@pytest.mark.parametrize("op", ["spmm", "sddmm"])
+def test_partition_search_keeps_the_model_caps(rng, op):
+    """A partition's run_cfg holds only lane-tile caps, a VMEM bound, so
+    ``tune="search"`` builds the model-tuned partition: the same
+    run_cfg, the model's caps, and the right answer on a mesh."""
+    from repro.dist import sddmm_sharded, spmm_sharded
     from repro.kernels import ref
 
     a = mixed_csr(96, 80, seed=10)
-    calls = {"n": 0}
-
-    def timer(fn):
-        calls["n"] += 1
-        fn()
-        return float(calls["n"])
-
-    part = partition_sddmm(a, 3, tune="search", tune_cache=str(tmp_path),
-                           timer=timer)
-    assert part.run_cfg.source == "search" and calls["n"] >= 1
+    build = partition_spmm if op == "spmm" else partition_sddmm
+    part = build(a, 1, tune="search", tune_backend="pallas")
+    assert part.run_cfg == build(a, 1, tune="model").run_cfg
+    assert (part.run_cfg.nt, part.run_cfg.kf_tile) == (
+        part.shards[0].cfg.nt, part.shards[0].cfg.kf_tile)
 
     mesh = jax.make_mesh((1,), ("shards",))
-    n0 = calls["n"]
-    p1 = partition_sddmm(a, 1, tune="search", tune_cache=str(tmp_path),
-                         timer=timer, mesh=mesh)
-    assert calls["n"] > n0
-    x = jnp.asarray(rng.standard_normal((a.m, 16)).astype(np.float32))
-    y = jnp.asarray(rng.standard_normal((a.k, 16)).astype(np.float32))
-    got = np.asarray(sddmm_sharded(p1, x, y, mesh=mesh))
-    oracle = np.asarray(ref.sddmm_dense_oracle(
-        a.to_dense(), np.asarray(x), np.asarray(y)))
-    np.testing.assert_allclose(got, oracle, rtol=1e-4, atol=1e-4)
+    x = rng.standard_normal((a.m, 24)).astype(np.float32)
+    y = rng.standard_normal((a.k, 24)).astype(np.float32)
+    if op == "spmm":
+        got = spmm_sharded(part, jnp.asarray(y), mesh=mesh, backend="pallas")
+        want = a.to_dense() @ y
+    else:
+        got = sddmm_sharded(part, jnp.asarray(x), jnp.asarray(y), mesh=mesh,
+                            backend="pallas")
+        want = ref.sddmm_dense_oracle(a.to_dense(), x, y)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)
 
 
 def test_single_shard_partition_is_transparent(rng):

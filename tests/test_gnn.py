@@ -258,3 +258,54 @@ def test_unimp_head_counters_on_an_enabled_tracer(looped):
     with use_tracer(quiet):
         make_unimp_train_step(g).lower(params, x, labels)
     assert not quiet.roots
+
+
+def _spans_named(roots, name):
+    out = []
+    for sp in roots:
+        if sp.name == name:
+            out.append(sp.attrs)
+        out += _spans_named(sp.children, name)
+    return out
+
+
+@pytest.mark.parametrize("model", ["gcn", "unimp"])
+def test_every_sparse_call_fetches_each_row_once_up_to_256(looped, model):
+    """Each SpMM and SDDMM call site of a traced step opens a
+    ``graphops.tile`` span with its width, lane tile and lane tiles; at
+    widths up to 256 under the tuned cap every call takes one tile, so
+    each dense row is copied once per call. A multi-head call's
+    ``graphops.heads`` span reads the same tile."""
+    from repro.dist.gnn import make_gcn_train_step, make_unimp_train_step
+    from repro.obs.trace import Tracer, use_tracer
+
+    from repro.api import ExecSpec
+
+    g = gnn.GraphOps(looped, spec=ExecSpec(tune="model"))
+    x = jnp.ones((looped.m, 128), jnp.float32)
+    labels = jnp.zeros((looped.m,), jnp.int32)
+    tr = Tracer()
+    with use_tracer(tr):
+        if model == "gcn":
+            params = gnn.init_gcn(jax.random.PRNGKey(0), [128, 256, 256, 40])
+            make_gcn_train_step(g).lower(
+                params, x, labels, jnp.ones((looped.nnz,), jnp.float32))
+        else:
+            params = gnn.init_unimp(jax.random.PRNGKey(0), [128, 256, 40], 4)
+            make_unimp_train_step(g).lower(params, x, labels)
+    tiles = _spans_named(tr.roots, "graphops.tile")
+    # GCN, a layer: the forward SpMM and, in backward, the SpMM into
+    # its input and the SDDMM into the edge values (traced, though the
+    # step drops it); UniMP: the 12 call sites of
+    # test_unimp_head_counters_on_an_enabled_tracer.
+    assert len(tiles) == (9 if model == "gcn" else 12)
+    widths = {(t["op"], t["width"], t["tile"], t["lane_tiles"])
+              for t in tiles}
+    if model == "gcn":
+        assert widths == {(op, w, t, 1) for op in ("spmm", "sddmm")
+                          for w, t in ((256, 256), (40, 128))}
+    else:
+        assert widths == {(op, w, 256, 1) for op in ("spmm", "sddmm")
+                          for w in (256, 160)}
+        heads = _spans_named(tr.roots, "graphops.heads")
+        assert {h["lane_fill"] for h in heads} == {100.0, 62.5}

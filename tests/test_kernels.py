@@ -165,6 +165,137 @@ def test_sddmm_vpu_matches_ref(rng, ntiles, ts, kf):
     np.testing.assert_allclose(np.asarray(out), expect, rtol=1e-4, atol=1e-4)
 
 
+# A 256-lane tile holds a whole width-256 call: one head, 4 heads of 64,
+# or 4 heads of 40 (160 features padded to 256, head 3 straddling the
+# two 128-lane tiles of the narrower tile).
+WIDE_LAYOUTS = {"one": (None, 256), "h4c64": (4, 64), "h4c40": (4, 40)}
+
+
+def _wide_layout(rng, layout, k, lead, integer=False):
+    """Values of shape ``lead`` (plus a head axis for multi-head) and a
+    ``(k, 256)`` B whose columns past ``H·c`` are zero, as ops.py pads
+    them; small integers where float sums must be exact in any order."""
+    heads, c = WIDE_LAYOUTS[layout]
+    draw = ((lambda *sh: rng.integers(-3, 4, sh).astype(np.float32))
+            if integer else (lambda *sh: _rand(rng, *sh)))
+    vals = draw(*lead, *((heads,) if heads else ()))
+    b = np.zeros((k, 256), np.float32)
+    b[:, :(heads or 1) * c] = draw(k, (heads or 1) * c)
+    return vals, b, heads, (c if heads else None)
+
+
+def _per_head(b, heads, c):
+    """``(k, n)`` → per-lane head index, ``H − 1`` past ``H·c``."""
+    return np.minimum(np.arange(b.shape[1]) // c, heads - 1)
+
+
+WIDE_SPMM_CASES = (
+    [("vpu", lay, order, bounded) for lay in WIDE_LAYOUTS
+     for order in ("n_outer", "block_outer") for bounded in (False, True)]
+    + [("mxu", lay, order, False) for lay in WIDE_LAYOUTS
+       for order in ("n_outer", "block_outer")])
+
+
+@pytest.mark.parametrize("kernel,layout,grid_order,bounded", WIDE_SPMM_CASES)
+def test_spmm_kernels_at_a_256_lane_tile_match_two_128_tiles(
+        rng, kernel, layout, grid_order, bounded):
+    """One 256-lane tile gives the output of two 128-lane tiles bit for
+    bit (each lane's sum is the same sum), for one head and four, with
+    and without segment lengths, in both grid orders; and it matches
+    the dense products."""
+    k = 48
+    if kernel == "vpu":
+        lens = np.asarray(BOUNDED_LENS["pad_rows"], np.int32)
+        ts = 32
+        vals, b, heads, c = _wide_layout(rng, layout, k, (len(lens), ts))
+        real = np.arange(ts)[None, :] < lens[:, None]
+        vals = np.where(real[..., None] if heads else real, vals, 0.0)
+        cols = np.where(real, rng.integers(0, k, real.shape), 0)
+        args = [jnp.asarray(vals.astype(np.float32)),
+                jnp.asarray(cols.astype(np.int32)), jnp.asarray(b)]
+        if bounded:
+            args.append(jnp.asarray(lens))
+        run = lambda nt: np.asarray(spmm_vpu(  # noqa: E731
+            *args, nt=nt, grid_order=grid_order, head_dim=c,
+            interpret=True))
+        per_slot = vals if heads else vals[..., None]
+        gathered = b[cols]                                   # (t, ts, n)
+        if heads:
+            expect = np.einsum("tjn,tjn->tn", np.take(
+                per_slot, _per_head(b, heads, c), axis=2), gathered)
+        else:
+            expect = np.einsum("tj,tjn->tn", vals, gathered)
+    else:
+        nb, bk = 5, 16
+        vals, b, heads, c = _wide_layout(rng, layout, k, (nb, WINDOW, bk),
+                                         integer=True)
+        cols = rng.integers(0, k, (nb, bk)).astype(np.int32)
+        args = [jnp.asarray(vals), jnp.asarray(cols), jnp.asarray(b)]
+        run = lambda nt: np.asarray(spmm_mxu(  # noqa: E731
+            *args, nt=nt, grid_order=grid_order, head_dim=c,
+            interpret=True))
+        if heads:
+            lane_vals = np.take(vals, _per_head(b, heads, c), axis=3)
+            expect = np.einsum("brjn,bjn->brn", lane_vals,
+                               b[cols]).reshape(nb * WINDOW, -1)
+        else:
+            expect = np.einsum("brj,bjn->brn", vals,
+                               b[cols]).reshape(nb * WINDOW, -1)
+    wide, narrow = run(256), run(128)
+    assert np.array_equal(wide, narrow)
+    np.testing.assert_allclose(wide, expect, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("layout", sorted(WIDE_LAYOUTS))
+@pytest.mark.parametrize("kernel", ["mxu", "vpu"])
+def test_sddmm_kernels_at_a_256_feature_tile_match_two_128_tiles(
+        rng, kernel, layout):
+    """One 256-feature tile scores as two 128-feature tiles summed, to
+    float32 rounding (one dot of 256 features in place of two of 128),
+    for one head and four; and it matches the dense products."""
+    heads, c = WIDE_LAYOUTS[layout]
+    m, ncols = 24, 40
+    width = (heads or 1) * c
+    x = np.zeros((m, 256), np.float32)
+    y = np.zeros((ncols, 256), np.float32)
+    x[:, :width] = _rand(rng, m, width)
+    y[:, :width] = _rand(rng, ncols, width)
+    hd = _per_head(x, heads, c) if heads else np.zeros(256, int)
+    hd = np.where(np.arange(256) < width, hd, -1)
+    per_head = np.stack([np.where(hd == h, 1.0, 0.0)
+                         for h in range(heads or 1)])      # (H, 256)
+    if kernel == "mxu":
+        nb, bk = 4, 16
+        window = np.sort(rng.integers(0, m // WINDOW, nb)).astype(np.int32)
+        cols = rng.integers(0, ncols, (nb, bk)).astype(np.int32)
+        bitmap = rng.integers(0, 256, (nb, bk)).astype(np.uint32)
+        run = lambda kt: np.asarray(sddmm_mxu(  # noqa: E731
+            jnp.asarray(cols), jnp.asarray(bitmap), jnp.asarray(window),
+            jnp.asarray(x), jnp.asarray(y), kf_tile=kt, heads=heads,
+            head_dim=c if heads else None, interpret=True))
+        xw = x.reshape(-1, WINDOW, 256)[window]                # (nb, 8, f)
+        s = np.einsum("brf,hf,bjf->bhrj", xw, per_head, y[cols])
+        bits = (bitmap[:, None, None, :].astype(np.int64)
+                >> np.arange(WINDOW)[None, None, :, None]) & 1
+        expect = np.where(bits > 0, s, 0.0)
+        if not heads:
+            expect = expect[:, 0]
+    else:
+        ntiles, ts = 3, 16
+        rows = rng.integers(0, m, (ntiles, ts)).astype(np.int32)
+        cols = rng.integers(0, ncols, (ntiles, ts)).astype(np.int32)
+        run = lambda kt: np.asarray(sddmm_vpu(  # noqa: E731
+            jnp.asarray(rows), jnp.asarray(cols), jnp.asarray(x),
+            jnp.asarray(y), kf_tile=kt, heads=heads,
+            head_dim=c if heads else None, interpret=True))
+        expect = np.einsum("tjf,hf,tjf->htj", x[rows], per_head, y[cols])
+        if not heads:
+            expect = expect[0]
+    wide, narrow = run(256), run(128)
+    np.testing.assert_allclose(wide, narrow, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(wide, expect, rtol=1e-4, atol=1e-4)
+
+
 MATS = [
     random_uniform_csr(80, 64, 0.03, seed=11),
     banded_csr(64, 64, 8, 0.85, seed=12),

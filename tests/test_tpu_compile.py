@@ -195,3 +195,98 @@ def test_multihead_applies_compile_for_v5e(one_chip, graphs, heads,
                           if k.startswith(f"{kind}_{stream}_mh")]
             assert f"/{stream}/" in op_name, op_name
         assert len(kernels) == 2, kernels
+
+
+def test_sddmm_apply_compiles_at_a_256_feature_tile(one_chip, graphs):
+    """A width-256 SDDMM of a plan tuned at the default ``tune_kf``
+    takes one 256-feature tile (the cap is the budget's, not
+    ``tune_kf``'s) and compiles, kernels named and scoped."""
+    from repro.tune.model import lane_tile
+
+    a = graphs["powerlaw"]
+    op = LibraSDDMM(a, spec=ExecSpec(backend="pallas", tune="model"))
+    assert lane_tile("sddmm", N, op.tune_config) == N
+    arrs = op.arrays.for_backend("pallas", segmented=True)
+    x = jax.ShapeDtypeStruct((a.m, N), jnp.float32, sharding=one_chip)
+    y = jax.ShapeDtypeStruct((a.k, N), jnp.float32, sharding=one_chip)
+    exe = sddmm_apply.lower(_shapes(arrs, one_chip), x, y, nnz=op.nnz,
+                            backend="pallas", cfg=op.tune_config,
+                            interpret=False).compile()
+    _check(exe)
+    assert {k.split(".")[0] for k in _kernel_scopes(exe.as_text())} == \
+        {"sddmm_mxu", "sddmm_vpu"}
+
+
+# (kernel, rows a step fetches per operand, lane tile, heads): the arxiv
+# plans' steps at 512 lanes, the widest segment caps the tuner emits
+# (Ts 32 × bk 32 = 1,024 MXU vectors; Cs 8 × 32 = 256 VPU elements) and
+# UniMP's four heads at 256.
+VMEM_CASES = [
+    ("spmm_mxu", 32, 512, 1), ("spmm_mxu", 1024, 512, 1),
+    ("spmm_mxu", 128, 256, 4),
+    ("spmm_vpu", 32, 512, 1), ("spmm_vpu", 256, 512, 1),
+    ("spmm_vpu", 32, 256, 4),
+    ("sddmm_mxu", 128, 512, 1), ("sddmm_mxu", 512, 512, 1),
+    ("sddmm_mxu", 128, 256, 4),
+    ("sddmm_vpu", 32, 512, 1), ("sddmm_vpu", 256, 256, 1),
+    ("sddmm_vpu", 32, 256, 4),
+]
+
+
+@pytest.mark.parametrize("kernel,rows,tile,heads", VMEM_CASES,
+                         ids=["-".join(map(str, c)) for c in VMEM_CASES])
+def test_vmem_model_covers_what_mosaic_allocates(one_chip, monkeypatch,
+                                                 kernel, rows, tile, heads):
+    """Each kernel compiles for a v5e with its scoped VMEM limited to
+    the tuner's modeled step (:mod:`repro.tune.model`), so a tile the
+    model admits under the budget fits the chip. Mosaic lays the
+    fetched-rows scratch out one sublane per row; the SpMM kernels'
+    relayout of the rows takes up to 1.6× that scratch again, and
+    ``spmm_mxu``'s heads stack a copy each."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.kernels import sddmm_mxu, sddmm_vpu, spmm_mxu, spmm_vpu
+    from repro.tune import model
+
+    step = getattr(model, f"{kernel}_step_bytes")(rows, tile, heads=heads)
+    pallas_call = pl.pallas_call
+
+    def limited(*args, **kw):
+        kw["compiler_params"] = pltpu.CompilerParams(vmem_limit_bytes=step)
+        return pallas_call(*args, **kw)
+
+    monkeypatch.setattr(pl, "pallas_call", limited)
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    nb, k, i32 = 64, 20_000, jnp.int32
+    multi = heads > 1
+    hd = tile // heads if multi else None
+    hs = (heads,) if multi else ()
+    if kernel == "spmm_mxu":
+        fn = lambda v, c, b: spmm_mxu.spmm_mxu.__wrapped__(  # noqa: E731
+            v, c, b, nt=tile, head_dim=hd, interpret=False)
+        args = (s((nb, 8, rows) + hs), s((nb, rows), i32), s((k, tile)))
+    elif kernel == "spmm_vpu":
+        fn = lambda v, c, b, n: spmm_vpu.spmm_vpu.__wrapped__(  # noqa: E731
+            v, c, b, n, nt=tile, head_dim=hd, interpret=False)
+        args = (s((nb, rows) + hs), s((nb, rows), i32), s((k, tile)),
+                s((nb,), i32))
+    elif kernel == "sddmm_mxu":
+        fn = lambda c, bm, w, x, y: sddmm_mxu.sddmm_mxu.__wrapped__(  # noqa: E731
+            c, bm, w, x, y, kf_tile=tile, heads=heads if multi else None,
+            head_dim=hd, interpret=False)
+        args = (s((nb, rows), i32), s((nb, rows), jnp.uint32),
+                s((nb,), i32), s((k, tile)), s((k, tile)))
+    else:
+        fn = lambda r, c, x, y: sddmm_vpu.sddmm_vpu.__wrapped__(  # noqa: E731
+            r, c, x, y, kf_tile=tile, heads=heads if multi else None,
+            head_dim=hd, interpret=False)
+        args = (s((nb, rows), i32), s((nb, rows), i32), s((k, tile)),
+                s((k, tile)))
+    # A jit of the kernels' own functions (``__wrapped__``), so that no
+    # earlier trace of the same shapes, made without the limit, is
+    # reused.
+    assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()

@@ -12,7 +12,9 @@ import numpy as np
 import pytest
 
 from benchmarks.common import corpus
+from repro.api import ExecSpec
 from repro.core import preprocess
+from repro.core.formats import WINDOW
 from repro.core.sddmm import LibraSDDMM
 from repro.core.spmm import LibraSpMM
 from repro.sparse.generate import banded_csr, mixed_csr, power_law_csr
@@ -34,6 +36,8 @@ from repro.tune import (
     vmem_sddmm_bytes,
     vmem_spmm_bytes,
 )
+from repro.tune.model import (lane_tile, spmm_mxu_step_bytes,
+                              spmm_vpu_step_bytes)
 
 
 def _sparse(m, k, nnz, seed=0):
@@ -190,12 +194,13 @@ def test_search_never_loses_to_default_on_ties():
     cfg, timings = search_spmm(a, n=32, timer=_seq_timer([5.0] * ncand))
     assert cfg.threshold == preprocess.DEFAULT_SPMM_THRESHOLD
     assert timings[0] == min(timings.values())
-    # On the pallas backend candidate #0 is the verbatim default config,
-    # and tile/grid-order candidates join the grid.
+    # On the pallas backend candidate #0 is the default config under the
+    # model's lane-tile cap, and segment-cap candidates join the grid.
     pallas_cands = spmm_candidates(a, n=32, mode="hybrid", threshold=None,
                                    backend="pallas")
     assert pallas_cands[0] == DEFAULT_TUNE.replace(
-        threshold=preprocess.DEFAULT_SPMM_THRESHOLD)
+        threshold=preprocess.DEFAULT_SPMM_THRESHOLD,
+        nt=model_tune_spmm(a, n=32).nt)
     assert len(pallas_cands) > len(
         spmm_candidates(a, n=32, mode="hybrid", threshold=None))
 
@@ -320,8 +325,8 @@ def test_tuned_configs_bit_identical_outputs_spmm(rng):
     b = jnp.asarray(rng.integers(-2, 3, (a.k, 160)).astype(np.float32))
     ref_out = None
     configs = ["off", "model",
-               TuneConfig(nt=128, threshold=2),
-               TuneConfig(nt=128, grid_order="block_outer")]
+               TuneConfig(nt=128, threshold=2),   # two lane tiles
+               TuneConfig(nt=256)]                # one lane tile
     for tune in configs:
         op = LibraSpMM(a, tune=tune)
         for backend in ("xla", "pallas"):
@@ -354,8 +359,8 @@ def test_block_outer_downgrade_on_shared_ranks(rng):
     its output block, so block_outer stays legal (no downgrade) and the
     combine sums the blocks that share a window."""
     a = banded_csr(64, 256, 48, 1.0, seed=10)  # 48 vecs/window > bk=32
-    op = LibraSpMM(a, tune=TuneConfig(grid_order="block_outer", ts=0,
-                                      cs=0))
+    # Two 128-lane tiles of the 256-wide B: the apply runs block_outer.
+    op = LibraSpMM(a, tune=TuneConfig(nt=128, ts=0, cs=0))
     assert op.plan.tc.nblk > op.plan.tc.n_active
     b = rng.standard_normal((a.k, 256)).astype(np.float32)
     out = np.asarray(op(jnp.asarray(b), backend="pallas"))
@@ -384,3 +389,111 @@ def test_tune_off_reproduces_legacy_defaults():
     assert op.tune_config.nt == 128
     with pytest.raises(ValueError):
         LibraSpMM(a, tune="bogus")
+
+
+# ------------------------------------------------------ per-call tile ---
+_TILE_FIELD = {"spmm": "nt", "sddmm": "kf_tile"}
+
+
+@pytest.mark.parametrize("width,tile,tile_256", [
+    (40, 128, 128), (128, 128, 128), (160, 256, 256), (256, 256, 256),
+    (384, 512, 256), (640, 512, 256)])
+@pytest.mark.parametrize("op", ["spmm", "sddmm"])
+def test_call_tile_follows_the_width(op, width, tile, tile_256):
+    """A call takes the tile under the plan's cap that covers its width
+    in the fewest tiles, the narrowest of those: 384 lanes take one
+    512-lane tile, or two of 256 under a cap of 256, not three of 128;
+    under a cap of 128 every width keeps 128."""
+    field = _TILE_FIELD[op]
+    assert lane_tile(op, width, TuneConfig(**{field: 512})) == tile
+    assert lane_tile(op, width, TuneConfig(**{field: 256})) == tile_256
+    assert lane_tile(op, width, TuneConfig(**{field: 128})) == 128
+
+
+@pytest.mark.parametrize("op", ["spmm", "sddmm"])
+def test_call_tile_keeps_a_narrower_tile_over_the_budget(op):
+    """A plan whose step at the wider tile exceeds the VMEM budget keeps
+    the narrower tile: segments of 512 VPU elements are charged 16 MiB
+    of rows a step at 512 lanes (SpMM: the rows and their relayout;
+    SDDMM: two operands' rows), 8 MiB at 256."""
+    field = _TILE_FIELD[op]
+    vmem = vmem_spmm_bytes if op == "spmm" else vmem_sddmm_bytes
+    cfg = TuneConfig(**{field: 512}, ts_tile=32, cs=512)
+    bk = preprocess.DEFAULT_BK_SPMM if op == "spmm" \
+        else preprocess.DEFAULT_BK_SDDMM
+    assert vmem(cfg, bk=bk, ts=32) > VMEM_BUDGET_BYTES
+    tile = lane_tile(op, 512, cfg)
+    assert tile == 256
+    assert vmem(cfg.replace(**{field: tile}), bk=bk, ts=32) \
+        <= VMEM_BUDGET_BYTES
+
+
+def test_call_tile_prices_the_heads():
+    """``spmm_mxu`` stacks a copy of the fetched rows a head: 8 heads of
+    64 over segments of 32 blocks of 32 vectors (1,024 rows) need
+    (1 + 8) · 1,024 · 512 · 4 B at 512 lanes, over the budget, so the
+    call keeps 256 lanes; one head fits 512."""
+    cfg = TuneConfig(nt=512, bk=32, ts=32, cs=32, ts_tile=32)
+    assert vmem_spmm_bytes(cfg, bk=32, ts=32, heads=8) > VMEM_BUDGET_BYTES
+    assert lane_tile("spmm", 512, cfg, heads=8) == 256
+    assert lane_tile("spmm", 512, cfg) == 512
+
+
+def test_model_caps_tiles_by_the_budget_not_the_tuned_width():
+    """``n`` / ``kf`` price the threshold only: the caps are the widest
+    tiles whose step fits the budget, and a budget under the widest
+    step narrows them."""
+    a = power_law_csr(256, 256, 6.0, seed=5)
+    spmm = model_tune_spmm(a, n=128)
+    sddmm = model_tune_sddmm(a, kf=128)
+    assert (spmm.nt, sddmm.kf_tile) == (512, 512)
+    over = vmem_spmm_bytes(spmm, bk=spmm.bk, ts=spmm.ts_tile) - 1
+    assert model_tune_spmm(a, n=128, budget=over).nt == 256
+    over = vmem_sddmm_bytes(sddmm, bk=sddmm.bk, ts=sddmm.ts_tile) - 1
+    assert model_tune_sddmm(a, kf=128, budget=over).kf_tile == 256
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_search_candidates_keep_the_model_caps(backend):
+    """The lane-tile cap is a VMEM bound, not a timed choice: every
+    search candidate, the default plan at #0 included, carries the
+    model's cap, so a search result keeps it."""
+    from repro.tune.search import sddmm_candidates
+
+    a = power_law_csr(96, 96, 8.0, seed=4)
+    nt = model_tune_spmm(a, n=128).nt
+    kf = model_tune_sddmm(a, kf=128).kf_tile
+    assert {c.nt for c in spmm_candidates(
+        a, n=128, mode="hybrid", threshold=None, backend=backend)} == {nt}
+    assert {c.kf_tile for c in sddmm_candidates(
+        a, kf=128, mode="hybrid", threshold=None, backend=backend)} == {kf}
+    ncand = len(spmm_candidates(a, n=128, mode="hybrid", threshold=None,
+                                backend=backend))
+    cfg, _ = search_spmm(a, n=128, backend=backend,
+                         timer=_seq_timer([5.0] * ncand))
+    assert cfg.nt == nt
+
+
+def test_tune_off_keeps_the_128_lane_tile():
+    """``tune="off"`` keeps ``DEFAULT_TUNE``'s caps of 128: every call
+    runs the 128-lane kernels, whatever its width."""
+    a = mixed_csr(64, 64, seed=12)
+    for op, cls in (("spmm", LibraSpMM), ("sddmm", LibraSDDMM)):
+        cfg = cls(a, spec=ExecSpec(tune="off")).tune_config
+        for width in (40, 256, 512):
+            assert lane_tile(op, width, cfg) == 128
+
+
+def test_vmem_model_charges_mosaic_tiles():
+    """Pipelined blocks are charged in whole (8, 128) tiles, so an
+    8-wide value block costs a 128-wide one; fetched rows one sublane
+    per row, as Mosaic lays the scratch out, plus the SpMM kernels'
+    relayout of them (one copy a head for ``spmm_mxu``'s stacked dot
+    operand). tests/test_tpu_compile.py holds the model to compiles."""
+    rows = lambda els, nt: 2 * els * WINDOW * nt * 4  # noqa: E731
+    assert spmm_vpu_step_bytes(8, 256) - rows(8, 256) == \
+        spmm_vpu_step_bytes(128, 256) - rows(128, 256)
+    assert spmm_mxu_step_bytes(1024, 512) - spmm_mxu_step_bytes(512, 512) \
+        == 2 * 512 * 512 * 4 + 2 * WINDOW * 512 * 4
+    assert spmm_mxu_step_bytes(32, 256, heads=4) \
+        - spmm_mxu_step_bytes(32, 256) == 3 * 32 * 256 * 4
