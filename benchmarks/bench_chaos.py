@@ -63,7 +63,7 @@ def run() -> list[tuple]:
 
     # --- seeded storm over every ladder site of every graph
     sites = [(name, "spmm", s) for name in mats
-             for s in ("fast", "single", "unsegmented", "xla")]
+             for s in ("fast", "single", "xla")]
     plan = FaultPlan.storm(FAULT_SEED, sites, n_faults=12, max_k=4,
                            kinds=("raise", "resource"), times=(1, 2))
     eng = SparseEngine(registry, max_queue=512, faults=plan,
@@ -87,7 +87,6 @@ def run() -> list[tuple]:
     served = h["degraded_served"]
     rows.append(("chaos/degradation", 0.0,
                  f"single{served.get('single', 0)}_"
-                 f"unseg{served.get('unsegmented', 0)}_"
                  f"xla{served.get('xla', 0)}_retries{h['retries']}"))
 
     # --- one full breaker cycle under a latched-then-healed fault

@@ -94,8 +94,8 @@ def _segmented_rows() -> list[tuple]:
     row-skew generator) packs many condensed TC blocks into its heavy
     windows; the Ts decomposition merges each window's blocks into
     bounded segments, so the Pallas TC stream runs ~4× fewer grid steps
-    with no padding. ``tcu`` mode isolates that stream (the paper's
-    single-resource ablation)."""
+    than one block per segment (``ts=1``) with no padding. ``tcu`` mode
+    isolates that stream (the paper's single-resource ablation)."""
     from repro.models.gnn import transpose_csr
     from repro.sparse.generate import power_law_csr
 
@@ -105,23 +105,23 @@ def _segmented_rows() -> list[tuple]:
     b = jnp.asarray(rng.standard_normal((a_t.k, N)).astype(np.float32))
     op = LibraSpMM(a_t, mode="tcu", tune="model")
     cfg = op.tune_config
-    op0 = LibraSpMM(a_t, mode="tcu", tune=cfg.replace(ts=0, cs=0))
-    t_seg, t_un = _interleaved(lambda: op(b, backend="pallas"),
-                               lambda: op0(b, backend="pallas"))
+    op1 = LibraSpMM(a_t, mode="tcu", tune=cfg.replace(ts=1, cs=cfg.ts_tile))
+    t_seg, t_one = _interleaved(lambda: op(b, backend="pallas"),
+                                lambda: op1(b, backend="pallas"))
     nseg = op.plan.meta["tc_segments"].nseg
-    nblk = op0.plan.tc.nblk
+    nblk = op1.plan.tc.nblk
     return [
         ("spmm/powerlaw_tr/tcu_segmented", t_seg * 1e6,
-         f"ts{cfg.ts}_steps{nseg}of{nblk}_x{t_un / t_seg:.2f}"),
-        ("spmm/powerlaw_tr/tcu_unsegmented", t_un * 1e6,
+         f"ts{cfg.ts}_steps{nseg}of{nblk}_x{t_one / t_seg:.2f}"),
+        ("spmm/powerlaw_tr/tcu_one_block_segments", t_one * 1e6,
          f"steps{nblk}"),
     ]
 
 
 def _bit_identity_row(mats: dict) -> tuple:
-    """Whole-corpus bit-identity of the segmented Pallas kernels vs the
-    unsegmented fused apply and the XLA reference. Checked on
-    integer-valued copies: float addition is exact there, so the segment
+    """Whole-corpus bit-identity of the tuned segment launch vs one block
+    (tile) per segment and the XLA reference. Checked on integer-valued
+    copies: float addition is exact there, so the segment
     re-association must be bitwise inert."""
     from repro.sparse.matrix import coo_to_csr
 
@@ -132,9 +132,10 @@ def _bit_identity_row(mats: dict) -> tuple:
                         rng.integers(1, 4, a.nnz).astype(np.float32))
         b = jnp.asarray(rng.integers(-2, 3, (a.k, 32)).astype(np.float32))
         op = LibraSpMM(ai, tune="model")
-        op0 = LibraSpMM(ai, tune=op.tune_config.replace(ts=0, cs=0))
+        cfg = op.tune_config
+        op1 = LibraSpMM(ai, tune=cfg.replace(ts=1, cs=cfg.ts_tile))
         seg_p = np.asarray(op(b, backend="pallas"))
-        ok &= np.array_equal(seg_p, np.asarray(op0(b, backend="pallas")))
+        ok &= np.array_equal(seg_p, np.asarray(op1(b, backend="pallas")))
         ok &= np.array_equal(seg_p, np.asarray(op(b, backend="xla")))
     return ("spmm/segmented_bit_identical", 0.0,
             f"{ok}_int_valued_{len(mats)}mats")

@@ -33,7 +33,7 @@ def main() -> None:
     t0 = time.perf_counter()
     gops = gnn.GraphOps(a)
     print(f"preprocessed graph: nnz={a.nnz} "
-          f"spmm_tc_ratio={gops.arrs['tc_vals'].shape[0]} blocks "
+          f"spmm_tc_ratio={gops.arrs.plan.tc.nblk} blocks "
           f"({time.perf_counter() - t0:.3f}s, reused every step)")
 
     norm = jnp.asarray(gnn.gcn_norm_edges(a))

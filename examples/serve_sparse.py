@@ -13,7 +13,7 @@ A fourth "chaotic" tenant then demonstrates the resilience layer: its
 requests carry deadlines, and an injected
 :class:`~repro.serve.faults.FaultPlan` crashes the fast packed apply —
 the engine degrades down the bit-equivalent ladder (packed → singles →
-unsegmented → xla), the expired request is dropped with a typed
+xla), the expired request is dropped with a typed
 ``DeadlineExceeded`` result, and ``engine.health()`` shows the
 breaker/degradation accounting.
 

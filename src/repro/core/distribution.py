@@ -29,24 +29,6 @@ def r_sddmm(nnz: int | np.ndarray, m: int, n: int):
 
 
 @dataclasses.dataclass(frozen=True)
-class SpMMSplit:
-    """Per-window split decision for SpMM (vector granularity)."""
-
-    tc_idx: np.ndarray   # indices into WindowVectors arrays → MXU portion
-    vpu_idx: np.ndarray  # indices → VPU portion
-
-
-def split_spmm_window(wv: WindowVectors, threshold: int) -> SpMMSplit:
-    """Vectors with NNZ ≥ threshold go to the MXU; the rest to the VPU.
-
-    threshold=1 ⇒ MXU-only; threshold=WINDOW+1 ⇒ VPU-only (used by the
-    single-resource ablations).
-    """
-    dense = wv.counts >= threshold
-    return SpMMSplit(np.nonzero(dense)[0], np.nonzero(~dense)[0])
-
-
-@dataclasses.dataclass(frozen=True)
 class SDDMMSplit:
     """Per-window split for SDDMM (block granularity).
 

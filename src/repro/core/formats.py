@@ -31,30 +31,20 @@ import numpy as np
 
 WINDOW = 8  # paper: 8×1 non-zero column vectors (swap-and-transpose granularity)
 
-#: The three device-byte attribution views of a plan (see
-#: :func:`view_of_key` / :class:`PlanArrays`): the compact
-#: per-block/per-tile tensors, the §4.3 segment launch tables, and the
-#: revaluation position maps.
-PLAN_VIEWS = ("compact", "segment", "revalue")
+#: The two device-byte attribution views of a plan (see
+#: :func:`view_of_key` / :class:`PlanArrays`): the §4.3 segment launch
+#: tables every apply reads, and the revaluation position maps.
+PLAN_VIEWS = ("segment", "revalue")
 
-# SpMM revaluation maps: canonical-nnz position tensors read only by
-# ref.revalue_spmm_arrays. (SDDMM's *_out_pos keys are structural
-# scatter maps every apply needs — they stay in compact/segment.)
-_REVALUE_KEYS = frozenset(
-    {"tc_pos", "vpu_pos", "tc_seg_pos", "vpu_seg_pos"})
-
-# vals tensor → the pos map that rebuilds it (ref.revalue_spmm_arrays).
-_REVALUE_OF = {"tc_vals": "tc_pos", "vpu_vals": "vpu_pos",
-               "tc_seg_vals": "tc_seg_pos", "vpu_seg_vals": "vpu_seg_pos"}
+# SpMM value tensor → the canonical-nnz position map that rebuilds it
+# (read only by ref.revalue_spmm_arrays). SDDMM's *_out_pos keys are
+# structural scatter maps every apply needs — they stay in "segment".
+_REVALUE_OF = {"tc_seg_vals": "tc_seg_pos", "vpu_seg_vals": "vpu_seg_pos"}
 
 
 def view_of_key(key: str) -> str:
     """Classify one device-array key into a :data:`PLAN_VIEWS` view."""
-    if key in _REVALUE_KEYS:
-        return "revalue"
-    if "_seg_" in key:
-        return "segment"
-    return "compact"
+    return "revalue" if key in _REVALUE_OF.values() else "segment"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -72,13 +62,10 @@ class TCBlocks:
     Two fields are *derived* from ``window`` (the TC-window compaction map):
 
     rank:       (nblk,) i32 — dense rank of each block's window among the
-                windows that have TC work. The XLA reference sums block
-                partials at ``rank`` instead of ``window``, so its TC
-                buffer is ``(n_active, 8, n)`` rather than ``(nwin, 8, n)``
-                — on hyper-sparse matrices (tc_ratio → 0) that removes
-                nearly the entire zero-initialized dense output.
-    active_win: (n_active,) i32 — rank → window id, used by the combine
-                epilogues to place TC rows into C.
+                windows that have TC work (the slab a block sums into in
+                :func:`repro.kernels.ref.spmm_tc_compact_ref`'s
+                ``(n_active, 8, n)`` layout).
+    active_win: (n_active,) i32 — rank → window id.
     """
 
     vals: np.ndarray
@@ -207,52 +194,47 @@ def _spmm_segment_arrays(plan: "SpMMPlan") -> dict[str, np.ndarray]:
     flattened to an ``(8, ts·bk)`` operand (the sum of per-block
     ``8×bk @ bk×n`` products equals one ``8×(ts·bk) @ (ts·bk)×n``
     product, so a segment is a single MXU dot); ``tc_seg_row`` maps its
-    8 output rows to its window's rows of C. VPU: segment ``s`` owns ≤ ``cs`` residual elements (whole tiles) of
-    one row — the same kernel, a wider tile; ``vpu_seg_len`` counts its
-    real elements, so the kernel fetches no B row for its padding.
-    Padding is inert: zero values, column 0, and ``pos`` −1 so
-    revaluation skips it.
+    8 output rows to its window's rows of C. VPU: segment ``s`` owns
+    ≤ ``cs`` residual elements (whole tiles) of one row — the same
+    kernel, a wider tile; ``vpu_seg_len`` counts its real elements, so
+    the kernel fetches no B row for its padding. With ``ts=1`` and
+    ``cs=ts_tile`` the tables are the per-block/per-tile tensors
+    themselves. Padding is inert: zero values, column 0, and ``pos`` −1
+    so revaluation skips it.
     """
     out: dict[str, np.ndarray] = {}
-    tc_seg = plan.meta.get("tc_segments")
-    if tc_seg is not None:
-        tc = plan.tc
-        take, mask = _seg_take_map(tc_seg, tc.nblk)
-        nseg, w = take.shape
-        win = (tc_seg.cur if tc_seg.nseg else np.zeros(1, np.int64))
-        vals = tc.vals[take] * mask[:, :, None, None]       # (nseg,w,8,bk)
-        cols = np.where(mask[:, :, None], tc.cols[take], 0)
-        pos = (np.where(mask[:, :, None, None], tc.pos[take], -1)
-               if tc.pos is not None else None)
-        bk = tc.vals.shape[-1]
-        out["tc_seg_vals"] = vals.transpose(0, 2, 1, 3).reshape(
-            nseg, WINDOW, w * bk).astype(np.float32)
-        out["tc_seg_cols"] = cols.reshape(nseg, w * bk).astype(np.int32)
-        if pos is not None:
-            out["tc_seg_pos"] = pos.transpose(0, 2, 1, 3).reshape(
-                nseg, WINDOW, w * bk).astype(np.int32)
-        out["tc_seg_row"] = (
-            win[:, None].astype(np.int64) * WINDOW
-            + np.arange(WINDOW, dtype=np.int64)[None, :]
-        ).reshape(-1).astype(np.int32)
-    vpu_seg = plan.meta.get("vpu_segments")
-    if vpu_seg is not None:
-        vpu = plan.vpu
-        take, mask = _seg_take_map(vpu_seg, vpu.ntiles)
-        nseg, spt = take.shape
-        row = (vpu_seg.cur if vpu_seg.nseg else np.zeros(1, np.int64))
-        ts = vpu.vals.shape[-1]
-        out["vpu_seg_vals"] = (vpu.vals[take] * mask[:, :, None]).reshape(
-            nseg, spt * ts).astype(np.float32)
-        out["vpu_seg_cols"] = np.where(
-            mask[:, :, None], vpu.cols[take], 0
-        ).reshape(nseg, spt * ts).astype(np.int32)
-        if vpu.pos is not None:
-            out["vpu_seg_pos"] = np.where(
-                mask[:, :, None], vpu.pos[take], -1
-            ).reshape(nseg, spt * ts).astype(np.int32)
-        out["vpu_seg_row"] = row.astype(np.int32)
-        out["vpu_seg_len"] = _vpu_seg_len(vpu, take, mask)
+    tc, tc_seg = plan.tc, plan.meta["tc_segments"]
+    take, mask = _seg_take_map(tc_seg, tc.nblk)
+    nseg, w = take.shape
+    win = (tc_seg.cur if tc_seg.nseg else np.zeros(1, np.int64))
+    vals = tc.vals[take] * mask[:, :, None, None]       # (nseg,w,8,bk)
+    cols = np.where(mask[:, :, None], tc.cols[take], 0)
+    bk = tc.vals.shape[-1]
+    out["tc_seg_vals"] = vals.transpose(0, 2, 1, 3).reshape(
+        nseg, WINDOW, w * bk).astype(np.float32)
+    out["tc_seg_cols"] = cols.reshape(nseg, w * bk).astype(np.int32)
+    pos = np.where(mask[:, :, None, None], tc.pos[take], -1)
+    out["tc_seg_pos"] = pos.transpose(0, 2, 1, 3).reshape(
+        nseg, WINDOW, w * bk).astype(np.int32)
+    out["tc_seg_row"] = (
+        win[:, None].astype(np.int64) * WINDOW
+        + np.arange(WINDOW, dtype=np.int64)[None, :]
+    ).reshape(-1).astype(np.int32)
+    vpu, vpu_seg = plan.vpu, plan.meta["vpu_segments"]
+    take, mask = _seg_take_map(vpu_seg, vpu.ntiles)
+    nseg, spt = take.shape
+    row = (vpu_seg.cur if vpu_seg.nseg else np.zeros(1, np.int64))
+    ts = vpu.vals.shape[-1]
+    out["vpu_seg_vals"] = (vpu.vals[take] * mask[:, :, None]).reshape(
+        nseg, spt * ts).astype(np.float32)
+    out["vpu_seg_cols"] = np.where(
+        mask[:, :, None], vpu.cols[take], 0
+    ).reshape(nseg, spt * ts).astype(np.int32)
+    out["vpu_seg_pos"] = np.where(
+        mask[:, :, None], vpu.pos[take], -1
+    ).reshape(nseg, spt * ts).astype(np.int32)
+    out["vpu_seg_row"] = row.astype(np.int32)
+    out["vpu_seg_len"] = _vpu_seg_len(vpu, take, mask)
     return out
 
 
@@ -264,20 +246,13 @@ def _vpu_seg_len(vpu: VPUTiles, take: np.ndarray,
     elements are a prefix of its slots and the kernel fetches B rows for
     just that prefix. Fills come from the ``pos`` map (−1 on padding),
     never from the values: a real edge may hold 0."""
-    if vpu.pos is None:
-        fill = np.full(vpu.ntiles, vpu.ts, np.int64)
-    else:
-        fill = (vpu.pos >= 0).sum(axis=1)
+    fill = (vpu.pos >= 0).sum(axis=1)
     return (fill[take] * mask).sum(axis=1).astype(np.int32)
 
 
-def spmm_vpu_seg_len(plan: "SpMMPlan") -> np.ndarray | None:
-    """The ``vpu_seg_len`` table the segmented VPU SpMM launches with,
-    or ``None`` for a plan without VPU segment tables."""
-    vpu_seg = plan.meta.get("vpu_segments")
-    if vpu_seg is None:
-        return None
-    take, mask = _seg_take_map(vpu_seg, plan.vpu.ntiles)
+def spmm_vpu_seg_len(plan: "SpMMPlan") -> np.ndarray:
+    """The ``vpu_seg_len`` table the VPU SpMM kernel launches with."""
+    take, mask = _seg_take_map(plan.meta["vpu_segments"], plan.vpu.ntiles)
     return _vpu_seg_len(plan.vpu, take, mask)
 
 
@@ -289,143 +264,85 @@ def _sddmm_segment_arrays(plan: "SDDMMPlan") -> dict[str, np.ndarray]:
     concatenated bitmaps (zero bitmap padding samples to zero and its
     ``out_pos`` −1 lands in the scatter's swallow slot). VPU: element
     tiles are flat, so the Cs cap just batches ``seg_spt`` tiles per
-    grid step (mask-False padding).
+    grid step (mask-False padding); at ``seg_spt`` 1 the tables are the
+    per-tile tensors themselves.
     """
     out: dict[str, np.ndarray] = {}
-    tc_seg = plan.meta.get("tc_segments")
-    if tc_seg is not None:
-        tc = plan.tc
-        take, mask = _seg_take_map(tc_seg, tc.nblk)
-        nseg, w = take.shape
-        win = (tc_seg.cur if tc_seg.nseg else np.zeros(1, np.int64))
-        bk = tc.cols.shape[-1]
-        out["tc_seg_cols"] = np.where(
-            mask[:, :, None], tc.cols[take], 0
-        ).reshape(nseg, w * bk).astype(np.int32)
-        out["tc_seg_bitmap"] = np.where(
-            mask[:, :, None], tc.bitmap[take], 0
-        ).reshape(nseg, w * bk).astype(np.uint32)
-        out["tc_seg_window"] = win.astype(np.int32)
-        out["tc_seg_out_pos"] = np.where(
-            mask[:, :, None, None], plan.tc_out_pos[take], -1
-        ).transpose(0, 2, 1, 3).reshape(nseg, WINDOW, w * bk).astype(np.int32)
-    spt = int(plan.meta.get("seg_spt", 1))
-    if spt > 1:
-        vpu = plan.vpu
-        nt, ts = vpu.rows.shape
-        nsegE = -(-nt // spt)
-        pad = nsegE * spt - nt
+    tc, tc_seg = plan.tc, plan.meta["tc_segments"]
+    take, mask = _seg_take_map(tc_seg, tc.nblk)
+    nseg, w = take.shape
+    win = (tc_seg.cur if tc_seg.nseg else np.zeros(1, np.int64))
+    bk = tc.cols.shape[-1]
+    out["tc_seg_cols"] = np.where(
+        mask[:, :, None], tc.cols[take], 0
+    ).reshape(nseg, w * bk).astype(np.int32)
+    out["tc_seg_bitmap"] = np.where(
+        mask[:, :, None], tc.bitmap[take], 0
+    ).reshape(nseg, w * bk).astype(np.uint32)
+    out["tc_seg_window"] = win.astype(np.int32)
+    out["tc_seg_out_pos"] = np.where(
+        mask[:, :, None, None], plan.tc_out_pos[take], -1
+    ).transpose(0, 2, 1, 3).reshape(nseg, WINDOW, w * bk).astype(np.int32)
+    spt = int(plan.meta["seg_spt"])
+    vpu = plan.vpu
+    nt, ts = vpu.rows.shape
+    nsegE = -(-nt // spt)
+    pad = nsegE * spt - nt
 
-        def _grp(x, fill):
-            x = np.concatenate(
-                [x, np.full((pad, ts), fill, x.dtype)]) if pad else x
-            return x.reshape(nsegE, spt * ts)
+    def _grp(x, fill):
+        x = np.concatenate(
+            [x, np.full((pad, ts), fill, x.dtype)]) if pad else x
+        return x.reshape(nsegE, spt * ts)
 
-        out["vpu_seg_rows"] = _grp(vpu.rows, 0).astype(np.int32)
-        out["vpu_seg_cols"] = _grp(vpu.cols, 0).astype(np.int32)
-        out["vpu_seg_out_pos"] = _grp(vpu.out_pos, 0).astype(np.int32)
-        out["vpu_seg_mask"] = _grp(vpu.mask, False)
+    out["vpu_seg_rows"] = _grp(vpu.rows, 0).astype(np.int32)
+    out["vpu_seg_cols"] = _grp(vpu.cols, 0).astype(np.int32)
+    out["vpu_seg_out_pos"] = _grp(vpu.out_pos, 0).astype(np.int32)
+    out["vpu_seg_mask"] = _grp(vpu.mask, False).astype(np.bool_)
     return out
 
 
 def _host_arrays(plan) -> dict[str, np.ndarray]:
-    """Every device-uploadable array of one plan, host-side, in its
-    exact device dtype (so ``nbytes`` matches ``jax.Array.nbytes`` and
-    a byte budget can be priced without uploading)."""
-    out: dict[str, np.ndarray] = {}
+    """Every device-uploadable array of one plan — its §4.3 segment
+    tables and, for SpMM, their revaluation position maps — host-side,
+    in its exact device dtype (so ``nbytes`` matches
+    ``jax.Array.nbytes`` and a byte budget can be priced without
+    uploading)."""
     if isinstance(plan, SpMMPlan):
-        # tc_active_row: flat output-row index of every compacted TC row —
-        # the scatter map of the fused combine epilogue (rank r owns rows
-        # active_win[r]*8 .. active_win[r]*8+7 of C).
-        active_rows = (
-            plan.tc.active_win[:, None].astype(np.int64) * WINDOW
-            + np.arange(WINDOW, dtype=np.int64)[None, :]
-        ).reshape(-1)
-        out.update(
-            tc_vals=np.asarray(plan.tc.vals, np.float32),
-            tc_cols=np.asarray(plan.tc.cols, np.int32),
-            tc_bitmap=np.asarray(plan.tc.bitmap, np.uint32),
-            tc_rank=np.asarray(plan.tc.rank, np.int32),
-            tc_active_row=np.asarray(active_rows, np.int32),
-            vpu_vals=np.asarray(plan.vpu.vals, np.float32),
-            vpu_cols=np.asarray(plan.vpu.cols, np.int32),
-            vpu_row=np.asarray(plan.vpu.row, np.int32),
-        )
-        if plan.tc.pos is not None:
-            out["tc_pos"] = np.asarray(plan.tc.pos, np.int32)
-        if plan.vpu.pos is not None:
-            out["vpu_pos"] = np.asarray(plan.vpu.pos, np.int32)
-        for k, v in _spmm_segment_arrays(plan).items():
-            out[k] = np.asarray(v)
-    elif isinstance(plan, SDDMMPlan):
-        out.update(
-            tc_cols=np.asarray(plan.tc.cols, np.int32),
-            tc_bitmap=np.asarray(plan.tc.bitmap, np.uint32),
-            tc_window=np.asarray(plan.tc.window, np.int32),
-            tc_out_pos=np.asarray(plan.tc_out_pos, np.int32),
-            vpu_rows=np.asarray(plan.vpu.rows, np.int32),
-            vpu_cols=np.asarray(plan.vpu.cols, np.int32),
-            vpu_out_pos=np.asarray(plan.vpu.out_pos, np.int32),
-            vpu_mask=np.asarray(plan.vpu.mask, np.bool_),
-        )
-        for k, v in _sddmm_segment_arrays(plan).items():
-            out[k] = np.asarray(v)
-    else:  # pragma: no cover
-        raise TypeError(type(plan))
-    return out
-
-
-# Compact key sets per stream (SpMM / SDDMM) and their §4.3 segment
-# replacements — the ingredients of PlanArrays.backend_keys.
-_SPMM_TC = ("tc_vals", "tc_cols", "tc_rank", "tc_active_row")
-_SPMM_TC_SEG = ("tc_seg_vals", "tc_seg_cols", "tc_seg_row")
-_SPMM_VPU = ("vpu_vals", "vpu_cols", "vpu_row")
-_SPMM_VPU_SEG = ("vpu_seg_vals", "vpu_seg_cols", "vpu_seg_row",
-                 "vpu_seg_len")
-_SDDMM_TC = ("tc_cols", "tc_bitmap", "tc_window", "tc_out_pos")
-_SDDMM_TC_SEG = ("tc_seg_cols", "tc_seg_bitmap", "tc_seg_window",
-                 "tc_seg_out_pos")
-_SDDMM_VPU = ("vpu_rows", "vpu_cols", "vpu_out_pos", "vpu_mask")
-_SDDMM_VPU_SEG = ("vpu_seg_rows", "vpu_seg_cols", "vpu_seg_out_pos",
-                  "vpu_seg_mask")
+        return _spmm_segment_arrays(plan)
+    if isinstance(plan, SDDMMPlan):
+        return _sddmm_segment_arrays(plan)
+    raise TypeError(type(plan))  # pragma: no cover
 
 
 class PlanArrays(Mapping):
-    """Lazy, byte-accounted device views of one plan (paper §4.1 ③,
-    made backend-aware).
+    """Lazy, byte-accounted device views of one plan (paper §4.1 ③).
 
-    The eager ``device_arrays`` dict uploaded *both* the compact
-    per-block/per-tile view and the §4.3 segment launch view — ~2× the
-    plan bytes a given backend ever reads. ``PlanArrays`` keeps the
-    plan host-side and uploads each array on first use:
+    ``PlanArrays`` keeps the plan host-side and uploads each array on
+    first use:
 
-    * :meth:`for_backend` returns the exact key set one backend's apply
-      reads (``xla`` → compact only — ``tc_bitmap`` is SpMM-dead on
-      both backends and never uploads; ``pallas`` → segment tables for
-      segmented streams, compact fallback otherwise; ``revalue=True``
-      swaps value tensors for their position maps, which
-      :func:`repro.kernels.ref.revalue_spmm_arrays` rebuilds in-trace),
-      so a pallas-serving registry holds only the segment view and an
-      xla one only the compact view. Outputs are bit-identical: the
-      dropped keys are exactly the ones the selected apply never reads.
+    * :meth:`apply_arrays` returns the exact key set an apply reads —
+      the same on both backends: the §4.3 segment tables, or with
+      ``revalue=True`` the tables with each value tensor swapped for its
+      position map, which :func:`repro.kernels.ref.revalue_spmm_arrays`
+      rebuilds in-trace. A serving registry therefore never holds the
+      baked-in values of a revalued plan.
     * Every upload is recorded (key, view, ``nbytes``, dtype); an
       *accountant* callback (:meth:`set_accountant` — usually a
       :class:`repro.obs.memstat.MemLedger` binder) receives each record,
       with already-resident uploads replayed on attach.
     * The object is a ``Mapping`` **and** a registered jax pytree whose
-      flatten materializes every key — legacy call sites that pass
+      flatten materializes every key — call sites that pass
       ``op.arrays`` straight into a jit (tests, benches, the GNN VJP)
-      keep working, eager-equivalently.
+      get every table and position map.
     """
 
     def __init__(self, plan):
         self.plan = plan
-        self.kind = "spmm" if isinstance(plan, SpMMPlan) else "sddmm"
         self._host = _host_arrays(plan)
         self._views = {k: view_of_key(k) for k in self._host}
         self._dev: dict[str, jnp.ndarray] = {}
         self._uploads: dict[str, tuple[str, int, str]] = {}
-        self._bcache: dict[tuple, dict] = {}
+        self._bcache: dict[bool, dict] = {}
         self._accountant = None
 
     # ------------------------------------------------------- mapping ---
@@ -454,59 +371,28 @@ class PlanArrays(Mapping):
     def __contains__(self, key) -> bool:
         return key in self._host
 
-    # ------------------------------------------------- backend views ---
-    @property
-    def segmented(self) -> bool:
-        """True when the plan carries §4.3 segment launch tables."""
-        return any(self._views[k] == "segment" for k in self._host)
-
-    def backend_keys(self, backend: str, *, revalue: bool = False,
-                     segmented: bool = True) -> tuple[str, ...]:
-        """The exact key set ``backend``'s apply reads for this plan."""
-        ks = self._host
-        if self.kind == "spmm":
-            if backend == "xla" or not segmented:
-                keys = list(_SPMM_TC + _SPMM_VPU)
-            else:
-                keys = list(_SPMM_TC_SEG if "tc_seg_vals" in ks
-                            else _SPMM_TC)
-                keys += list(_SPMM_VPU_SEG if "vpu_seg_vals" in ks
-                             else _SPMM_VPU)
-            if revalue:
-                # Swap each value tensor for its position map — the
-                # revaluation path rebuilds values in-trace, so the
-                # baked-in ones never upload.
-                swapped = []
-                for k in keys:
-                    pos = _REVALUE_OF.get(k)
-                    swapped.append(pos if pos is not None and pos in ks
-                                   else k)
-                keys = swapped
-            return tuple(keys)
-        if backend == "xla" or not segmented:
-            return _SDDMM_TC + _SDDMM_VPU
-        keys = list(_SDDMM_TC_SEG if "tc_seg_cols" in ks else _SDDMM_TC)
-        keys += list(_SDDMM_VPU_SEG if "vpu_seg_rows" in ks
-                     else _SDDMM_VPU)
+    # --------------------------------------------------- apply views ---
+    def apply_keys(self, *, revalue: bool = False) -> tuple[str, ...]:
+        """The exact key set an apply of this plan reads (either
+        backend); ``revalue`` swaps each value tensor for the position
+        map that rebuilds it."""
+        keys = [k for k in self._host if self._views[k] == "segment"]
+        if revalue:
+            keys = [_REVALUE_OF.get(k, k) for k in keys]
         return tuple(keys)
 
-    def for_backend(self, backend: str, *, revalue: bool = False,
-                    segmented: bool = True) -> dict[str, jnp.ndarray]:
-        """Materialize (upload on first use) and return the minimal
-        device dict for one backend; memoized per (backend, revalue,
-        segmented)."""
-        ck = (backend, revalue, segmented)
-        cached = self._bcache.get(ck)
+    def apply_arrays(self, *, revalue: bool = False
+                     ) -> dict[str, jnp.ndarray]:
+        """Materialize (upload on first use) and return the device dict
+        of :meth:`apply_keys`; memoized per ``revalue``."""
+        cached = self._bcache.get(revalue)
         if cached is None:
-            cached = self._bcache[ck] = {
-                k: self[k]
-                for k in self.backend_keys(backend, revalue=revalue,
-                                           segmented=segmented)}
+            cached = self._bcache[revalue] = {
+                k: self[k] for k in self.apply_keys(revalue=revalue)}
         return cached
 
     def materialize_all(self) -> dict[str, jnp.ndarray]:
-        """Upload every view (the old eager behaviour) and return the
-        full device dict."""
+        """Upload every view and return the full device dict."""
         return {k: self[k] for k in self._host}
 
     # ---------------------------------------------------- accounting ---
@@ -536,16 +422,11 @@ class PlanArrays(Mapping):
             out[v] += nb
         return out
 
-    def projected_nbytes(self, backend: str | None = None, *,
-                         revalue: bool = False,
-                         segmented: bool = True) -> int:
-        """Bytes this plan *would* hold resident once served: the
-        backend key set's host ``nbytes`` (device dtypes match host —
-        see :func:`_host_arrays`), or all keys when ``backend`` is
-        None. No upload happens."""
-        keys = (self._host if backend is None
-                else self.backend_keys(backend, revalue=revalue,
-                                       segmented=segmented))
+    def projected_nbytes(self, keys=None) -> int:
+        """Bytes ``keys`` (every key when None) would hold resident once
+        uploaded: their host ``nbytes`` (device dtypes match host — see
+        :func:`_host_arrays`). No upload happens."""
+        keys = self._host if keys is None else keys
         return sum(int(self._host[k].nbytes) for k in keys)
 
     def memory(self) -> dict:
@@ -584,8 +465,7 @@ jax.tree_util.register_pytree_node(
 
 
 def device_arrays(plan) -> PlanArrays:
-    """Backend-aware lazy device views of a plan; arrays upload on
-    first use and register their bytes (paper §4.1 ③ — upload once,
-    reuse across iterations; see :class:`PlanArrays`). Indexing or
-    flattening the result reproduces the old eager dict exactly."""
+    """Lazy device views of a plan; arrays upload on first use and
+    register their bytes (paper §4.1 ③ — upload once, reuse across
+    iterations; see :class:`PlanArrays`)."""
     return PlanArrays(plan)

@@ -1,15 +1,16 @@
 """Libra preprocessing: distribution + balancing + format build (paper §4.5).
 
 Preprocessing runs once per sparse matrix; its products (:class:`SpMMPlan`
-/ :class:`SDDMMPlan`) are uploaded once and reused every iteration. Two
-implementations are provided:
+/ :class:`SDDMMPlan`) carry the §4.3 segment tables, uploaded once and
+reused every iteration. The SpMM preprocessor comes in two forms:
 
-* the **vectorized** path (default) — NumPy/JAX bulk ops, the analogue of
-  the paper's GPU-accelerated preprocessing kernels;
+* the **vectorized** path (:func:`preprocess_spmm`, the one every
+  operator runs) — NumPy bulk ops, the analogue of the paper's
+  GPU-accelerated preprocessing kernels;
 * a **scalar** per-element loop (:func:`preprocess_spmm_loop`) — the
-  sequential-CPU baseline the paper compares against (their OpenMP row).
-
-Both produce bit-identical plans (tested).
+  sequential-CPU baseline the paper compares against (their OpenMP
+  row). It builds the same block and tile tensors (tested bit for bit)
+  and no segment tables: it is compared and timed, never uploaded.
 """
 from __future__ import annotations
 
@@ -23,7 +24,7 @@ from repro.core.balance import (
     decompose_counts,
     propagate_atomicity,
 )
-from repro.core.distribution import split_sddmm_window, split_spmm_window
+from repro.core.distribution import split_sddmm_window
 from repro.core.formats import (
     COOTiles,
     SDDMMPlan,
@@ -74,24 +75,25 @@ def _resolve(explicit, cfg_value, default):
 def _resolve_balance(balance: BalanceParams | None,
                      cfg: TuneConfig | None) -> BalanceParams:
     """§4.3 segment caps resolve explicit ``balance`` > ``cfg.ts``/``cfg.cs``
-    > the :class:`BalanceParams` defaults. A cap of 0 disables that
-    path's segmentation (legacy per-block / per-tile launch)."""
-    if balance is not None:
-        return balance
-    return BalanceParams(
-        ts=_resolve(None, cfg and cfg.ts, BalanceParams.ts),
-        cs=_resolve(None, cfg and cfg.cs, BalanceParams.cs))
+    > the :class:`BalanceParams` defaults. Every plan launches from its
+    segment tables, so a cap below 1 is refused."""
+    if balance is None:
+        balance = BalanceParams(
+            ts=_resolve(None, cfg and cfg.ts, BalanceParams.ts),
+            cs=_resolve(None, cfg and cfg.cs, BalanceParams.cs))
+    if balance.ts < 1 or balance.cs < 1:
+        raise ValueError(f"segment caps must be >= 1: ts={balance.ts}, "
+                         f"cs={balance.cs}")
+    return balance
 
 
 def _propagate_segment_atomicity(
-        tc_seg: Segments | None, vpu_seg: Segments | None
-) -> tuple[Segments | None, Segments | None]:
+        tc_seg: Segments, vpu_seg: Segments) -> tuple[Segments, Segments]:
     """Paper Fig. 6 window-1 rule at segment granularity: once any
     segment writing into a window is atomic (decomposed or shared), every
     other segment of that window becomes atomic too. VPU segment owners
     are rows; their window is ``row // WINDOW``."""
-    if tc_seg is None or vpu_seg is None or not tc_seg.nseg \
-            or not vpu_seg.nseg:
+    if not tc_seg.nseg or not vpu_seg.nseg:
         return tc_seg, vpu_seg
     vpu_win = vpu_seg.cur // WINDOW
     hot = np.union1d(tc_seg.cur[tc_seg.atomic], vpu_win[vpu_seg.atomic])
@@ -105,7 +107,7 @@ def _propagate_segment_atomicity(
 def _spmm_segments(tc_blocks_per_win: np.ndarray, shared: np.ndarray,
                    tiles_per_row: np.ndarray, row_shared: np.ndarray,
                    balance: BalanceParams, ts_tile: int
-                   ) -> tuple[Segments | None, Segments | None, int]:
+                   ) -> tuple[Segments, Segments, int]:
     """Build both §4.3 segment launch tables for one SpMM plan.
 
     TC segments own ≤ ``ts`` condensed blocks of one window; VPU
@@ -114,10 +116,8 @@ def _spmm_segments(tc_blocks_per_win: np.ndarray, shared: np.ndarray,
     tiles-per-VPU-segment grouping.
     """
     spt = max(1, balance.cs // max(ts_tile, 1))
-    tc_seg = (decompose_counts(tc_blocks_per_win, balance.ts, shared)
-              if balance.ts > 0 else None)
-    vpu_seg = (decompose_counts(tiles_per_row, spt, row_shared)
-               if balance.cs > 0 else None)
+    tc_seg = decompose_counts(tc_blocks_per_win, balance.ts, shared)
+    vpu_seg = decompose_counts(tiles_per_row, spt, row_shared)
     tc_seg, vpu_seg = _propagate_segment_atomicity(tc_seg, vpu_seg)
     return tc_seg, vpu_seg, spt
 
@@ -371,152 +371,6 @@ def _empty_spmm_plan(a, threshold, bk, ts_tile, balance) -> SpMMPlan:
     return SpMMPlan(a.m, a.k, a.nnz, threshold, tc, vpu, meta)
 
 
-def _preprocess_spmm_semivectorized(
-    a: SparseCSR,
-    threshold: int = DEFAULT_SPMM_THRESHOLD,
-    bk: int = DEFAULT_BK_SPMM,
-    ts_tile: int = 32,
-    balance: BalanceParams | None = None,
-) -> SpMMPlan:
-    """Previous per-window implementation (kept as a cross-check oracle)."""
-    balance = _resolve_balance(balance, None)
-    wvs = extract_windows(a)
-    nwin = num_windows(a.m)
-
-    blk_vals, blk_cols, blk_bits, blk_win, blk_pos = [], [], [], [], []
-    tc_blocks_per_win = np.zeros(nwin, np.int64)
-    tc_nnz = 0
-    # VPU residue gathered per row.
-    res_cols: list[list[np.ndarray]] = [[] for _ in range(a.m)]
-    res_vals: list[list[np.ndarray]] = [[] for _ in range(a.m)]
-    res_pos: list[list[np.ndarray]] = [[] for _ in range(a.m)]
-    win_has_tc = np.zeros(nwin, bool)
-    win_has_vpu = np.zeros(nwin, bool)
-
-    for w, wv in enumerate(wvs):
-        split = split_spmm_window(wv, threshold)
-        # --- MXU portion: condense selected vectors into 8×bk blocks.
-        sel = split.tc_idx
-        if sel.size:
-            win_has_tc[w] = True
-            tc_nnz += int(wv.counts[sel].sum())
-            for s in range(0, sel.size, bk):
-                part = sel[s : s + bk]
-                v = np.zeros((WINDOW, bk), np.float32)
-                c = np.zeros(bk, np.int32)
-                b = np.zeros(bk, np.uint32)
-                p = np.full((WINDOW, bk), -1, np.int32)
-                v[:, : part.size] = wv.vals[part].T
-                c[: part.size] = wv.cols[part]
-                b[: part.size] = wv.bitmap[part]
-                p[:, : part.size] = wv.pos[part].T
-                blk_vals.append(v)
-                blk_cols.append(c)
-                blk_bits.append(b)
-                blk_pos.append(p)
-                blk_win.append(w)
-                tc_blocks_per_win[w] += 1
-        # --- VPU portion: scatter residual vector elements back to rows.
-        if split.vpu_idx.size:
-            win_has_vpu[w] = True
-            for vi in split.vpu_idx:
-                col = wv.cols[vi]
-                occ = wv.vals[vi]
-                subs = np.nonzero(occ)[0]
-                for sub in subs:
-                    r = w * WINDOW + int(sub)
-                    res_cols[r].append(np.asarray([col], np.int32))
-                    res_vals[r].append(np.asarray([occ[sub]], np.float32))
-                    res_pos[r].append(np.asarray([wv.pos[vi, sub]], np.int32))
-
-    # --- Balance the MXU portion: ≤ Ts blocks per segment.
-    shared = win_has_tc & win_has_vpu
-
-    # --- VPU portion: short/long split + Cs decomposition into tiles.
-    t_vals, t_cols, t_row, t_long, t_pos = [], [], [], [], []
-    vpu_nnz = 0
-    rows_per_win_shared = win_has_tc  # a VPU row is shared if its window has TC work
-    for r in range(a.m):
-        if not res_cols[r]:
-            continue
-        cs_ = np.concatenate(res_cols[r])
-        vs_ = np.concatenate(res_vals[r])
-        ps_ = np.concatenate(res_pos[r])
-        vpu_nnz += vs_.size
-        is_long = vs_.size > balance.short_len
-        for s in range(0, vs_.size, ts_tile):
-            c = np.zeros(ts_tile, np.int32)
-            v = np.zeros(ts_tile, np.float32)
-            p = np.full(ts_tile, -1, np.int32)
-            seg_c, seg_v = cs_[s : s + ts_tile], vs_[s : s + ts_tile]
-            c[: seg_c.size] = seg_c
-            v[: seg_v.size] = seg_v
-            p[: seg_c.size] = ps_[s : s + ts_tile]
-            t_vals.append(v)
-            t_cols.append(c)
-            t_row.append(r)
-            t_long.append(is_long)
-            t_pos.append(p)
-
-    t_row_arr = np.asarray(t_row, np.int64) if t_row else np.zeros(0, np.int64)
-    tile_atomic = np.asarray(
-        [
-            bool(rows_per_win_shared[r // WINDOW])
-            or int((t_row_arr == r).sum()) > 1
-            for r in t_row
-        ],
-        bool,
-    ) if t_row else np.zeros(0, bool)
-
-    blk_atomic = np.asarray(
-        [bool(shared[w]) for w in blk_win], bool
-    ) if blk_win else np.zeros(0, bool)
-    if len(blk_win):
-        blk_atomic, tile_atomic = propagate_atomicity(
-            np.asarray(blk_win) if blk_win else np.zeros(0, np.int64),
-            blk_atomic,
-            t_row_arr // WINDOW,
-            tile_atomic,
-        )
-
-    tc = _pad_blocks(blk_vals, blk_cols, blk_bits, blk_win, blk_atomic, tc_nnz,
-                     bk, pos=blk_pos)
-    if t_vals:
-        vpu = VPUTiles(
-            np.stack(t_vals), np.stack(t_cols),
-            np.asarray(t_row, np.int32), np.asarray(t_long, bool),
-            tile_atomic, vpu_nnz, ts_tile, pos=np.stack(t_pos),
-        )
-    else:
-        vpu = VPUTiles(
-            np.zeros((1, ts_tile), np.float32), np.zeros((1, ts_tile), np.int32),
-            np.zeros(1, np.int32), np.zeros(1, bool), np.zeros(1, bool), 0, ts_tile,
-            pos=np.full((1, ts_tile), -1, np.int32),
-        )
-
-    tiles_per_row_sv = np.bincount(
-        np.asarray(t_row, np.int64), minlength=a.m).astype(np.int64) \
-        if t_row else np.zeros(a.m, np.int64)
-    row_shared_sv = win_has_tc[np.arange(a.m, dtype=np.int64) // WINDOW] \
-        if a.m else np.zeros(0, bool)
-    tc_seg, vpu_seg, spt = _spmm_segments(
-        tc_blocks_per_win, shared, tiles_per_row_sv, row_shared_sv,
-        balance, ts_tile)
-    meta = {
-        "tc_segments": tc_seg,
-        "vpu_segments": vpu_seg,
-        "seg_spt": spt,
-        "tc_nnz": tc_nnz,
-        "vpu_nnz": vpu_nnz,
-        "tc_ratio": tc_nnz / max(a.nnz, 1),
-        "has_tc": bool(tc_nnz),
-        "has_vpu": bool(vpu_nnz),
-        "balance": balance,
-    }
-    assert tc_nnz + vpu_nnz == a.nnz, (tc_nnz, vpu_nnz, a.nnz)
-    return SpMMPlan(a.m, a.k, a.nnz, threshold, tc, vpu, meta)
-
-
 def preprocess_sddmm(
     a: SparseCSR,
     threshold: int | None = None,
@@ -636,9 +490,8 @@ def preprocess_sddmm(
         # element tiles are flat (no per-row ownership, every score has
         # its own canonical output slot ⇒ no atomicity), so the Cs cap
         # only batches ``seg_spt`` tiles per VPU grid step.
-        "tc_segments": (decompose_counts(tc_blocks_per_win, balance.ts,
-                                         shared)
-                        if balance.ts > 0 else None),
+        "tc_segments": decompose_counts(tc_blocks_per_win, balance.ts,
+                                        shared),
         "vpu_segments": None,
         "seg_spt": max(1, balance.cs // max(ts_tile, 1)),
         "balance": balance,
@@ -669,10 +522,12 @@ def preprocess_spmm_loop(a: SparseCSR, threshold: int = DEFAULT_SPMM_THRESHOLD,
 
     Walks the matrix one element at a time in pure Python — window
     extraction, vector counting, bitmap building, threshold split, block
-    condensation and residue tiling all scalar. Produces a plan with the
-    same tensors as :func:`preprocess_spmm` (bit-identity tested); used by
-    the preprocessing benchmark to quantify the bulk-vectorized win (the
-    analogue of the paper's GPU-vs-OpenMP 17.1×).
+    condensation and residue tiling all scalar. Produces the same block
+    and tile tensors as :func:`preprocess_spmm` (bit-identity tested)
+    but no §4.3 segment tables, so its plan is compared and timed, never
+    uploaded; the preprocessing benchmark times it to quantify the
+    bulk-vectorized win (the analogue of the paper's GPU-vs-OpenMP
+    17.1×).
     """
     balance = balance or BalanceParams()
     nwin = num_windows(a.m)

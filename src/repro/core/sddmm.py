@@ -96,8 +96,8 @@ class LibraSDDMM:
                 perm = jnp.concatenate(
                     [perm, jnp.arange(self.m, x.shape[0])])
             x = jnp.take(x, perm, axis=0)
-        # Backend-aware lazy view: see LibraSpMM.__call__.
-        arrs = self.arrays.for_backend(backend)
+        # Lazy view: see LibraSpMM.__call__.
+        arrs = self.arrays.apply_arrays()
         fn = cached_compile(
             self._apply_cache,
             (x.shape[1], str(x.dtype), backend, x.shape[0], y.shape[0]),

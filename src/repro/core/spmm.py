@@ -117,10 +117,9 @@ class LibraSpMM:
                  ) -> jnp.ndarray:
         assert b.shape[0] == self.k, (b.shape, self.k)
         backend = self.spec.backend if backend is None else backend
-        # Only the key set this backend's apply reads is uploaded —
-        # an xla operator never materializes the §4.3 segment tables
-        # and a pallas one never the compact fallback.
-        arrs = self.arrays.for_backend(backend)
+        # Only the key set the apply reads is uploaded: the §4.3
+        # segment tables, never their revaluation maps.
+        arrs = self.arrays.apply_arrays()
         fn = cached_compile(
             self._apply_cache,
             (b.shape[1], str(b.dtype), backend),

@@ -25,14 +25,13 @@ Each shard is then a self-contained Libra problem:
   model-tuned partition: the ``run_cfg`` holds only lane-tile caps, a
   VMEM bound (each call derives its tile from its width), so a
   partition has nothing to time.
-* **padded stacking** — per-shard device arrays are padded to common
-  shapes and stacked on a leading shard axis so ``shard_map`` can split
-  them over a mesh axis. Padding is *semantically inert by
-  construction*: dummy TC blocks carry zero values and cover exactly
-  the compacted output ranks a shard is missing (so the Pallas kernel
-  writes every output block), dummy VPU tiles scatter zeros onto local
-  row 0, dummy SDDMM entries carry bitmap 0 / mask False and scatter
-  into the swallow slot.
+* **padded stacking** — per-shard §4.3 segment tables are padded to
+  common shapes and stacked on a leading shard axis so ``shard_map``
+  can split them over a mesh axis. Padding is *semantically inert by
+  construction*: padding SpMM segments carry zero values and length 0
+  and scatter onto the last local row (keeping every row map
+  non-decreasing), padding SDDMM segments carry bitmap 0 / mask False
+  and scatter into the swallow slot.
 
 ``out_gather`` / ``nnz_gather`` invert the padding: one global ``take``
 reassembles the row-partitioned C (or the canonical nnz value vector)
@@ -117,8 +116,7 @@ def shard_windows(a: SparseCSR, n_shards: int,
 
 
 def segment_curve(a: SparseCSR, *, op: str, threshold: int, bk: int,
-                  seg_ts: int, seg_cs: int, ts_tile: int,
-                  feat=None) -> np.ndarray:
+                  seg_ts: int, seg_cs: int, feat=None) -> np.ndarray:
     """Per-window §4.3 segment counts — the number of kernel grid steps
     (launch-table rows) each window contributes under the given caps.
 
@@ -146,10 +144,8 @@ def segment_curve(a: SparseCSR, *, op: str, threshold: int, bk: int,
         tc_mask, nblk_w, nnz_win = sddmm_window_split(feat, threshold, bk)
         blocks_w = np.where(tc_mask, nblk_w, 0).astype(np.int64)
         tc_nnz_w = np.where(tc_mask, nnz_win, 0)
-    tc_segs = -(-blocks_w // seg_ts) if seg_ts > 0 else blocks_w
-    res_w = nnz_w - tc_nnz_w
-    cs_eff = max(seg_cs if seg_cs > 0 else ts_tile, 1)
-    vpu_segs = -(-res_w // cs_eff)
+    tc_segs = -(-blocks_w // seg_ts)
+    vpu_segs = -(-(nnz_w - tc_nnz_w) // seg_cs)
     # matrix_features pads the histogram to max(nwin, 1) rows; trim so
     # an empty (m=0) matrix yields the empty curve shard_windows expects.
     return (tc_segs + vpu_segs).astype(np.int64)[:num_windows(a.m)]
@@ -225,6 +221,16 @@ def _offset_pos(pos: np.ndarray, off: int) -> np.ndarray:
     return np.where(pos >= 0, pos + off, -1).astype(np.int32)
 
 
+def _stack_halos(shards) -> np.ndarray:
+    """Each shard's halo map on a leading shard axis, zero-padded to the
+    widest."""
+    halo = np.zeros((len(shards), max(1, max(s.halo.size for s in shards))),
+                    np.int32)
+    for p, shard in enumerate(shards):
+        halo[p, :shard.halo.size] = shard.halo
+    return halo
+
+
 def _stack_spmm_segments(plans, shards, n_shards,
                          pad_row: int) -> dict[str, np.ndarray]:
     """Pad/stack each shard's §4.3 segment launch tables on the leading
@@ -232,39 +238,36 @@ def _stack_spmm_segments(plans, shards, n_shards,
     onto local row ``pad_row`` (the last, so rows stay non-decreasing),
     pos −1 skips revaluation and length 0 fetches no B row."""
     seg_list = [_spmm_segment_arrays(p) for p in plans]
-    out: dict[str, np.ndarray] = {}
-    if "tc_seg_vals" in seg_list[0]:
-        ns = max(s["tc_seg_vals"].shape[0] for s in seg_list)
-        wbk = seg_list[0]["tc_seg_vals"].shape[-1]
-        vals = np.zeros((n_shards, ns, WINDOW, wbk), np.float32)
-        cols = np.zeros((n_shards, ns, wbk), np.int32)
-        pos = np.full((n_shards, ns, WINDOW, wbk), -1, np.int32)
-        row = np.full((n_shards, ns * WINDOW), pad_row, np.int32)
-        for p, (s, sh) in enumerate(zip(seg_list, shards)):
-            k = s["tc_seg_vals"].shape[0]
-            vals[p, :k] = s["tc_seg_vals"]
-            cols[p, :k] = s["tc_seg_cols"]
-            pos[p, :k] = _offset_pos(s["tc_seg_pos"], sh.nnz_start)
-            row[p, :k * WINDOW] = s["tc_seg_row"]
-        out.update(tc_seg_vals=vals, tc_seg_cols=cols, tc_seg_pos=pos,
-                   tc_seg_row=row)
-    if "vpu_seg_vals" in seg_list[0]:
-        ns = max(s["vpu_seg_row"].shape[0] for s in seg_list)
-        w = seg_list[0]["vpu_seg_vals"].shape[-1]
-        vals = np.zeros((n_shards, ns, w), np.float32)
-        cols = np.zeros((n_shards, ns, w), np.int32)
-        pos = np.full((n_shards, ns, w), -1, np.int32)
-        row = np.full((n_shards, ns), pad_row, np.int32)
-        seg_len = np.zeros((n_shards, ns), np.int32)
-        for p, (s, sh) in enumerate(zip(seg_list, shards)):
-            k = s["vpu_seg_row"].shape[0]
-            vals[p, :k] = s["vpu_seg_vals"]
-            cols[p, :k] = s["vpu_seg_cols"]
-            pos[p, :k] = _offset_pos(s["vpu_seg_pos"], sh.nnz_start)
-            row[p, :k] = s["vpu_seg_row"]
-            seg_len[p, :k] = s["vpu_seg_len"]
-        out.update(vpu_seg_vals=vals, vpu_seg_cols=cols, vpu_seg_pos=pos,
-                   vpu_seg_row=row, vpu_seg_len=seg_len)
+    ns = max(s["tc_seg_vals"].shape[0] for s in seg_list)
+    wbk = seg_list[0]["tc_seg_vals"].shape[-1]
+    vals = np.zeros((n_shards, ns, WINDOW, wbk), np.float32)
+    cols = np.zeros((n_shards, ns, wbk), np.int32)
+    pos = np.full((n_shards, ns, WINDOW, wbk), -1, np.int32)
+    row = np.full((n_shards, ns * WINDOW), pad_row, np.int32)
+    for p, (s, sh) in enumerate(zip(seg_list, shards)):
+        k = s["tc_seg_vals"].shape[0]
+        vals[p, :k] = s["tc_seg_vals"]
+        cols[p, :k] = s["tc_seg_cols"]
+        pos[p, :k] = _offset_pos(s["tc_seg_pos"], sh.nnz_start)
+        row[p, :k * WINDOW] = s["tc_seg_row"]
+    out = dict(tc_seg_vals=vals, tc_seg_cols=cols, tc_seg_pos=pos,
+               tc_seg_row=row)
+    ns = max(s["vpu_seg_row"].shape[0] for s in seg_list)
+    w = seg_list[0]["vpu_seg_vals"].shape[-1]
+    vals = np.zeros((n_shards, ns, w), np.float32)
+    cols = np.zeros((n_shards, ns, w), np.int32)
+    pos = np.full((n_shards, ns, w), -1, np.int32)
+    row = np.full((n_shards, ns), pad_row, np.int32)
+    seg_len = np.zeros((n_shards, ns), np.int32)
+    for p, (s, sh) in enumerate(zip(seg_list, shards)):
+        k = s["vpu_seg_row"].shape[0]
+        vals[p, :k] = s["vpu_seg_vals"]
+        cols[p, :k] = s["vpu_seg_cols"]
+        pos[p, :k] = _offset_pos(s["vpu_seg_pos"], sh.nnz_start)
+        row[p, :k] = s["vpu_seg_row"]
+        seg_len[p, :k] = s["vpu_seg_len"]
+    out.update(vpu_seg_vals=vals, vpu_seg_cols=cols, vpu_seg_pos=pos,
+               vpu_seg_row=row, vpu_seg_len=seg_len)
     return out
 
 
@@ -272,13 +275,11 @@ def _segment_load_meta(plans) -> dict[str, Any]:
     """Per-shard §4.3 segment counts (= kernel grid steps) — the load
     the segment-curve split balances."""
     def nseg(p):
-        tc = p.meta.get("tc_segments")
-        vpu = p.meta.get("vpu_segments")
-        n = (tc.nseg if tc is not None else 0) \
-            + (vpu.nseg if vpu is not None else 0)
+        n = p.meta["tc_segments"].nseg
+        vpu = p.meta["vpu_segments"]
         if vpu is None:  # SDDMM: flat element tiles grouped by seg_spt
-            n += -(-p.vpu.ntiles // int(p.meta.get("seg_spt", 1)))
-        return int(n)
+            return n + -(-p.vpu.ntiles // int(p.meta["seg_spt"]))
+        return n + vpu.nseg
 
     per = [nseg(p) for p in plans]
     mean = max(sum(per) / max(len(per), 1), 1e-9)
@@ -357,7 +358,7 @@ def partition_spmm(a: SparseCSR, n_shards: int, *, mode=UNSET,
     curve = segment_curve(
         a, op="spmm", threshold=spmm_threshold_for_mode(
             mode, forced if forced is not None else base.threshold),
-        bk=bk_c, seg_ts=seg_ts, seg_cs=seg_cs, ts_tile=ts_c, feat=feat)
+        bk=bk_c, seg_ts=seg_ts, seg_cs=seg_cs, feat=feat)
     raw = _make_shards(a, n_shards, weights=curve)
     shards, plans = [], []
     for p, w0, w1, r0, r1, halo, sub, nz0, nz1 in raw:
@@ -372,47 +373,6 @@ def partition_spmm(a: SparseCSR, n_shards: int, *, mode=UNSET,
 
     wmax = max(1, max(s.win_end - s.win_start for s in shards))
     rows_pad = wmax * WINDOW
-    na = max(p.tc.n_active for p in plans)
-    nb = max(p.tc.nblk + (na - p.tc.n_active) for p in plans)
-    nt = max(p.vpu.ntiles for p in plans)
-    hmax = max(1, max(int(s.halo.size) for s in shards))
-
-    tc_vals = np.zeros((n_shards, nb, WINDOW, bk_c), np.float32)
-    tc_cols = np.zeros((n_shards, nb, bk_c), np.int32)
-    tc_rank = np.zeros((n_shards, nb), np.int32)
-    tc_pos = np.full((n_shards, nb, WINDOW, bk_c), -1, np.int32)
-    # Row maps pad with the last local row: padding adds zeros there and
-    # keeps every map non-decreasing (the combine's sorted scatters).
-    tc_active_row = np.full((n_shards, na * WINDOW), rows_pad - 1, np.int32)
-    vpu_vals = np.zeros((n_shards, nt, ts_c), np.float32)
-    vpu_cols = np.zeros((n_shards, nt, ts_c), np.int32)
-    vpu_row = np.full((n_shards, nt), rows_pad - 1, np.int32)
-    vpu_pos = np.full((n_shards, nt, ts_c), -1, np.int32)
-    halo_arr = np.zeros((n_shards, hmax), np.int32)
-
-    for p, (shard, plan) in enumerate(zip(shards, plans)):
-        tc, vpu = plan.tc, plan.vpu
-        nblk, nact = tc.nblk, tc.n_active
-        tc_vals[p, :nblk] = tc.vals
-        tc_cols[p, :nblk] = tc.cols
-        tc_pos[p, :nblk] = _offset_pos(tc.pos, shard.nnz_start)
-        # Real ranks, then one dummy block per missing rank (so the
-        # Pallas kernel writes every compacted output block), then
-        # repeat the last rank (accumulates zeros).
-        rank_pad = np.full(nb, na - 1, np.int32)
-        rank_pad[:nblk] = tc.rank
-        rank_pad[nblk:nblk + (na - nact)] = np.arange(nact, na, dtype=np.int32)
-        tc_rank[p] = rank_pad
-        active_rows = (tc.active_win[:, None].astype(np.int64) * WINDOW
-                       + np.arange(WINDOW)[None, :]).reshape(-1)
-        tc_active_row[p, :nact * WINDOW] = active_rows
-        ntl = vpu.ntiles
-        vpu_vals[p, :ntl] = vpu.vals
-        vpu_cols[p, :ntl] = vpu.cols
-        vpu_row[p, :ntl] = vpu.row
-        vpu_pos[p, :ntl] = _offset_pos(vpu.pos, shard.nnz_start)
-        halo_arr[p, :shard.halo.size] = shard.halo
-
     out_gather = np.zeros(a.m, np.int32)
     for shard in shards:
         rr = np.arange(shard.rows)
@@ -423,11 +383,7 @@ def partition_spmm(a: SparseCSR, n_shards: int, *, mode=UNSET,
         # runtime cost — same single take as before.
         out_gather = out_gather[reord.row_inv]
 
-    host = dict(
-        tc_vals=tc_vals, tc_cols=tc_cols, tc_rank=tc_rank,
-        tc_active_row=tc_active_row, tc_pos=tc_pos,
-        vpu_vals=vpu_vals, vpu_cols=vpu_cols, vpu_row=vpu_row,
-        vpu_pos=vpu_pos, halo=halo_arr)
+    host = dict(halo=_stack_halos(shards))
     host.update(_stack_spmm_segments(plans, shards, n_shards, rows_pad - 1))
     stacked = {k: jnp.asarray(v) for k, v in host.items()}
     meta = {
@@ -456,37 +412,34 @@ def _stack_sddmm_segments(plans, n_shards) -> dict[str, np.ndarray]:
     reassembles) — padding carries bitmap 0 / mask False and pos −1/0,
     which the swallow slot absorbs."""
     seg_list = [_sddmm_segment_arrays(p) for p in plans]
-    out: dict[str, np.ndarray] = {}
-    if "tc_seg_cols" in seg_list[0]:
-        ns = max(s["tc_seg_window"].shape[0] for s in seg_list)
-        wbk = seg_list[0]["tc_seg_cols"].shape[-1]
-        cols = np.zeros((n_shards, ns, wbk), np.int32)
-        bitmap = np.zeros((n_shards, ns, wbk), np.uint32)
-        win = np.zeros((n_shards, ns), np.int32)
-        opos = np.full((n_shards, ns, WINDOW, wbk), -1, np.int32)
-        for p, s in enumerate(seg_list):
-            k = s["tc_seg_window"].shape[0]
-            cols[p, :k] = s["tc_seg_cols"]
-            bitmap[p, :k] = s["tc_seg_bitmap"]
-            win[p, :k] = s["tc_seg_window"]
-            opos[p, :k] = s["tc_seg_out_pos"]
-        out.update(tc_seg_cols=cols, tc_seg_bitmap=bitmap,
-                   tc_seg_window=win, tc_seg_out_pos=opos)
-    if "vpu_seg_rows" in seg_list[0]:
-        ns = max(s["vpu_seg_rows"].shape[0] for s in seg_list)
-        w = seg_list[0]["vpu_seg_rows"].shape[-1]
-        rows = np.zeros((n_shards, ns, w), np.int32)
-        cols = np.zeros((n_shards, ns, w), np.int32)
-        opos = np.zeros((n_shards, ns, w), np.int32)
-        mask = np.zeros((n_shards, ns, w), bool)
-        for p, s in enumerate(seg_list):
-            k = s["vpu_seg_rows"].shape[0]
-            rows[p, :k] = s["vpu_seg_rows"]
-            cols[p, :k] = s["vpu_seg_cols"]
-            opos[p, :k] = s["vpu_seg_out_pos"]
-            mask[p, :k] = s["vpu_seg_mask"]
-        out.update(vpu_seg_rows=rows, vpu_seg_cols=cols,
-                   vpu_seg_out_pos=opos, vpu_seg_mask=mask)
+    ns = max(s["tc_seg_window"].shape[0] for s in seg_list)
+    wbk = seg_list[0]["tc_seg_cols"].shape[-1]
+    cols = np.zeros((n_shards, ns, wbk), np.int32)
+    bitmap = np.zeros((n_shards, ns, wbk), np.uint32)
+    win = np.zeros((n_shards, ns), np.int32)
+    opos = np.full((n_shards, ns, WINDOW, wbk), -1, np.int32)
+    for p, s in enumerate(seg_list):
+        k = s["tc_seg_window"].shape[0]
+        cols[p, :k] = s["tc_seg_cols"]
+        bitmap[p, :k] = s["tc_seg_bitmap"]
+        win[p, :k] = s["tc_seg_window"]
+        opos[p, :k] = s["tc_seg_out_pos"]
+    out = dict(tc_seg_cols=cols, tc_seg_bitmap=bitmap, tc_seg_window=win,
+               tc_seg_out_pos=opos)
+    ns = max(s["vpu_seg_rows"].shape[0] for s in seg_list)
+    w = seg_list[0]["vpu_seg_rows"].shape[-1]
+    rows = np.zeros((n_shards, ns, w), np.int32)
+    cols = np.zeros((n_shards, ns, w), np.int32)
+    opos = np.zeros((n_shards, ns, w), np.int32)
+    mask = np.zeros((n_shards, ns, w), bool)
+    for p, s in enumerate(seg_list):
+        k = s["vpu_seg_rows"].shape[0]
+        rows[p, :k] = s["vpu_seg_rows"]
+        cols[p, :k] = s["vpu_seg_cols"]
+        opos[p, :k] = s["vpu_seg_out_pos"]
+        mask[p, :k] = s["vpu_seg_mask"]
+    out.update(vpu_seg_rows=rows, vpu_seg_cols=cols, vpu_seg_out_pos=opos,
+               vpu_seg_mask=mask)
     return out
 
 
@@ -550,7 +503,7 @@ def partition_sddmm(a: SparseCSR, n_shards: int, *, mode=UNSET,
     curve = segment_curve(
         a, op="sddmm", threshold=sddmm_threshold_for_mode(
             mode, bk_c, forced if forced is not None else base.threshold),
-        bk=bk_c, seg_ts=seg_ts, seg_cs=seg_cs, ts_tile=ts_c, feat=feat)
+        bk=bk_c, seg_ts=seg_ts, seg_cs=seg_cs, feat=feat)
     raw = _make_shards(a, n_shards, weights=curve)
     shards, plans = [], []
     for p, w0, w1, r0, r1, halo, sub, nz0, nz1 in raw:
@@ -565,32 +518,7 @@ def partition_sddmm(a: SparseCSR, n_shards: int, *, mode=UNSET,
 
     wmax = max(1, max(s.win_end - s.win_start for s in shards))
     rows_pad = wmax * WINDOW
-    nb = max(p.tc.nblk for p in plans)
-    ntl = max(p.vpu.ntiles for p in plans)
-    hmax = max(1, max(int(s.halo.size) for s in shards))
     nnz_pad = max(1, max(s.nnz for s in shards))
-
-    tc_cols = np.zeros((n_shards, nb, bk_c), np.int32)
-    tc_bitmap = np.zeros((n_shards, nb, bk_c), np.uint32)
-    tc_window = np.zeros((n_shards, nb), np.int32)
-    tc_out_pos = np.full((n_shards, nb, WINDOW, bk_c), -1, np.int32)
-    vpu_rows = np.zeros((n_shards, ntl, ts_c), np.int32)
-    vpu_cols = np.zeros((n_shards, ntl, ts_c), np.int32)
-    vpu_out_pos = np.zeros((n_shards, ntl, ts_c), np.int32)
-    vpu_mask = np.zeros((n_shards, ntl, ts_c), bool)
-    halo_arr = np.zeros((n_shards, hmax), np.int32)
-
-    for p, (shard, plan) in enumerate(zip(shards, plans)):
-        tc, vpu = plan.tc, plan.vpu
-        tc_cols[p, :tc.nblk] = tc.cols
-        tc_bitmap[p, :tc.nblk] = tc.bitmap
-        tc_window[p, :tc.nblk] = tc.window
-        tc_out_pos[p, :tc.nblk] = plan.tc_out_pos  # shard-local positions
-        vpu_rows[p, :vpu.ntiles] = vpu.rows
-        vpu_cols[p, :vpu.ntiles] = vpu.cols
-        vpu_out_pos[p, :vpu.ntiles] = vpu.out_pos
-        vpu_mask[p, :vpu.ntiles] = vpu.mask
-        halo_arr[p, :shard.halo.size] = shard.halo
 
     x_take = np.zeros(n_shards * rows_pad, np.int32)
     nnz_gather = np.zeros(a.nnz, np.int32)
@@ -607,10 +535,7 @@ def partition_sddmm(a: SparseCSR, n_shards: int, *, mode=UNSET,
         x_take = reord.row_perm.astype(np.int32)[x_take]
         nnz_gather = nnz_gather[reord.nnz_inv]
 
-    host = dict(
-        tc_cols=tc_cols, tc_bitmap=tc_bitmap, tc_window=tc_window,
-        tc_out_pos=tc_out_pos, vpu_rows=vpu_rows, vpu_cols=vpu_cols,
-        vpu_out_pos=vpu_out_pos, vpu_mask=vpu_mask, halo=halo_arr)
+    host = dict(halo=_stack_halos(shards))
     host.update(_stack_sddmm_segments(plans, n_shards))
     stacked = {k: jnp.asarray(v) for k, v in host.items()}
     meta = {

@@ -204,9 +204,9 @@ class BatchedSpMM:
                 out = jnp.take(out, unperm, axis=1)
             return out
 
-        # Lazy backend view; with edge_vals the revalue maps replace
-        # the baked-in value tensors (rebuilt in-trace per panel).
-        arrs = op.arrays.for_backend(backend, revalue=has_ev)
+        # Lazy view; with edge_vals the revalue maps replace the
+        # baked-in value tensors (rebuilt in-trace per panel).
+        arrs = op.arrays.apply_arrays(revalue=has_ev)
         args = (arrs, b_stack) + ((edge_vals,) if has_ev else ())
         fn = cached_compile(
             self._cache,
@@ -243,7 +243,7 @@ class BatchedSDDMM:
             return sddmm_apply_stack(arrs, xx, yy, nnz=op.nnz,
                                      backend=backend, cfg=op.tune_config)
 
-        arrs = op.arrays.for_backend(backend)
+        arrs = op.arrays.apply_arrays()
         fn = cached_compile(
             self._cache,
             (x_stack.shape, y_stack.shape, str(x_stack.dtype), backend),

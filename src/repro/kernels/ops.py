@@ -35,14 +35,13 @@ passes:
    sorted scatters (plans keep windows and rows non-decreasing). SDDMM
    combines both streams' scores with a single scatter into the
    canonical nnz vector (multi-head: a gather by the inverse map).
-4. **Segment-granular launch (§4.3).** Plans carrying the hybrid
-   balancer's Ts/Cs launch tables (``*_seg_*`` device arrays — the
-   default) run the kernels one *segment* per grid step: bounded work
-   per step no matter how skewed the matrix, and the combine is exactly
-   where atomic segments (decomposed windows/rows, shared windows)
-   accumulate while non-atomic ones degenerate to stores.
-   ``TuneConfig(ts=0, cs=0)`` falls back to the per-block/per-tile
-   launch.
+4. **Segment-granular launch (§4.3).** A plan's device arrays are the
+   hybrid balancer's Ts/Cs launch tables (``*_seg_*``), and the kernels
+   run one *segment* per grid step: bounded work per step no matter
+   how skewed the matrix, and the combine is exactly where atomic
+   segments (decomposed windows/rows, shared windows) accumulate while
+   non-atomic ones degenerate to stores. One block (``ts=1``) or one
+   tile (``cs=ts_tile``) per segment is the per-block/per-tile launch.
 
 5. **Heads.** Edge values may carry a head axis, ``(nnz, H)``, against
    dense operands of ``H·c`` columns that hold the heads contiguously
@@ -176,14 +175,13 @@ def _pad_to(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
 
 #: A plan's value tensors: a revalued plan gives them a trailing head
 #: axis when its edge values carry one.
-_VALUE_KEYS = ("tc_vals", "vpu_vals", "tc_seg_vals", "vpu_seg_vals")
+_VALUE_KEYS = ("tc_seg_vals", "vpu_seg_vals")
 
 
 def _plan_heads(arrs) -> int | None:
     """Heads of a plan's values: ``H`` when its VPU value tensor carries
-    a trailing head axis (``(tiles, width, H)``), else ``None``."""
-    vals = arrs["vpu_seg_vals"] if "vpu_seg_vals" in arrs \
-        else arrs["vpu_vals"]
+    a trailing head axis (``(segments, width, H)``), else ``None``."""
+    vals = arrs["vpu_seg_vals"]
     return vals.shape[2] if vals.ndim == 3 else None
 
 
@@ -222,55 +220,37 @@ def spmm_apply(arrs, b, *, m: int, nwin: int, backend: str = "xla",
     order = "block_outer" if n0 > nt else "n_outer"
     with jax.named_scope("mxu"):
         b_p = _pad_to(b, 1, nt)
-        if "tc_seg_vals" in arrs:
-            # Segment-granular launch (§4.3 Ts decomposition): one grid
-            # step per segment of ≤ ts blocks of one window.
-            tc = spmm_mxu(arrs["tc_seg_vals"], arrs["tc_seg_cols"], b_p,
-                          nt=nt, grid_order=order,
-                          head_dim=head_dim, interpret=interpret)
-        else:
-            tc = spmm_mxu(arrs["tc_vals"], arrs["tc_cols"], b_p, nt=nt,
-                          grid_order=order, head_dim=head_dim,
-                          interpret=interpret)
+        # One grid step per segment of ≤ ts blocks of one window (§4.3
+        # Ts decomposition).
+        tc = spmm_mxu(arrs["tc_seg_vals"], arrs["tc_seg_cols"], b_p, nt=nt,
+                      grid_order=order, head_dim=head_dim,
+                      interpret=interpret)
     with jax.named_scope("vpu"):
-        if "vpu_seg_vals" in arrs:
-            # §4.3 Cs decomposition: one row-segment of ≤ cs residual
-            # elements per tile (same kernel, wider tiles); a table with
-            # segment lengths fetches B rows for real elements only.
-            partials = spmm_vpu(arrs["vpu_seg_vals"], arrs["vpu_seg_cols"],
-                                b_p, arrs.get("vpu_seg_len"), nt=nt,
-                                grid_order=order,
-                                head_dim=head_dim, interpret=interpret)
-            vpu_rows = arrs["vpu_seg_row"]
-        else:
-            partials = spmm_vpu(arrs["vpu_vals"], arrs["vpu_cols"], b_p,
-                                nt=nt, grid_order=order,
-                                head_dim=head_dim, interpret=interpret)
-            vpu_rows = arrs["vpu_row"]
+        # §4.3 Cs decomposition: one row-segment of ≤ cs residual
+        # elements per tile; the segment lengths fetch B rows for real
+        # elements only.
+        partials = spmm_vpu(arrs["vpu_seg_vals"], arrs["vpu_seg_cols"], b_p,
+                            arrs["vpu_seg_len"], nt=nt, grid_order=order,
+                            head_dim=head_dim, interpret=interpret)
     # Fused combine epilogue: the TC partials sum into their windows of
     # a zero C, then one scatter-add lays the VPU partials over it (rows
-    # ≥ m from the padded last window are sliced off). Under the
-    # segmented launch this is where atomic segments combine: non-atomic
-    # segments own their rows exclusively (the add is a store), atomic
-    # ones — decomposed windows/rows or TC∩VPU windows — deterministically
-    # accumulate in segment order, the TPU analogue of the paper's
-    # invoke-atomicAdd-only-when-necessary rule. Plans keep block windows
-    # and VPU rows non-decreasing (padding repeats the last row), so both
-    # sums are sorted scatters — an unsorted one makes the TPU compiler
-    # sort the updates, tens of seconds of compile at graph scale.
+    # ≥ m from the padded last window are sliced off). This is where
+    # atomic segments combine: non-atomic segments own their rows
+    # exclusively (the add is a store), atomic ones — decomposed
+    # windows/rows or TC∩VPU windows — deterministically accumulate in
+    # segment order, the TPU analogue of the paper's
+    # invoke-atomicAdd-only-when-necessary rule. Plans keep segment
+    # windows and rows non-decreasing (padding repeats the last row), so
+    # both sums are sorted scatters — an unsorted one makes the TPU
+    # compiler sort the updates, tens of seconds of compile at graph
+    # scale.
     with jax.named_scope("combine"):
-        if "tc_seg_vals" in arrs:
-            tc_win = arrs["tc_seg_row"][::WINDOW] // WINDOW
-        else:
-            # Per-block launch: each block's 8 output rows are its
-            # window's (compacted rank → window); blocks sharing a window
-            # add up here.
-            tc_win = (arrs["tc_active_row"][::WINDOW]
-                      // WINDOW)[arrs["tc_rank"]]
+        tc_win = arrs["tc_seg_row"][::WINDOW] // WINDOW
         out = jax.ops.segment_sum(
             tc.reshape(-1, WINDOW, tc.shape[-1]), tc_win, num_segments=nwin,
             indices_are_sorted=True).reshape(nwin * WINDOW, -1)
-        out = out.at[vpu_rows].add(partials, indices_are_sorted=True)
+        out = out.at[arrs["vpu_seg_row"]].add(partials,
+                                              indices_are_sorted=True)
         return out[:m, :n0]
 
 
@@ -353,43 +333,27 @@ def sddmm_apply(arrs, x, y, *, nnz: int, backend: str = "xla",
         x = _pad_to(x, 1, kft)
         y = _pad_to(y, 1, kft)
         x_p = _pad_to(x, 0, WINDOW)
-        if "tc_seg_cols" in arrs:
-            # §4.3 Ts decomposition: one grid step scores a whole segment
-            # of ≤ ts blocks sharing a window — one 8×kf @ kf×(ts·bk)
-            # dot, bitmap-sampled (zero bitmap padding samples to zero
-            # and its out_pos −1 lands in the scatter's swallow slot).
-            s_tc = sddmm_mxu(arrs["tc_seg_cols"], arrs["tc_seg_bitmap"],
-                             arrs["tc_seg_window"], x_p, y, kf_tile=kft,
-                             heads=heads, head_dim=head_dim,
-                             interpret=interpret)
-            tc_pos_src = arrs["tc_seg_out_pos"]
-        else:
-            s_tc = sddmm_mxu(arrs["tc_cols"], arrs["tc_bitmap"],
-                             arrs["tc_window"], x_p, y, kf_tile=kft,
-                             heads=heads, head_dim=head_dim,
-                             interpret=interpret)
-            tc_pos_src = arrs["tc_out_pos"]
+        # §4.3 Ts decomposition: one grid step scores a whole segment of
+        # ≤ ts blocks sharing a window — one 8×kf @ kf×(ts·bk) dot,
+        # bitmap-sampled (zero bitmap padding samples to zero and its
+        # out_pos −1 lands in the scatter's swallow slot).
+        s_tc = sddmm_mxu(arrs["tc_seg_cols"], arrs["tc_seg_bitmap"],
+                         arrs["tc_seg_window"], x_p, y, kf_tile=kft,
+                         heads=heads, head_dim=head_dim, interpret=interpret)
     with jax.named_scope("vpu"):
-        if "vpu_seg_rows" in arrs:
-            # Cs cap batches whole element tiles per VPU grid step.
-            vpu_mask = arrs["vpu_seg_mask"]
-            s_el = sddmm_vpu(arrs["vpu_seg_rows"], arrs["vpu_seg_cols"], x,
-                             y, kf_tile=kft, heads=heads, head_dim=head_dim,
-                             interpret=interpret)
-            el_pos_src = arrs["vpu_seg_out_pos"]
-        else:
-            vpu_mask = arrs["vpu_mask"]
-            s_el = sddmm_vpu(arrs["vpu_rows"], arrs["vpu_cols"], x, y,
-                             kf_tile=kft, heads=heads, head_dim=head_dim,
-                             interpret=interpret)
-            el_pos_src = arrs["vpu_out_pos"]
+        # The Cs cap batches whole element tiles per VPU grid step.
+        vpu_mask = arrs["vpu_seg_mask"]
+        s_el = sddmm_vpu(arrs["vpu_seg_rows"], arrs["vpu_seg_cols"], x, y,
+                         kf_tile=kft, heads=heads, head_dim=head_dim,
+                         interpret=interpret)
     # Fused combine: one scatter of both streams into the canonical nnz
     # vector (slot nnz swallows -1/masked padding).
     with jax.named_scope("combine"):
         if not heads:
             s_el = jnp.where(vpu_mask, s_el, 0.0)
-        pos_tc = jnp.where(tc_pos_src >= 0, tc_pos_src, nnz)
-        pos_el = jnp.where(vpu_mask, el_pos_src, nnz)
+        tc_pos = arrs["tc_seg_out_pos"]
+        pos_tc = jnp.where(tc_pos >= 0, tc_pos, nnz)
+        pos_el = jnp.where(vpu_mask, arrs["vpu_seg_out_pos"], nnz)
         pos = jnp.concatenate([pos_tc.reshape(-1), pos_el.reshape(-1)])
         if heads:
             # Each non-zero's scores sit at exactly one output position,

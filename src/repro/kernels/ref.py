@@ -9,8 +9,11 @@ These define the semantics the kernels must reproduce exactly (allclose):
 * TC/MXU SDDMM path: per block ``S = X[win] @ Y[cols]ᵀ`` sampled by bitmap.
 * VPU SDDMM path: per element ``s = ⟨X[row], Y[col]⟩``.
 
-The same functions serve as the fast XLA backend on CPU (interpret-mode
-Pallas is a correctness tool, not a CPU performance path).
+The hybrid references (:func:`spmm_hybrid_ref`, :func:`sddmm_hybrid_ref`)
+run these semantics on the kernels' own §4.3 segment tables — a segment
+is a block (or tile) of ``ts·bk`` (or ``cs``) columns — and serve as
+the fast XLA backend on CPU (interpret-mode Pallas is a correctness
+tool, not a CPU performance path).
 
 Multi-head: edge values of shape ``(nnz, H)`` against dense operands
 whose ``H·c`` columns hold the heads contiguously (head ``h`` owns
@@ -27,19 +30,22 @@ import numpy as np
 from repro.core.formats import WINDOW
 
 
+def _tc_partials(tc_vals, gathered):
+    """Per-block ``8 × n`` partials ``vals @ B[cols]`` from the gathered
+    rows ``(nb, bk, n)``; values ``(nb, 8, bk)`` or, with a head axis,
+    ``(nb, 8, bk, H)`` (each head's columns apart)."""
+    if tc_vals.ndim == 3:
+        return jnp.einsum("bsk,bkn->bsn", tc_vals, gathered)
+    nb, bk, n = gathered.shape
+    gathered = gathered.reshape(nb, bk, tc_vals.shape[3], -1)
+    return jnp.einsum("bskh,bkhc->bshc", tc_vals,
+                      gathered).reshape(nb, WINDOW, n)
+
+
 def spmm_tc_compact_ref(tc_vals, tc_cols, tc_rank, b, n_active):
     """Compacted-layout oracle for :func:`repro.kernels.spmm_mxu.spmm_mxu`:
-    ``(n_active*8, n)`` — one 8-row slab per TC-*active* window rank.
-    (The pre-compaction full-dense layout was ``rank → window`` with
-    ``n_active → nwin``; the kernel no longer produces it.)"""
-    gathered = jnp.take(b, tc_cols, axis=0)  # (nb, bk, n)
-    if tc_vals.ndim == 4:   # (nb, 8, bk, H): each head's columns apart
-        nb, bk, n = gathered.shape
-        gathered = gathered.reshape(nb, bk, tc_vals.shape[3], -1)
-        partial = jnp.einsum("bskh,bkhc->bshc", tc_vals,
-                             gathered).reshape(nb, WINDOW, n)
-    else:
-        partial = jnp.einsum("bsk,bkn->bsn", tc_vals, gathered)  # (nb, 8, n)
+    ``(n_active*8, n)`` — one 8-row slab per TC-*active* window rank."""
+    partial = _tc_partials(tc_vals, jnp.take(b, tc_cols, axis=0))
     out = jax.ops.segment_sum(partial, tc_rank, num_segments=n_active,
                               indices_are_sorted=True)
     return out.reshape(n_active * WINDOW, b.shape[1])
@@ -56,27 +62,22 @@ def _vpu_partials(vpu_vals, gathered):
     return jnp.einsum("tjh,tjhc->thc", vpu_vals, gathered).reshape(nt, n)
 
 
-def spmm_vpu_ref(vpu_vals, vpu_cols, vpu_row, b, m):
-    """(nt,ts)×(nt,ts) → rows of (m, n)."""
-    gathered = jnp.take(b, vpu_cols, axis=0)  # (nt, ts, n)
-    partial = _vpu_partials(vpu_vals, gathered)  # (nt, n)
-    return jax.ops.segment_sum(partial, vpu_row, num_segments=m,
-                               indices_are_sorted=True)
-
-
 def spmm_hybrid_ref(arrs, b, m, nwin):
-    """Single-pass hybrid reference mirroring the fused Pallas epilogue:
-    compacted TC partials, then VPU tile partials, scatter-added into C.
-    Ranks, active rows and VPU rows are non-decreasing in every plan, so
-    each scatter is a sorted one."""
-    tc_rows = arrs["tc_active_row"]
-    tc = spmm_tc_compact_ref(arrs["tc_vals"], arrs["tc_cols"],
-                             arrs["tc_rank"], b, tc_rows.shape[0] // WINDOW)
-    gathered = jnp.take(b, arrs["vpu_cols"], axis=0)  # (nt, ts, n)
-    partials = _vpu_partials(arrs["vpu_vals"], gathered)
-    out = jnp.zeros((nwin * WINDOW, b.shape[1]), tc.dtype)
-    out = out.at[tc_rows].add(tc, indices_are_sorted=True)
-    return out.at[arrs["vpu_row"]].add(partials, indices_are_sorted=True)[:m]
+    """Single-pass hybrid reference on the segment tables, mirroring
+    the fused Pallas epilogue: each MXU segment's ``8 × n`` partial sums
+    into its window of C, then the VPU segments' partial rows
+    scatter-add over it. Segment windows and rows are non-decreasing in
+    every plan, so both sums are sorted ones."""
+    tc = _tc_partials(arrs["tc_seg_vals"],
+                      jnp.take(b, arrs["tc_seg_cols"], axis=0))
+    partials = _vpu_partials(arrs["vpu_seg_vals"],
+                             jnp.take(b, arrs["vpu_seg_cols"], axis=0))
+    tc_win = arrs["tc_seg_row"][::WINDOW] // WINDOW
+    out = jax.ops.segment_sum(tc, tc_win, num_segments=nwin,
+                              indices_are_sorted=True)
+    out = out.reshape(nwin * WINDOW, b.shape[1])
+    return out.at[arrs["vpu_seg_row"]].add(partials,
+                                           indices_are_sorted=True)[:m]
 
 
 def bitmap_mask(bitmap):
@@ -123,15 +124,17 @@ def sddmm_vpu_ref(rows, cols, mask, x, y, heads=None):
 
 
 def sddmm_hybrid_ref(arrs, x, y, nnz, heads=None):
-    """Hybrid SDDMM producing the canonical nnz-ordered value vector,
-    ``(nnz, H)`` with ``heads`` (single fused scatter; slot nnz swallows
-    -1/masked padding)."""
-    s_tc = sddmm_tc_ref(arrs["tc_cols"], arrs["tc_bitmap"], arrs["tc_window"],
-                        x, y, heads)
-    s_el = sddmm_vpu_ref(arrs["vpu_rows"], arrs["vpu_cols"], arrs["vpu_mask"],
+    """Hybrid SDDMM on the segment tables, producing the canonical
+    nnz-ordered value vector, ``(nnz, H)`` with ``heads`` (single fused
+    scatter; slot nnz swallows -1/masked padding)."""
+    s_tc = sddmm_tc_ref(arrs["tc_seg_cols"], arrs["tc_seg_bitmap"],
+                        arrs["tc_seg_window"], x, y, heads)
+    mask = arrs["vpu_seg_mask"]
+    s_el = sddmm_vpu_ref(arrs["vpu_seg_rows"], arrs["vpu_seg_cols"], mask,
                          x, y, heads)
-    pos_tc = jnp.where(arrs["tc_out_pos"] >= 0, arrs["tc_out_pos"], nnz)
-    pos_el = jnp.where(arrs["vpu_mask"], arrs["vpu_out_pos"], nnz)
+    tc_pos = arrs["tc_seg_out_pos"]
+    pos_tc = jnp.where(tc_pos >= 0, tc_pos, nnz)
+    pos_el = jnp.where(mask, arrs["vpu_seg_out_pos"], nnz)
     pos = jnp.concatenate([pos_tc.reshape(-1), pos_el.reshape(-1)])
     tail = (heads,) if heads else ()
     data = jnp.concatenate([s_tc.reshape((-1,) + tail),
@@ -141,12 +144,14 @@ def sddmm_hybrid_ref(arrs, x, y, nnz, heads=None):
 
 
 def revalue_spmm_arrays(arrs, edge_vals):
-    """Rebuild plan value tensors from a runtime per-edge value vector.
+    """Rebuild a plan's segment value tensors from a runtime per-edge
+    value vector.
 
     The sparsity pattern (and hence the whole Libra plan) is fixed; only
     values change — e.g. GNN attention weights per step. ``edge_vals``
     follows canonical CSR nnz order: ``(nnz,)``, or ``(nnz, H)`` with one
     value per head, which gives each value tensor a trailing head axis.
+    The position maps are −1 on padding, which reads as 0.
     """
     def from_pos(pos):
         if edge_vals.ndim == 2:
@@ -159,18 +164,8 @@ def revalue_spmm_arrays(arrs, edge_vals):
         ).astype(jnp.float32)
 
     out = dict(arrs)
-    # Lazy backend views may omit compact pos maps when only the
-    # segment stream is served (see PlanArrays.for_backend).
-    if "tc_pos" in arrs:
-        out["tc_vals"] = from_pos(arrs["tc_pos"])
-    if "vpu_pos" in arrs:
-        out["vpu_vals"] = from_pos(arrs["vpu_pos"])
-    # Segment-granular launch tables (§4.3) carry their own value
-    # tensors; their pos maps are −1 on padding, which from_pos zeroes.
-    if "tc_seg_pos" in arrs:
-        out["tc_seg_vals"] = from_pos(arrs["tc_seg_pos"])
-    if "vpu_seg_pos" in arrs:
-        out["vpu_seg_vals"] = from_pos(arrs["vpu_seg_pos"])
+    out["tc_seg_vals"] = from_pos(arrs["tc_seg_pos"])
+    out["vpu_seg_vals"] = from_pos(arrs["vpu_seg_pos"])
     return out
 
 

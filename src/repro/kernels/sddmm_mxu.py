@@ -15,7 +15,7 @@ is tiled (``kf_tile``, the fastest grid axis) with the output block as
 the accumulator, so arbitrarily wide embeddings fit; the bitmap sample
 is applied on the last feature tile.
 
-**Segment-granular launch (§4.3 Ts decomposition).** The preferred
+**Segment-granular launch (§4.3 Ts decomposition).** The
 operand layout is the hybrid balancer's segment table: one grid step
 scores a whole segment of ≤ ``Ts`` blocks sharing a window — ``bk``
 becomes ``ts·bk`` concatenated condensed vectors, the step is a single

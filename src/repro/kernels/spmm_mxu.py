@@ -16,16 +16,16 @@ TPU adaptation of the paper's TCU stream (§4.4):
   block, so no output block is ever revisited and both grid orders are
   always legal; the caller's fused scatter-add maps each block's 8 rows
   to its window's rows of C (and sums blocks that share a window).
-* **Segment-granular launch (§4.3 Ts decomposition).** The preferred
-  operand layout is the hybrid balancer's segment table: a "block" is a
+* **Segment-granular launch (§4.3 Ts decomposition).** The operand
+  layout ops.py passes is the hybrid balancer's segment table: a "block" is a
   *segment* of ≤ ``Ts`` condensed blocks of a single window, flattened
   to an ``(8, ts·bk)`` operand (the sum of per-block ``8×bk @ bk×nt``
   products is one ``8×(ts·bk) @ (ts·bk)×nt`` product). Per-step work is
   bounded by ``Ts`` no matter how long a power-law window is. Segments
   marked ``atomic`` (decomposed windows, or windows shared with the VPU
   path) share scatter rows with another producer; non-atomic segments
-  own their rows, so the add degenerates to a store for them. The
-  per-block (unsegmented) table runs through the same kernel.
+  own their rows, so the add degenerates to a store for them. A table
+  of one block per segment (``ts=1``) is the per-block launch.
 
 **Heads.** With ``H`` heads of width ``c`` (the layout of
 :mod:`repro.kernels.gather`) a block carries one ``8×bk`` value matrix

@@ -13,8 +13,8 @@ segment tables' ``vpu_seg_len``) it fetches rows for the real elements
 only, so B traffic is ``nnz · nt`` per lane tile; without them it is
 ``padded nnz · nt``. Either way it is independent of ``k``.
 
-**Segment-granular launch (§4.3 Cs decomposition).** The preferred
-operand layout is the hybrid balancer's segment table: a "tile" is a
+**Segment-granular launch (§4.3 Cs decomposition).** The operand
+layout ops.py passes is the hybrid balancer's segment table: a "tile" is a
 *segment* of ≤ ``Cs`` residual elements (whole tiles) of a single row —
 the same kernel, a wider tile — so long power-law rows are split across
 bounded grid steps and short rows don't pad up to the cap. Segments

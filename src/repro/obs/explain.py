@@ -80,20 +80,20 @@ def plan_counts(plan, kind: str) -> dict:
     the VPU stream's real elements, its tiles (the segments the kernel
     launches over), their width ``cs`` and their slots (tiles × ``cs``).
     ``vpu_fetches`` is the B rows the VPU kernel fetches a lane tile:
-    the sum of the segment lengths it launches with, or every slot for
-    a plan without a ``vpu_seg_len`` table.
+    the sum of the SpMM segment lengths it launches with, every slot for
+    SDDMM.
     :func:`_padding_report` derives the padding from these, and the
     ``preprocess.spmm``/``preprocess.sddmm`` spans of an enabled tracer
     carry them as attributes."""
     from repro.core.formats import spmm_vpu_seg_len
 
     tc, vpu = plan.tc, plan.vpu
-    seg_len = None
     if kind == "spmm":
         slots, vpu_nnz = vpu.vals, int(vpu.nnz)
-        seg_len = spmm_vpu_seg_len(plan)
+        fetches = int(spmm_vpu_seg_len(plan).sum())
     else:  # COOTiles: mask marks real elements
         slots, vpu_nnz = vpu.mask, int(vpu.mask.sum())
+        fetches = int(slots.size)
     return {
         "tc_nnz": int(tc.nnz),
         "mxu_blocks": int(tc.cols.shape[0]),
@@ -103,8 +103,7 @@ def plan_counts(plan, kind: str) -> dict:
         "vpu_segments": int(slots.shape[0]),
         "cs": int(slots.shape[1]),
         "vpu_slots": int(slots.size),
-        "vpu_fetches": (int(slots.size) if seg_len is None
-                        else int(seg_len.sum())),
+        "vpu_fetches": fetches,
     }
 
 

@@ -48,7 +48,7 @@ admitted rid to its result **or** a typed
 discards the results of buckets that already executed. With a
 :class:`~repro.serve.resilience.ResiliencePolicy` (the default), an
 executable failure walks the degradation ladder
-``fast → single → unsegmented → xla`` with capped-backoff retries (the
+``fast → single → xla`` with capped-backoff retries (the
 ``single`` rung re-executes the chunk per request, so one poison
 submission fails alone), per-(graph, op) circuit breakers stop
 hammering a failing fast path and half-open probe it back, and requests
@@ -713,10 +713,9 @@ class SparseEngine:
     def _rungs(self, entry, op: str, w: int, r: SparseRequest) -> list:
         """The per-request rungs below ``fast`` for one request, in
         degradation order: ``single`` (isolate the poison request on
-        the same AOT operator), ``unsegmented`` (strip the §4.3 launch
-        tables — batched entries only), ``xla`` (pure-jnp reference —
-        for sharded entries, the sharded apply on the xla backend).
-        Every rung is bit-equivalent to the fast path."""
+        the same AOT operator), ``xla`` (pure-jnp reference — for
+        sharded entries, the sharded apply on the xla backend). Every
+        rung is bit-equivalent to the fast path."""
         from repro.kernels import ref
 
         reg = self.registry
@@ -739,16 +738,6 @@ class SparseEngine:
                 return [("single", single), ("xla", xla)]
             one = fn.op                     # the underlying LibraSpMM
 
-            def arrays(backend: str, segmented: bool):
-                # Lazy per-rung view: only the keys this rung's apply
-                # reads materialize (revalue maps instead of baked-in
-                # values when the request carries edge_vals).
-                arrs = one.arrays.for_backend(
-                    backend, segmented=segmented,
-                    revalue=r.edge_vals is not None)
-                return (arrs if r.edge_vals is None
-                        else ref.revalue_spmm_arrays(arrs, r.edge_vals))
-
             def single():
                 if r.edge_vals is None:
                     return one(bp, backend=reg.backend)[:, :width]
@@ -756,21 +745,18 @@ class SparseEngine:
                          edge_vals=r.edge_vals[None])
                 return out[0, :, :width]
 
-            def unsegmented():
-                cfg = one.tune_config.replace(ts=0, cs=0)
-                return spmm_apply(arrays(reg.backend, False), bp, m=one.m,
-                                  nwin=one.nwin, backend=reg.backend,
-                                  cfg=cfg)[:, :width]
-
             def xla():
-                return spmm_apply(arrays("xla", True), bp, m=one.m,
-                                  nwin=one.nwin, backend="xla",
+                # Revalue maps instead of baked-in values when the
+                # request carries edge_vals.
+                arrs = one.arrays.apply_arrays(
+                    revalue=r.edge_vals is not None)
+                if r.edge_vals is not None:
+                    arrs = ref.revalue_spmm_arrays(arrs, r.edge_vals)
+                return spmm_apply(arrs, bp, m=one.m, nwin=one.nwin,
+                                  backend="xla",
                                   cfg=one.tune_config)[:, :width]
 
-            rungs = [("single", single)]
-            if any("_seg_" in k for k in one.arrays):
-                rungs.append(("unsegmented", unsegmented))
-            return rungs + [("xla", xla)]
+            return [("single", single), ("xla", xla)]
         # ---- sddmm ----
         xp = _pad_width(r.payload[0], w)
         yp = _pad_width(r.payload[1], w)
@@ -784,25 +770,12 @@ class SparseEngine:
                     backend="xla", y_layout=fn.y_layout)),
             ]
         one = fn.op                         # the underlying LibraSDDMM
-
-        def sd_single():
-            return one(xp, yp, backend=reg.backend)
-
-        def sd_unsegmented():
-            cfg = one.tune_config.replace(ts=0, cs=0)
-            return sddmm_apply(
-                one.arrays.for_backend(reg.backend, segmented=False),
-                xp, yp, nnz=one.nnz, backend=reg.backend, cfg=cfg)
-
-        def sd_xla():
-            return sddmm_apply(one.arrays.for_backend("xla"), xp, yp,
-                               nnz=one.nnz, backend="xla",
-                               cfg=one.tune_config)
-
-        rungs = [("single", sd_single)]
-        if any("_seg_" in k for k in one.arrays):
-            rungs.append(("unsegmented", sd_unsegmented))
-        return rungs + [("xla", sd_xla)]
+        return [
+            ("single", lambda: one(xp, yp, backend=reg.backend)),
+            ("xla", lambda: sddmm_apply(
+                one.arrays.apply_arrays(), xp, yp, nnz=one.nnz,
+                backend="xla", cfg=one.tune_config)),
+        ]
 
     def _serve_degraded(self, entry, graph: str, op: str, w: int,
                         r: SparseRequest):

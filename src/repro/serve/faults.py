@@ -18,7 +18,7 @@ inject, if any:
   ``validate=True`` mode catches it).
 
 Strategy names match the engine's execution ladder (``"fast"`` is the
-packed/stacked rung, then ``"single"``, ``"unsegmented"``, ``"xla"``;
+packed/stacked rung, then ``"single"`` and ``"xla"``;
 the registry's AOT warmup ticks as ``"warm"``). ``None`` fields in a
 rule are wildcards; ``kth`` indexes the *site's own* call counter
 (1-based), so two graphs' fast paths count independently.

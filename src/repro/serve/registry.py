@@ -229,9 +229,9 @@ class GraphRegistry:
             if need > self.max_bytes:
                 self._pressure_rejects.inc()
                 raise MemoryPressure(
-                    f"graph {name!r} needs {need} plan bytes for the "
-                    f"{self.backend!r} serving view; registry budget is "
-                    f"{self.max_bytes}", required=need,
+                    f"graph {name!r} needs {need} plan bytes for its "
+                    f"serving view; registry budget is {self.max_bytes}",
+                    required=need,
                     budget=self.max_bytes)
 
         vpu_elems = 0
@@ -241,7 +241,7 @@ class GraphRegistry:
                 vpu_elems = int(vpu.ntiles) * int(vpu.vals.shape[-1])
             else:
                 # Sharded: the cache-resident stream is per device.
-                vv = built["spmm"].part.stacked["vpu_vals"]
+                vv = built["spmm"].part.stacked["vpu_seg_vals"]
                 vpu_elems = int(vv.shape[1]) * int(vv.shape[2])
         entry = RegisteredGraph(key=key, names={name}, m=a.m, k=a.k,
                                 nnz=a.nnz, mode=mode,
@@ -286,13 +286,13 @@ class GraphRegistry:
                                      int(v.nbytes), str(v.dtype))
 
     def _entry_bytes(self, built: dict) -> int:
-        """Projected resident bytes of an entry once serving on the
-        registry backend (host nbytes — device dtypes match)."""
+        """Projected resident bytes of an entry once serving (host
+        nbytes — device dtypes match)."""
         total = 0
         for op in built.values():
             arrays = getattr(getattr(op, "op", op), "arrays", None)
             if arrays is not None and hasattr(arrays, "projected_nbytes"):
-                total += arrays.projected_nbytes(self.backend)
+                total += arrays.projected_nbytes(arrays.apply_keys())
             elif getattr(op, "part", None) is not None:
                 total += sum(int(v.nbytes)
                              for v in op.part.stacked.values())

@@ -10,16 +10,13 @@ rung      what runs
               requests — the PR-4/5 hot path)
 ``single``    the same AOT operator, one request per apply (isolates
               a poison request: one bad submission fails alone)
-``unsegmented``  the per-request apply with the §4.3 segment launch
-              tables stripped (``ts=0``/``cs=0`` plan view — same
-              fused scatter combine, simpler grid)
 ``xla``       the pure-jnp reference apply (no Pallas, no AOT cache —
               the last resort that only dies if jnp itself does)
 ========  ==========================================================
 
-All rungs compute the same values (the segment/packing/stacking
-transforms are verified inert by the serving and §4.3 test suites), so
-degradation trades throughput for survival, never correctness.
+All rungs compute the same values (the packing/stacking transforms
+are verified inert by the serving test suite), so degradation trades
+throughput for survival, never correctness.
 
 This module owns the *policy* side: typed per-request failure results
 (:class:`ServeError` and friends — returned from ``flush``, never
@@ -35,10 +32,8 @@ from __future__ import annotations
 import dataclasses
 
 # The ladder, fastest first. ``fast`` is chunk-granular; the rest are
-# per-request. Sharded entries skip ``unsegmented`` (their segment
-# tables are stacked device arrays, not a strippable view) and fall
-# from ``single`` straight to the ``xla`` reference.
-LADDER = ("fast", "single", "unsegmented", "xla")
+# per-request.
+LADDER = ("fast", "single", "xla")
 
 
 class ServeError(RuntimeError):

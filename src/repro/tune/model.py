@@ -79,8 +79,8 @@ class TuneConfig:
     ts_tile: int | None = None    # VPU tile width (None = operator default)
     # Hybrid load balancing caps (paper §4.3 Ts/Cs): ``ts`` TC blocks per
     # MXU segment and ``cs`` VPU elements per row-segment bound the work
-    # one grid step does. None = operator default (segmentation on);
-    # 0 disables segmentation (the pre-§4.3 per-block/per-tile launch).
+    # one grid step does. None = operator default; a plan build refuses
+    # a cap below 1 (``ts=1``/``cs=ts_tile`` is one block/tile a step).
     ts: int | None = None
     cs: int | None = None
     source: str = "default"  # default | model | search | cache
@@ -154,15 +154,14 @@ def _itemsize(dtype) -> int:
 def _seg_widths(cfg: TuneConfig, *, bk: int, ts_tile: int) -> tuple[int, int]:
     """Effective per-grid-step work widths under the §4.3 segment caps:
     condensed vectors per MXU segment (``ts`` blocks × ``bk``) and VPU
-    elements per row-segment (``cs`` rounded down to whole tiles).
-    ``ts``/``cs`` of 0 disable segmentation (one block / one tile per
-    step — the legacy launch)."""
+    elements per row-segment (``cs`` rounded down to whole tiles, at
+    least one)."""
     from repro.core.balance import BalanceParams
 
     dflt = BalanceParams()
     seg_ts = dflt.ts if cfg.ts is None else cfg.ts
     seg_cs = dflt.cs if cfg.cs is None else cfg.cs
-    mxu_vecs = max(1, seg_ts) * bk
+    mxu_vecs = seg_ts * bk
     vpu_els = max(1, seg_cs // max(ts_tile, 1)) * ts_tile
     return mxu_vecs, vpu_els
 

@@ -101,11 +101,11 @@ def _seg_cap_perturbations(model: TuneConfig) -> list[TuneConfig]:
     caps re-layout the plan (the launch tables change), so they only
     matter where the executable iterates them — the Pallas backend."""
     out = []
-    if model.ts is not None and model.ts > 0:
+    if model.ts is not None:
         for ts in (max(model.ts // 2, 1), min(model.ts * 2, 64)):
             if ts != model.ts:
                 out.append(model.replace(ts=ts))
-    if model.cs is not None and model.cs > 0:
+    if model.cs is not None:
         tile = model.ts_tile or 32
         for cs in (max(model.cs // 2, tile), min(model.cs * 2, 16 * tile)):
             if cs != model.cs:

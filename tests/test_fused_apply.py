@@ -77,9 +77,9 @@ def test_tc_window_compaction_map(rng):
     assert tc.n_active <= nwin
     if op.plan.meta["has_tc"]:
         assert tc.n_active == len(np.unique(tc.window))
-    # the device-side scatter map matches active_win
-    rows = np.asarray(op.arrays["tc_active_row"]).reshape(-1, WINDOW)
-    assert np.array_equal(rows[:, 0] // WINDOW, tc.active_win)
+    # the device-side scatter map covers exactly active_win
+    rows = np.asarray(op.arrays["tc_seg_row"]).reshape(-1, WINDOW)
+    assert np.array_equal(np.unique(rows[:, 0] // WINDOW), tc.active_win)
 
 
 @pytest.mark.parametrize("k", [4608, 16384])

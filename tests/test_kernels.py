@@ -1,5 +1,7 @@
 """Per-kernel interpret-mode validation against the pure-jnp oracles:
 shape/dtype sweeps + end-to-end hybrid op vs dense oracle."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -356,12 +358,9 @@ def test_bitmap_mask_bit_decoding():
 
 
 def _combine_maps(arrs):
-    """The row/window maps the SpMM combines scatter with, per layout."""
+    """The window/row maps the SpMM combines scatter with."""
     return {
-        "tc_rank": arrs["tc_rank"],
-        "tc_active_row": arrs["tc_active_row"],
         "tc_seg_window": arrs["tc_seg_row"][..., ::WINDOW] // WINDOW,
-        "vpu_row": arrs["vpu_row"],
         "vpu_seg_row": arrs["vpu_seg_row"],
     }
 
@@ -379,10 +378,13 @@ def test_spmm_combine_maps_are_sorted(mi, mode, n_shards):
     a = MATS[mi]
     spec = ExecSpec(mode=mode, tune="model")
     if n_shards == 1:
-        arrs = LibraSpMM(a, spec=spec).arrays.for_backend("pallas")
-        arrs = dict(arrs, **LibraSpMM(a, spec=spec).arrays.for_backend(
-            "pallas", segmented=False))
-        stacks = [{k: np.asarray(v) for k, v in arrs.items()}]
+        op = LibraSpMM(a, spec=spec)
+        cfg = op.tune_config
+        one = LibraSpMM(a, spec=dataclasses.replace(
+            spec, tune=cfg.replace(ts=1, cs=op.plan.vpu.ts)))
+        stacks = [{k: np.asarray(v)
+                   for k, v in o.arrays.apply_arrays().items()}
+                  for o in (op, one)]
     else:
         part = partition_spmm(a, n_shards, spec=spec)
         stacks = [{k: np.asarray(v[p]) for k, v in part.stacked.items()}

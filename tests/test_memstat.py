@@ -47,14 +47,14 @@ class TestLazyBitIdentity:
             eager = dict(PlanArrays(plan).materialize_all())
             y_e = spmm_apply(eager, jnp.asarray(b), m=a.shape[0],
                              nwin=nwin, backend=backend, interpret=True)
-            lazy = pa.for_backend(backend)
-            # only the Pallas segmented apply reads segment lengths
-            assert ("vpu_seg_len" in lazy) == (backend == "pallas")
+            lazy = pa.apply_arrays()
+            # both backends read the same segment tables
+            assert "vpu_seg_len" in lazy
             y_l = spmm_apply(lazy, jnp.asarray(b),
                              m=a.shape[0], nwin=nwin, backend=backend,
                              interpret=True)
             assert np.array_equal(np.asarray(y_e), np.asarray(y_l))
-            # the backend view resident set is a strict subset
+            # the apply view resident set is a strict subset
             assert _resident_sum(pa) < pa.projected_nbytes()
 
     @pytest.mark.parametrize("backend", ["xla", "pallas"])
@@ -69,7 +69,7 @@ class TestLazyBitIdentity:
             o_e = sddmm_apply(eager, jnp.asarray(x), jnp.asarray(y),
                               nnz=plan.nnz, backend=backend,
                               interpret=True)
-            o_l = sddmm_apply(pa.for_backend(backend), jnp.asarray(x),
+            o_l = sddmm_apply(pa.apply_arrays(), jnp.asarray(x),
                               jnp.asarray(y), nnz=plan.nnz,
                               backend=backend, interpret=True)
             assert np.array_equal(np.asarray(o_e), np.asarray(o_l))
@@ -88,7 +88,7 @@ class TestLazyBitIdentity:
                          jnp.asarray(b), m=a.shape[0], nwin=nwin,
                          backend="xla", interpret=True)
         pa = PlanArrays(plan)
-        lazy = pa.for_backend("xla", revalue=True)
+        lazy = pa.apply_arrays(revalue=True)
         assert not any(k.endswith("_vals") for k in lazy)
         y_l = spmm_apply(ref.revalue_spmm_arrays(lazy, jnp.asarray(ev)),
                          jnp.asarray(b), m=a.shape[0], nwin=nwin,
@@ -96,7 +96,7 @@ class TestLazyBitIdentity:
         assert np.array_equal(np.asarray(y_e), np.asarray(y_l))
 
     def test_pytree_flatten_is_eager_dict(self, corpus):
-        """Legacy call sites jit over op.arrays directly; flattening
+        """Call sites that jit over op.arrays directly: flattening
         must materialize every key, eager-equivalently."""
         a = next(iter(corpus.values()))
         pa = PlanArrays(preprocess_spmm(a))
@@ -108,38 +108,36 @@ class TestLazyBitIdentity:
         assert pa.resident_nbytes() == pa.projected_nbytes()
 
     def test_view_classification(self):
-        assert view_of_key("tc_pos") == "revalue"
+        assert PLAN_VIEWS == ("segment", "revalue")
         assert view_of_key("tc_seg_pos") == "revalue"
+        assert view_of_key("vpu_seg_pos") == "revalue"
         assert view_of_key("tc_seg_vals") == "segment"
-        assert view_of_key("tc_vals") == "compact"
         # SDDMM scatter maps are structural, not revalue
-        assert view_of_key("tc_out_pos") == "compact"
+        assert view_of_key("tc_seg_out_pos") == "segment"
         assert view_of_key("vpu_seg_out_pos") == "segment"
 
     def test_vpu_seg_len_in_pallas_segment_view_only(self, corpus):
         a = next(iter(corpus.values()))
         pa = PlanArrays(preprocess_spmm(a))
         assert view_of_key("vpu_seg_len") == "segment"
-        assert "vpu_seg_len" in pa.backend_keys("pallas")
-        assert "vpu_seg_len" in pa.backend_keys("pallas", revalue=True)
-        assert "vpu_seg_len" not in pa.backend_keys("pallas",
-                                                    segmented=False)
-        assert "vpu_seg_len" not in pa.backend_keys("xla")
+        assert "vpu_seg_len" in pa.apply_keys()
+        assert "vpu_seg_len" in pa.apply_keys(revalue=True)
         assert "vpu_seg_len" not in PlanArrays(
-            preprocess_sddmm(a)).backend_keys("pallas")
+            preprocess_sddmm(a)).apply_keys()
         nbytes = int(pa._host["vpu_seg_len"].nbytes)
         assert nbytes == 4 * pa._host["vpu_seg_row"].shape[0]
-        assert pa.projected_nbytes("pallas") == sum(
-            int(pa._host[k].nbytes) for k in pa.backend_keys("pallas"))
-        pa.for_backend("pallas")
+        assert pa.projected_nbytes(pa.apply_keys()) == sum(
+            int(pa._host[k].nbytes) for k in pa.apply_keys())
+        pa.apply_arrays()
         assert pa._uploads["vpu_seg_len"] == ("segment", nbytes, "int32")
-        assert pa.resident_nbytes() == pa.projected_nbytes("pallas")
+        assert pa.resident_nbytes() == pa.projected_nbytes(pa.apply_keys())
 
     def test_tc_bitmap_not_in_spmm_backend_views(self, corpus):
         a = next(iter(corpus.values()))
         pa = PlanArrays(preprocess_spmm(a))
-        for backend in ("xla", "pallas"):
-            assert "tc_bitmap" not in pa.backend_keys(backend)
+        for revalue in (False, True):
+            assert not any("bitmap" in k
+                           for k in pa.apply_keys(revalue=revalue))
 
 
 # ------------------------------------------------------------- ledger ---
@@ -151,7 +149,7 @@ class TestMemLedgerExactness:
         for name, a in corpus.items():
             pa = PlanArrays(preprocess_spmm(a))
             pa.set_accountant(led.binder(name, "spmm"))
-            pa.for_backend("xla")
+            pa.apply_arrays()
             pas[name] = pa
         expect = sum(_resident_sum(pa) for pa in pas.values())
         assert led.resident_bytes() == expect
@@ -161,7 +159,7 @@ class TestMemLedgerExactness:
         assert sum(rep["by_op"].values()) == expect
         assert sum(g["bytes"] for g in rep["graphs"]) == expect
         # materialize more: ledger tracks the growth exactly
-        next(iter(pas.values())).for_backend("pallas")
+        next(iter(pas.values())).apply_arrays(revalue=True)
         expect = sum(_resident_sum(pa) for pa in pas.values())
         assert led.resident_bytes() == expect
         assert led.peak_bytes() == expect
@@ -171,21 +169,21 @@ class TestMemLedgerExactness:
         accounting; set_accountant replays recorded uploads."""
         a = next(iter(corpus.values()))
         pa = PlanArrays(preprocess_spmm(a))
-        pa.for_backend("xla")   # uploads happen before any accountant
+        pa.apply_arrays()   # uploads happen before any accountant
         led = MemLedger()
         pa.set_accountant(led.binder("g", "spmm"))
         assert led.resident_bytes() == _resident_sum(pa)
 
     def test_mixed_backend_double_materialization(self, corpus):
-        """Serving one graph on both backends accounts each array once
-        (delta semantics), totals still exact."""
+        """Serving one graph through both apply views accounts each
+        array once (delta semantics), totals still exact."""
         a = next(iter(corpus.values()))
         pa = PlanArrays(preprocess_spmm(a))
         led = MemLedger()
         pa.set_accountant(led.binder("g", "spmm"))
-        pa.for_backend("xla")
-        pa.for_backend("pallas")
-        pa.for_backend("xla")   # re-serving re-uses, no double count
+        pa.apply_arrays()
+        pa.apply_arrays(revalue=True)
+        pa.apply_arrays()   # re-serving re-uses, no double count
         assert led.resident_bytes() == _resident_sum(pa)
         assert led.graph_bytes("g") == _resident_sum(pa)
         vb = pa.view_nbytes()
@@ -359,7 +357,7 @@ class TestMemoryObservability:
             assert doc["n_graphs"] == 1
             body = urllib.request.urlopen(
                 f"{srv.url}/metrics", timeout=10).read().decode()
-            assert 'registry_resident_bytes{view="compact"}' in body
+            assert 'registry_resident_bytes{view="segment"}' in body
             assert "registry_bytes_evicted_total" in body
             # route list advertises /memory
             with pytest.raises(urllib.error.HTTPError) as ei:
@@ -388,9 +386,9 @@ class TestMemoryObservability:
         report = explain_spmm(op)
         mem = report["memory"]
         assert mem["resident_bytes"] == op.arrays.resident_nbytes() > 0
-        assert mem["views"]["compact"]["resident_keys"] > 0
+        assert mem["views"]["segment"]["resident_keys"] > 0
         text = render_table(report)
-        assert "mem_compact" in text and "mem_resident" in text
+        assert "mem_segment" in text and "mem_resident" in text
 
     def test_ledger_samples_carry_mem_bytes(self, tmp_path):
         from repro.core.spmm import LibraSpMM

@@ -1,10 +1,10 @@
 """Multi-head edge values through the hybrid applies: ``(nnz, H)``
 values against dense operands whose ``H·c`` columns hold the heads
 contiguously, on both backends (the XLA reference and the Pallas
-kernels, interpreted here), in both launch layouts, against a dense
-per-head oracle; and ``(nnz, 1)`` against today's ``(nnz,)`` call, bit
-for bit; and the same values through ``DistGraphOps`` on an emulated
-mesh."""
+kernels, interpreted here), on the tuned segment tables and on one
+block (tile) per segment, against a dense per-head oracle; and
+``(nnz, 1)`` against today's ``(nnz,)`` call, bit for bit; and the same
+values through ``DistGraphOps`` on an emulated mesh."""
 import os
 import subprocess
 import sys
@@ -36,34 +36,33 @@ def graph():
 @pytest.fixture(scope="module")
 def ops(graph):
     """Both operators of the graph, whose plans split the non-zeros
-    between the MXU and the VPU streams, so both kernels of each op run."""
+    between the MXU and the VPU streams, so both kernels of each op run:
+    ``ops[False]`` on the tuned segment tables, ``ops[True]`` on one
+    block (``ts=1``) and one tile (``cs=ts_tile``) per segment."""
     a = graph[0]
     sp = LibraSpMM(a, spec=ExecSpec(tune="model"))
     sd = LibraSDDMM(a, spec=ExecSpec(tune="model"))
-    for op in (sp, sd):
+    out = {False: (sp, sd)}
+    out[True] = tuple(
+        cls(a, spec=ExecSpec(tune=op.tune_config.replace(
+            ts=1, cs=op.tune_config.ts_tile)))
+        for cls, op in ((LibraSpMM, sp), (LibraSDDMM, sd)))
+    for op in out[False] + out[True]:
         assert 0.0 < op.plan.meta["tc_ratio"] < 1.0
-    return sp, sd
+    return out
 
 
-def _cfg(op, layout):
-    return op.tune_config if layout == "segmented" else \
-        op.tune_config.replace(ts=0, cs=0)
-
-
-def _spmm(op, ev, b, backend, layout, cfg=None):
-    arrs = op.arrays.for_backend(backend, revalue=True,
-                                 segmented=layout == "segmented")
+def _spmm(op, ev, b, backend, cfg=None):
+    arrs = op.arrays.apply_arrays(revalue=True)
     return np.asarray(spmm_apply(
         ref.revalue_spmm_arrays(arrs, jnp.asarray(ev)), jnp.asarray(b),
-        m=op.m, nwin=op.nwin, backend=backend,
-        cfg=cfg or _cfg(op, layout)))
+        m=op.m, nwin=op.nwin, backend=backend, cfg=cfg or op.tune_config))
 
 
-def _sddmm(op, x, y, backend, layout, heads):
-    arrs = op.arrays.for_backend(backend, segmented=layout == "segmented")
+def _sddmm(op, x, y, backend, heads):
     return np.asarray(sddmm_apply(
-        arrs, jnp.asarray(x), jnp.asarray(y), nnz=op.nnz, backend=backend,
-        cfg=_cfg(op, layout), heads=heads))
+        op.arrays.apply_arrays(), jnp.asarray(x), jnp.asarray(y),
+        nnz=op.nnz, backend=backend, cfg=op.tune_config, heads=heads))
 
 
 def _spmm_oracle(graph, ev, b, heads):
@@ -85,33 +84,33 @@ def _sddmm_oracle(graph, x, y, heads):
                      for h in range(heads)], axis=1)
 
 
-@pytest.mark.parametrize("layout", ["segmented", "legacy"])
+@pytest.mark.parametrize("one_unit", [False, True])
 @pytest.mark.parametrize("backend", ["xla", "pallas"])
 @pytest.mark.parametrize("head_dim", HEAD_DIMS)
 @pytest.mark.parametrize("heads", HEADS)
 def test_multihead_spmm_matches_per_head_oracle(graph, ops, heads, head_dim,
-                                                backend, layout):
+                                                backend, one_unit):
     a = graph[0]
     rng = np.random.default_rng(heads * 1000 + head_dim)
     ev = rng.standard_normal((a.nnz, heads)).astype(np.float32)
     b = rng.standard_normal((a.k, heads * head_dim)).astype(np.float32)
-    got = _spmm(ops[0], ev, b, backend, layout)
+    got = _spmm(ops[one_unit][0], ev, b, backend)
     assert got.shape == (a.m, heads * head_dim)
     np.testing.assert_allclose(got, _spmm_oracle(graph, ev, b, heads),
                                rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("layout", ["segmented", "legacy"])
+@pytest.mark.parametrize("one_unit", [False, True])
 @pytest.mark.parametrize("backend", ["xla", "pallas"])
 @pytest.mark.parametrize("head_dim", HEAD_DIMS)
 @pytest.mark.parametrize("heads", HEADS)
 def test_multihead_sddmm_matches_per_head_oracle(graph, ops, heads, head_dim,
-                                                 backend, layout):
+                                                 backend, one_unit):
     a = graph[0]
     rng = np.random.default_rng(heads * 1000 + head_dim + 1)
     x = rng.standard_normal((a.m, heads * head_dim)).astype(np.float32)
     y = rng.standard_normal((a.k, heads * head_dim)).astype(np.float32)
-    got = _sddmm(ops[1], x, y, backend, layout, heads)
+    got = _sddmm(ops[one_unit][1], x, y, backend, heads)
     assert got.shape == (a.nnz, heads)
     np.testing.assert_allclose(got, _sddmm_oracle(graph, x, y, heads),
                                rtol=1e-4, atol=1e-4)
@@ -125,29 +124,28 @@ def test_multihead_spmm_block_outer_grid(graph, ops):
     ev = rng.standard_normal((a.nnz, 4)).astype(np.float32)
     b = rng.standard_normal((a.k, 160)).astype(np.float32)
     # A cap of 128 gives the 160-wide call two lane tiles: block_outer.
-    cfg = ops[0].tune_config.replace(nt=128)
-    got = _spmm(ops[0], ev, b, "pallas", "segmented", cfg=cfg)
+    cfg = ops[False][0].tune_config.replace(nt=128)
+    got = _spmm(ops[False][0], ev, b, "pallas", cfg=cfg)
     np.testing.assert_allclose(got, _spmm_oracle(graph, ev, b, 4),
                                rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("layout", ["segmented", "legacy"])
+@pytest.mark.parametrize("one_unit", [False, True])
 @pytest.mark.parametrize("backend", ["xla", "pallas"])
 def test_one_head_is_bitwise_the_single_head_call(graph, ops, backend,
-                                                  layout):
+                                                  one_unit):
     a = graph[0]
+    sp, sd = ops[one_unit]
     rng = np.random.default_rng(11)
     ev = rng.standard_normal((a.nnz,)).astype(np.float32)
     b = rng.standard_normal((a.k, 40)).astype(np.float32)
-    np.testing.assert_array_equal(
-        _spmm(ops[0], ev[:, None], b, backend, layout),
-        _spmm(ops[0], ev, b, backend, layout))
+    np.testing.assert_array_equal(_spmm(sp, ev[:, None], b, backend),
+                                  _spmm(sp, ev, b, backend))
     x = rng.standard_normal((a.m, 40)).astype(np.float32)
     y = rng.standard_normal((a.k, 40)).astype(np.float32)
-    one = _sddmm(ops[1], x, y, backend, layout, 1)
+    one = _sddmm(sd, x, y, backend, 1)
     assert one.shape == (a.nnz, 1)
-    np.testing.assert_array_equal(
-        one[:, 0], _sddmm(ops[1], x, y, backend, layout, None))
+    np.testing.assert_array_equal(one[:, 0], _sddmm(sd, x, y, backend, None))
 
 
 def test_dist_graphops_carries_heads_4dev():

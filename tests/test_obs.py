@@ -628,17 +628,17 @@ class TestPlanBuildSpans:
         from repro.tune import TuneConfig
 
         a = power_law_csr(96, 96, avg_row=6.0, seed=5)
-        cfg = TuneConfig() if segmented else TuneConfig(ts=0, cs=0)
+        # per_tile: one tile per segment (cs = ts_tile).
+        cfg = TuneConfig() if segmented else TuneConfig(ts=1, cs=32,
+                                                        ts_tile=32)
         plan = preprocess_spmm(a, cfg=cfg)
         arrs = PlanArrays(plan)
         counts = plan_counts(plan, "spmm")
-        if segmented:
-            fetched = int(np.asarray(arrs["vpu_seg_len"]).sum())
-            assert counts["vpu_fetches"] == fetched == counts["vpu_nnz"]
-            assert counts["vpu_fetches"] < counts["vpu_slots"]
-        else:
-            assert "vpu_seg_len" not in arrs
-            assert counts["vpu_fetches"] == counts["vpu_slots"]
+        fetched = int(np.asarray(arrs["vpu_seg_len"]).sum())
+        assert counts["vpu_fetches"] == fetched == counts["vpu_nnz"]
+        assert counts["vpu_fetches"] < counts["vpu_slots"]
+        if not segmented:
+            assert arrs["vpu_seg_len"].shape == (plan.vpu.ntiles,)
 
     def test_disabled_tracer_computes_no_counters(self, monkeypatch):
         from repro.core import preprocess

@@ -557,7 +557,7 @@ def _storm_ctx():
         sites = [(g, op, s)
                  for g, op in (("g1", "spmm"), ("g2", "spmm"),
                                ("g2", "sddmm"))
-                 for s in ("fast", "single", "unsegmented", "xla")]
+                 for s in ("fast", "single", "xla")]
         _STORM.update(reg=reg, subs=subs, want=want, sites=sites)
     return _STORM
 

@@ -2,9 +2,11 @@
 
 Covers the vectorized decomposition, the segment launch tables, the
 atomic-flag invariants (every multi-producer output marked), bit-identity
-of the segmented kernels vs the unsegmented fused apply and the dense
-oracle on both backends, empty-path edge plans, Ts/Cs threading through
-the tuner + plan cache, and the dist partitioner's segment-curve split.
+of the tuned segment tables vs one block (tile) per segment and the
+dense oracle on both backends, the one-unit tables vs the per-block /
+per-tile host tensors, the refusal of caps below 1, empty-path edge
+plans, Ts/Cs threading through the tuner + plan cache, and the dist
+partitioner's segment-curve split.
 """
 import dataclasses
 
@@ -163,11 +165,12 @@ def _check_bitident_spmm(a, cfg, n=64):
     r = np.random.default_rng(2)
     b = jnp.asarray(r.integers(-2, 3, (a.k, n)).astype(np.float32))
     op = LibraSpMM(a, tune=cfg)
-    op0 = LibraSpMM(a, tune=cfg.replace(ts=0, cs=0))
-    assert "tc_seg_vals" in op.arrays and "tc_seg_vals" not in op0.arrays
-    # The segmented stream fetches real elements only, the per-tile
-    # stream every slot.
-    assert "vpu_seg_len" in op.arrays and "vpu_seg_len" not in op0.arrays
+    op0 = LibraSpMM(a, tune=cfg.replace(ts=1, cs=op.plan.vpu.ts))
+    assert op0.plan.meta["tc_segments"].nseg == op0.plan.tc.nblk
+    assert op0.plan.meta["vpu_segments"].nseg == op0.plan.vpu.ntiles
+    # Both streams fetch real elements only.
+    for o in (op, op0):
+        assert int(np.asarray(o.arrays["vpu_seg_len"]).sum()) == o.plan.vpu.nnz
     oracle = np.asarray(a.to_dense() @ np.asarray(b), np.float32)
     outs = [np.asarray(op(b, backend=be)) for be in ("xla", "pallas")]
     outs += [np.asarray(op0(b, backend=be)) for be in ("xla", "pallas")]
@@ -214,9 +217,9 @@ def test_segmented_sddmm_bit_identical(rng):
     y = jnp.asarray(rng.integers(-2, 3, (a.k, 48)).astype(np.float32))
     cfg = TuneConfig(ts=2, cs=64, ts_tile=16)
     op = LibraSDDMM(a, tune=cfg)
-    op0 = LibraSDDMM(a, tune=cfg.replace(ts=0, cs=0))
-    assert "tc_seg_cols" in op.arrays and "vpu_seg_rows" in op.arrays
-    assert "tc_seg_cols" not in op0.arrays
+    op0 = LibraSDDMM(a, tune=cfg.replace(ts=1, cs=op.plan.vpu.ts))
+    assert op.plan.meta["seg_spt"] > 1 and op0.plan.meta["seg_spt"] == 1
+    assert op0.plan.meta["tc_segments"].nseg == op0.plan.tc.nblk
     oracle = np.asarray(ref.sddmm_dense_oracle(
         a.to_dense(), np.asarray(x), np.asarray(y)))
     outs = [np.asarray(op(x, y, backend=be)) for be in ("xla", "pallas")]
@@ -241,6 +244,61 @@ def test_segmented_revalue_matches_rebaked_plan(rng):
     r, c, _ = a.to_coo()
     dense[r, c] = ev
     assert np.array_equal(out, np.asarray(dense @ np.asarray(b), np.float32))
+
+
+# ----------------------------------------------- one-unit segments ---
+@pytest.mark.parametrize("mode", ["hybrid", "tcu", "vpu"])
+def test_one_unit_tables_are_the_per_block_tensors(mode):
+    """One block (``ts=1``) and one tile (``cs=ts_tile``) per segment:
+    the launch tables are the per-block / per-tile host tensors, element
+    for element, same shapes and dtypes — for SpMM's MXU and VPU streams
+    and SDDMM's. SDDMM's VPU table is the per-tile one whenever a
+    segment holds one tile, whatever ``ts``."""
+    from repro.core.formats import _host_arrays
+
+    a = _skewed(seed=4)
+    cfg = TuneConfig(ts=1, cs=16, bk=8, ts_tile=16)
+
+    def same(table, tensor):
+        assert table.dtype == tensor.dtype and np.array_equal(table, tensor)
+
+    plan = preprocess.preprocess_spmm(
+        a, preprocess.threshold_for_mode_spmm(mode), cfg=cfg)
+    host, tc, vpu = _host_arrays(plan), plan.tc, plan.vpu
+    same(host["tc_seg_vals"], tc.vals)
+    same(host["tc_seg_cols"], tc.cols)
+    same(host["tc_seg_pos"], tc.pos)
+    same(host["tc_seg_row"].reshape(-1, WINDOW)[:, 0] // WINDOW, tc.window)
+    same(host["vpu_seg_vals"], vpu.vals)
+    same(host["vpu_seg_cols"], vpu.cols)
+    same(host["vpu_seg_pos"], vpu.pos)
+    same(host["vpu_seg_row"], vpu.row)
+    for sd_cfg in (cfg, cfg.replace(ts=None)):
+        plan = preprocess.preprocess_sddmm(
+            a, preprocess.threshold_for_mode_sddmm(mode, 8), cfg=sd_cfg)
+        host, tc, vpu = _host_arrays(plan), plan.tc, plan.vpu
+        assert plan.meta["seg_spt"] == 1
+        same(host["vpu_seg_rows"], vpu.rows)
+        same(host["vpu_seg_cols"], vpu.cols)
+        same(host["vpu_seg_out_pos"], vpu.out_pos)
+        same(host["vpu_seg_mask"], vpu.mask)
+    plan = preprocess.preprocess_sddmm(
+        a, preprocess.threshold_for_mode_sddmm(mode, 8), cfg=cfg)
+    host, tc = _host_arrays(plan), plan.tc
+    same(host["tc_seg_cols"], tc.cols)
+    same(host["tc_seg_bitmap"], tc.bitmap)
+    same(host["tc_seg_window"], tc.window)
+    same(host["tc_seg_out_pos"], plan.tc_out_pos)
+
+
+@pytest.mark.parametrize("op", [LibraSpMM, LibraSDDMM])
+@pytest.mark.parametrize("cfg", [TuneConfig(ts=0), TuneConfig(cs=0)],
+                         ids=["ts", "cs"])
+def test_segment_caps_below_one_are_refused(op, cfg):
+    """Every plan launches from its segment tables: a cap of 0 names no
+    launch and fails the plan build."""
+    with pytest.raises(ValueError, match="segment caps"):
+        op(mixed_csr(32, 32, seed=1), tune=cfg)
 
 
 # ------------------------------------------------- segment lengths ---
@@ -362,7 +420,7 @@ def test_partition_balances_on_segment_curve():
     assert len(part.meta["shard_segments"]) == 4
     assert part.meta["segment_balance"]["max_over_mean"] >= 1.0
     curve = segment_curve(a, op="spmm", threshold=3, bk=32, seg_ts=8,
-                          seg_cs=128, ts_tile=32)
+                          seg_cs=128)
     assert curve.shape == ((a.m + WINDOW - 1) // WINDOW,)
     # shard boundaries follow the curve: per-shard curve mass within one
     # window's mass of the ideal split

@@ -4,8 +4,8 @@ Nothing runs: each test lowers ``spmm_apply`` / ``sddmm_apply`` with
 ``backend="pallas", interpret=False`` against shapes placed on one chip
 of a ``v5e:2x2`` topology that is described, not attached, and lets the
 TPU compiler accept or refuse it — the tiling, VMEM and memory rules
-the Pallas interpreter cannot check. Both launches (§4.3 segment tables
-and the per-block/per-tile tables) run at real widths (n = 256,
+the Pallas interpreter cannot check. The tuned §4.3 segment tables and
+tables of one block (tile) per segment run at real widths (n = 256,
 kf = 128) with ``k`` in the tens of thousands, so B, X and Y are
 fetched row by row from HBM.
 
@@ -13,6 +13,7 @@ The topology is described inside a module fixture (never at import):
 one process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import dataclasses
 import os
 import re
 
@@ -72,37 +73,46 @@ def _check(compiled):
     assert used < HBM_BYTES, used
 
 
-@pytest.mark.parametrize("segmented", [True, False],
-                         ids=["segmented", "legacy"])
+def _one_unit(op, cls, a, spec):
+    """``op``'s plan rebuilt at one block (``ts=1``) and one tile
+    (``cs=ts_tile``) per segment."""
+    cfg = op.tune_config
+    return cls(a, spec=dataclasses.replace(
+        spec, tune=cfg.replace(ts=1, cs=cfg.ts_tile)))
+
+
+@pytest.mark.parametrize("one_unit", [False, True],
+                         ids=["segmented", "one_unit"])
 @pytest.mark.parametrize("graph", ["powerlaw", "block"])
-def test_spmm_apply_compiles_for_v5e(one_chip, graphs, graph, segmented):
+def test_spmm_apply_compiles_for_v5e(one_chip, graphs, graph, one_unit):
     a = graphs[graph]
-    op = LibraSpMM(a, spec=ExecSpec(backend="pallas", tune="model",
-                                    tune_n=N))
-    cfg = op.tune_config if segmented else op.tune_config.replace(ts=0,
-                                                                  cs=0)
-    arrs = op.arrays.for_backend("pallas", segmented=segmented)
-    assert any("_seg_" in k for k in arrs) == segmented
+    spec = ExecSpec(backend="pallas", tune="model", tune_n=N)
+    op = LibraSpMM(a, spec=spec)
+    if one_unit:
+        op = _one_unit(op, LibraSpMM, a, spec)
+        assert op.plan.meta["tc_segments"].nseg == op.plan.tc.nblk
+    arrs = op.arrays.apply_arrays()
     b = jax.ShapeDtypeStruct((a.k, N), jnp.float32, sharding=one_chip)
     _check(spmm_apply.lower(_shapes(arrs, one_chip), b, m=op.m,
-                            nwin=op.nwin, backend="pallas", cfg=cfg,
-                            interpret=False).compile())
+                            nwin=op.nwin, backend="pallas",
+                            cfg=op.tune_config, interpret=False).compile())
 
 
-@pytest.mark.parametrize("segmented", [True, False],
-                         ids=["segmented", "legacy"])
+@pytest.mark.parametrize("one_unit", [False, True],
+                         ids=["segmented", "one_unit"])
 @pytest.mark.parametrize("graph", ["powerlaw", "block"])
-def test_sddmm_apply_compiles_for_v5e(one_chip, graphs, graph, segmented):
+def test_sddmm_apply_compiles_for_v5e(one_chip, graphs, graph, one_unit):
     a = graphs[graph]
-    op = LibraSDDMM(a, spec=ExecSpec(backend="pallas", tune="model",
-                                     tune_kf=KF))
-    cfg = op.tune_config if segmented else op.tune_config.replace(ts=0,
-                                                                  cs=0)
-    arrs = op.arrays.for_backend("pallas", segmented=segmented)
+    spec = ExecSpec(backend="pallas", tune="model", tune_kf=KF)
+    op = LibraSDDMM(a, spec=spec)
+    if one_unit:
+        op = _one_unit(op, LibraSDDMM, a, spec)
+        assert op.plan.meta["seg_spt"] == 1
+    arrs = op.arrays.apply_arrays()
     x = jax.ShapeDtypeStruct((a.m, KF), jnp.float32, sharding=one_chip)
     y = jax.ShapeDtypeStruct((a.k, KF), jnp.float32, sharding=one_chip)
     _check(sddmm_apply.lower(_shapes(arrs, one_chip), x, y, nnz=op.nnz,
-                             backend="pallas", cfg=cfg,
+                             backend="pallas", cfg=op.tune_config,
                              interpret=False).compile())
 
 
@@ -127,7 +137,7 @@ def test_applies_name_their_kernels_and_scopes(one_chip, graphs, kind):
     if kind == "spmm":
         op = LibraSpMM(a, spec=ExecSpec(backend="pallas", tune="model",
                                         tune_n=N))
-        arrs = op.arrays.for_backend("pallas", segmented=True)
+        arrs = op.arrays.apply_arrays()
         b = jax.ShapeDtypeStruct((a.k, N), jnp.float32, sharding=one_chip)
         lowered = spmm_apply.lower(_shapes(arrs, one_chip), b, m=op.m,
                                    nwin=op.nwin, backend="pallas",
@@ -135,7 +145,7 @@ def test_applies_name_their_kernels_and_scopes(one_chip, graphs, kind):
     else:
         op = LibraSDDMM(a, spec=ExecSpec(backend="pallas", tune="model",
                                          tune_kf=KF))
-        arrs = op.arrays.for_backend("pallas", segmented=True)
+        arrs = op.arrays.apply_arrays()
         x = jax.ShapeDtypeStruct((a.m, KF), jnp.float32, sharding=one_chip)
         y = jax.ShapeDtypeStruct((a.k, KF), jnp.float32, sharding=one_chip)
         lowered = sddmm_apply.lower(_shapes(arrs, one_chip), x, y,
@@ -165,7 +175,7 @@ def test_multihead_applies_compile_for_v5e(one_chip, graphs, heads,
     width = heads * head_dim
     sp = LibraSpMM(a, spec=ExecSpec(backend="pallas", tune="model",
                                     tune_n=N))
-    arrs = sp.arrays.for_backend("pallas", revalue=True, segmented=True)
+    arrs = sp.arrays.apply_arrays(revalue=True)
     ev = jax.ShapeDtypeStruct((a.nnz, heads), jnp.float32,
                               sharding=one_chip)
     b = jax.ShapeDtypeStruct((a.k, width), jnp.float32, sharding=one_chip)
@@ -183,7 +193,7 @@ def test_multihead_applies_compile_for_v5e(one_chip, graphs, heads,
         "spmm": jax.jit(spmm).lower(_shapes(arrs, one_chip), ev,
                                     b).compile(),
         "sddmm": sddmm_apply.lower(
-            _shapes(sd.arrays.for_backend("pallas", segmented=True),
+            _shapes(sd.arrays.apply_arrays(),
                     one_chip), x, y, nnz=sd.nnz, backend="pallas",
             cfg=sd.tune_config, heads=heads, interpret=False).compile(),
     }
@@ -206,7 +216,7 @@ def test_sddmm_apply_compiles_at_a_256_feature_tile(one_chip, graphs):
     a = graphs["powerlaw"]
     op = LibraSDDMM(a, spec=ExecSpec(backend="pallas", tune="model"))
     assert lane_tile("sddmm", N, op.tune_config) == N
-    arrs = op.arrays.for_backend("pallas", segmented=True)
+    arrs = op.arrays.apply_arrays()
     x = jax.ShapeDtypeStruct((a.m, N), jnp.float32, sharding=one_chip)
     y = jax.ShapeDtypeStruct((a.k, N), jnp.float32, sharding=one_chip)
     exe = sddmm_apply.lower(_shapes(arrs, one_chip), x, y, nnz=op.nnz,

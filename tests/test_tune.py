@@ -344,7 +344,7 @@ def test_tuned_configs_bit_identical_outputs_sddmm(rng):
     for tune in ("off", "model", TuneConfig(kf_tile=128),
                  TuneConfig(threshold=8),
                  TuneConfig(threshold=1),          # everything on the MXU
-                 TuneConfig(threshold=8, ts=0, cs=0)):  # per-block launch
+                 TuneConfig(threshold=8, ts=1)):  # one block a segment
 
         op = LibraSDDMM(a, tune=tune)
         for backend in ("xla", "pallas"):
@@ -355,13 +355,14 @@ def test_tuned_configs_bit_identical_outputs_sddmm(rng):
 
 
 def test_block_outer_downgrade_on_shared_ranks(rng):
-    """Multi-block windows on the per-block launch: every grid step owns
+    """Multi-block windows at one block per segment: every grid step owns
     its output block, so block_outer stays legal (no downgrade) and the
-    combine sums the blocks that share a window."""
+    combine sums the segments that share a window."""
     a = banded_csr(64, 256, 48, 1.0, seed=10)  # 48 vecs/window > bk=32
     # Two 128-lane tiles of the 256-wide B: the apply runs block_outer.
-    op = LibraSpMM(a, tune=TuneConfig(nt=128, ts=0, cs=0))
-    assert op.plan.tc.nblk > op.plan.tc.n_active
+    op = LibraSpMM(a, tune=TuneConfig(nt=128, ts=1))
+    seg = op.plan.meta["tc_segments"]
+    assert seg.nseg == op.plan.tc.nblk > len(np.unique(seg.cur))
     b = rng.standard_normal((a.k, 256)).astype(np.float32)
     out = np.asarray(op(jnp.asarray(b), backend="pallas"))
     np.testing.assert_allclose(out, a.to_dense() @ b, rtol=1e-3, atol=1e-3)
