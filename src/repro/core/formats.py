@@ -37,8 +37,8 @@ WINDOW = 8  # paper: 8×1 non-zero column vectors (swap-and-transpose granularit
 PLAN_VIEWS = ("segment", "revalue")
 
 # SpMM value tensor → the canonical-nnz position map that rebuilds it
-# (read only by ref.revalue_spmm_arrays). SDDMM's *_out_pos keys are
-# structural scatter maps every apply needs — they stay in "segment".
+# (read only by ref.revalue_spmm_arrays). SDDMM's ``sddmm_src`` gather
+# index is structural — every apply needs it, so it stays in "segment".
 _REVALUE_OF = {"tc_seg_vals": "tc_seg_pos", "vpu_seg_vals": "vpu_seg_pos"}
 
 
@@ -256,16 +256,18 @@ def spmm_vpu_seg_len(plan: "SpMMPlan") -> np.ndarray:
     return _vpu_seg_len(plan.vpu, take, mask)
 
 
-def _sddmm_segment_arrays(plan: "SDDMMPlan") -> dict[str, np.ndarray]:
-    """Segment-granular launch tables for the SDDMM kernels (§4.3).
+def _sddmm_seg_tables(plan: "SDDMMPlan") -> dict[str, np.ndarray]:
+    """Segment-granular host tables of the SDDMM kernels (§4.3).
 
     MXU: a segment's ≤ ``ts`` blocks share one window, so one grid step
     is a single ``8×kf @ kf×(ts·bk)`` score dot sampled by the
-    concatenated bitmaps (zero bitmap padding samples to zero and its
-    ``out_pos`` −1 lands in the scatter's swallow slot). VPU: element
-    tiles are flat, so the Cs cap just batches ``seg_spt`` tiles per
-    grid step (mask-False padding); at ``seg_spt`` 1 the tables are the
-    per-tile tensors themselves.
+    concatenated bitmaps; ``tc_seg_out_pos`` names each scored cell's
+    canonical non-zero (−1 on padding). VPU: element tiles are flat, so
+    the Cs cap just batches ``seg_spt`` tiles per grid step
+    (``vpu_seg_mask`` False on padding); at ``seg_spt`` 1 the tables are
+    the per-tile tensors themselves. The out-position tables and the
+    mask stay on the host: :func:`sddmm_gather_map` folds them into the
+    combine's one device index.
     """
     out: dict[str, np.ndarray] = {}
     tc, tc_seg = plan.tc, plan.meta["tc_segments"]
@@ -301,9 +303,62 @@ def _sddmm_segment_arrays(plan: "SDDMMPlan") -> dict[str, np.ndarray]:
     return out
 
 
+def sddmm_gather_map(tc_out_pos: np.ndarray, vpu_out_pos: np.ndarray,
+                     vpu_mask: np.ndarray, nnz: int,
+                     length: int | None = None) -> np.ndarray:
+    """The SDDMM combine's gather index, ``(length,)`` int32 (``length``
+    defaults to ``nnz``): entry ``p`` is non-zero ``p``'s position in the
+    kernels' scores laid end to end — the MXU stream's ``(nseg, WINDOW,
+    w·bk)`` cells flattened, then the VPU stream's element slots. It is
+    the inverse of the out-position tables, built once per plan: the
+    build refuses a plan that does not place each of its ``nnz``
+    non-zeros at exactly one position, and padding (out-pos −1, mask
+    False) is never named. Entries from ``nnz`` to ``length`` (a stacked
+    shard's padding non-zeros) read position 0."""
+    pos = np.concatenate([np.asarray(tc_out_pos).reshape(-1),
+                          np.where(vpu_mask, vpu_out_pos, -1).reshape(-1)])
+    real = np.flatnonzero(pos >= 0)
+    named = pos[real]
+    counts = np.bincount(named, minlength=nnz)
+    if named.size != nnz or counts.size != nnz or not (counts == 1).all():
+        raise ValueError(
+            f"SDDMM plan places {named.size} scores over {nnz} non-zeros: "
+            "each non-zero needs exactly one position")
+    src = np.zeros(nnz if length is None else length, np.int32)
+    src[named] = real
+    return src
+
+
+def _sddmm_segment_arrays(plan: "SDDMMPlan") -> dict[str, np.ndarray]:
+    """The SDDMM device tables: the kernels' segment tables
+    (:func:`_sddmm_seg_tables`) and ``sddmm_src``, the combine's gather
+    index (:func:`sddmm_gather_map`). Cap padding is never gathered:
+    zero-bitmap MXU cells and mask-False VPU slots are scored and
+    dropped."""
+    out = _sddmm_seg_tables(plan)
+    out["sddmm_src"] = sddmm_gather_map(
+        out.pop("tc_seg_out_pos"), out.pop("vpu_seg_out_pos"),
+        out.pop("vpu_seg_mask"), plan.nnz)
+    return out
+
+
+def sddmm_combine_slots(plan: "SDDMMPlan") -> int:
+    """Length of the score buffer the SDDMM combine gathers from: the
+    MXU segment table's cells (``nseg × WINDOW × ts·bk``) plus the VPU
+    segment table's slots, the ``positions`` :func:`sddmm_gather_map`
+    indexes."""
+    seg = plan.meta["tc_segments"]
+    nseg, w = (seg.nseg, seg.limit) if seg.nseg else (1, max(seg.limit, 1))
+    spt = int(plan.meta["seg_spt"])
+    nt, ts = plan.vpu.rows.shape
+    return (nseg * w * WINDOW * plan.tc.cols.shape[-1]
+            + -(-nt // spt) * spt * ts)
+
+
 def _host_arrays(plan) -> dict[str, np.ndarray]:
     """Every device-uploadable array of one plan — its §4.3 segment
-    tables and, for SpMM, their revaluation position maps — host-side,
+    tables and, for SpMM, their revaluation position maps, for SDDMM
+    the combine's gather index — host-side,
     in its exact device dtype (so ``nbytes`` matches
     ``jax.Array.nbytes`` and a byte budget can be priced without
     uploading)."""

@@ -745,7 +745,7 @@ class Plan:
                ``take(out, reorder.row_inv, axis=0)`` (or keep permuted
                space and compose with ``row_perm`` themselves); SDDMM
                outputs land in original canonical order already (the
-               scatter maps were rewritten).
+               out-position maps were rewritten).
     """
 
     op: str

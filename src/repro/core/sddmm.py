@@ -3,8 +3,8 @@
 Output follows the canonical CSR (row-major, column-sorted) non-zero
 order of the mask matrix, so GNN attention pipelines can chain
 ``SDDMM → softmax-by-row → SpMM`` without reindexing — this holds even
-under ``ExecSpec.reorder``: the plan's scatter maps are rewritten back
-to original-canonical positions at build time, and the row-permuted
+under ``ExecSpec.reorder``: the plan's out-position maps are rewritten
+back to original-canonical positions at build time, and the row-permuted
 ``x`` operand is gathered once on the way in.
 
 Execution knobs live on one frozen :class:`repro.api.ExecSpec`
@@ -57,7 +57,7 @@ class LibraSDDMM:
         self.tune_config: TuneConfig = built.cfg
         self.plan: SDDMMPlan = built.plan
         self.reorder = built.reorder
-        # The SDDMM output scatter maps were rewritten to original
+        # The SDDMM out-position maps were rewritten to original
         # canonical positions at build time, so only the row operand
         # needs permuting: x_reordered = x[row_perm].
         self._row_perm = (None if built.reorder is None
@@ -89,7 +89,7 @@ class LibraSDDMM:
         backend = self.spec.backend if backend is None else backend
         if self._row_perm is not None:
             # Row-permuted plan: gather x into reordered row space (the
-            # output scatter maps already point back to original
+            # out-position maps already point back to original
             # canonical nnz order). Padding rows past m stay in place.
             perm = self._row_perm
             if x.shape[0] > self.m:

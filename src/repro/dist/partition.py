@@ -31,7 +31,7 @@ Each shard is then a self-contained Libra problem:
   construction*: padding SpMM segments carry zero values and length 0
   and scatter onto the last local row (keeping every row map
   non-decreasing), padding SDDMM segments carry bitmap 0 / mask False
-  and scatter into the swallow slot.
+  and the combine's gather index never names them.
 
 ``out_gather`` / ``nnz_gather`` invert the padding: one global ``take``
 reassembles the row-partitioned C (or the canonical nnz value vector)
@@ -50,8 +50,9 @@ from repro.core import preprocess
 from repro.core.balance import BalanceParams, balance_report
 from repro.core.formats import (
     WINDOW,
-    _sddmm_segment_arrays,
+    _sddmm_seg_tables,
     _spmm_segment_arrays,
+    sddmm_gather_map,
 )
 from repro.core.sddmm import threshold_for_mode as sddmm_threshold_for_mode
 from repro.core.spmm import threshold_for_mode as spmm_threshold_for_mode
@@ -406,26 +407,26 @@ def partition_spmm(a: SparseCSR, n_shards: int, *, mode=UNSET,
                                     else jnp.asarray(reord.nnz_perm)))
 
 
-def _stack_sddmm_segments(plans, n_shards) -> dict[str, np.ndarray]:
-    """SDDMM flavour of :func:`_stack_spmm_segments`. Out-positions stay
-    shard-local (the scatter targets the local nnz slice; ``nnz_gather``
-    reassembles) — padding carries bitmap 0 / mask False and pos −1/0,
-    which the swallow slot absorbs."""
-    seg_list = [_sddmm_segment_arrays(p) for p in plans]
+def _stack_sddmm_segments(plans, n_shards, nnz_pad) -> dict[str, np.ndarray]:
+    """SDDMM flavour of :func:`_stack_spmm_segments`. Each shard's
+    ``sddmm_src`` indexes its own stacked score buffer over its local
+    ``nnz_pad`` slice (``nnz_gather`` reassembles); cap padding carries
+    bitmap 0 / mask False and is never gathered, and padding non-zeros
+    read position 0, which ``nnz_gather`` drops."""
+    seg_list = [_sddmm_seg_tables(p) for p in plans]
     ns = max(s["tc_seg_window"].shape[0] for s in seg_list)
     wbk = seg_list[0]["tc_seg_cols"].shape[-1]
     cols = np.zeros((n_shards, ns, wbk), np.int32)
     bitmap = np.zeros((n_shards, ns, wbk), np.uint32)
     win = np.zeros((n_shards, ns), np.int32)
-    opos = np.full((n_shards, ns, WINDOW, wbk), -1, np.int32)
+    tc_opos = np.full((n_shards, ns, WINDOW, wbk), -1, np.int32)
     for p, s in enumerate(seg_list):
         k = s["tc_seg_window"].shape[0]
         cols[p, :k] = s["tc_seg_cols"]
         bitmap[p, :k] = s["tc_seg_bitmap"]
         win[p, :k] = s["tc_seg_window"]
-        opos[p, :k] = s["tc_seg_out_pos"]
-    out = dict(tc_seg_cols=cols, tc_seg_bitmap=bitmap, tc_seg_window=win,
-               tc_seg_out_pos=opos)
+        tc_opos[p, :k] = s["tc_seg_out_pos"]
+    out = dict(tc_seg_cols=cols, tc_seg_bitmap=bitmap, tc_seg_window=win)
     ns = max(s["vpu_seg_rows"].shape[0] for s in seg_list)
     w = seg_list[0]["vpu_seg_rows"].shape[-1]
     rows = np.zeros((n_shards, ns, w), np.int32)
@@ -438,8 +439,11 @@ def _stack_sddmm_segments(plans, n_shards) -> dict[str, np.ndarray]:
         cols[p, :k] = s["vpu_seg_cols"]
         opos[p, :k] = s["vpu_seg_out_pos"]
         mask[p, :k] = s["vpu_seg_mask"]
-    out.update(vpu_seg_rows=rows, vpu_seg_cols=cols, vpu_seg_out_pos=opos,
-               vpu_seg_mask=mask)
+    src = np.zeros((n_shards, nnz_pad), np.int32)
+    for p, plan in enumerate(plans):
+        src[p] = sddmm_gather_map(tc_opos[p], opos[p], mask[p], plan.nnz,
+                                  nnz_pad)
+    out.update(vpu_seg_rows=rows, vpu_seg_cols=cols, sddmm_src=src)
     return out
 
 
@@ -536,7 +540,7 @@ def partition_sddmm(a: SparseCSR, n_shards: int, *, mode=UNSET,
         nnz_gather = nnz_gather[reord.nnz_inv]
 
     host = dict(halo=_stack_halos(shards))
-    host.update(_stack_sddmm_segments(plans, n_shards))
+    host.update(_stack_sddmm_segments(plans, n_shards, nnz_pad))
     stacked = {k: jnp.asarray(v) for k, v in host.items()}
     meta = {
         "balance": balance_report(

@@ -146,7 +146,7 @@ def sddmm_sharded(part: SDDMMPartition, x: jnp.ndarray, y: jnp.ndarray, *,
     X is row-sharded to match the output rows (``part.x_take`` lays the
     global rows out in padded per-shard panels before the shard_map);
     Y follows ``y_layout`` like B in :func:`spmm_sharded`. Each shard
-    scatters into its local nnz slice; ``part.nnz_gather`` reassembles
+    gathers its local nnz slice; ``part.nnz_gather`` reassembles
     the canonical global vector — again no cross-device combine. With
     ``heads`` = H the values are ``(nnz, H)``, one score per head.
     """

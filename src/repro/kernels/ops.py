@@ -33,8 +33,8 @@ passes:
    of C and the row-sorted VPU partials scatter-add over them — the
    TPU-deterministic analogue of the paper's atomicAdd combine. Both are
    sorted scatters (plans keep windows and rows non-decreasing). SDDMM
-   combines both streams' scores with a single scatter into the
-   canonical nnz vector (multi-head: a gather by the inverse map).
+   puts both streams' scores in canonical nnz order with one gather,
+   by an inverse position map built with the plan, for any head count.
 4. **Segment-granular launch (§4.3).** A plan's device arrays are the
    hybrid balancer's Ts/Cs launch tables (``*_seg_*``), and the kernels
    run one *segment* per grid step: bounded work per step no matter
@@ -315,15 +315,15 @@ def sddmm_apply(arrs, x, y, *, nnz: int, backend: str = "xla",
     tiles — a lane-dense row is 128 wide in HBM regardless; padded
     features are zeros. With ``heads`` = H, X and Y hold H heads of
     ``kf / H`` columns each and the values are ``(nnz, H)``, one score
-    per head.
+    per head. ``nnz`` is the length of the plan's ``sddmm_src``.
     """
     cfg = DEFAULT_TUNE if cfg is None else cfg
+    assert arrs["sddmm_src"].shape == (nnz,), (arrs["sddmm_src"].shape, nnz)
     if heads == 1:
         return sddmm_apply(arrs, x, y, nnz=nnz, backend=backend, cfg=cfg,
                            interpret=interpret)[:, None]
     if backend == "xla":
-        return ref.sddmm_hybrid_ref(arrs, _pad_to(x, 0, WINDOW), y, nnz,
-                                    heads)
+        return ref.sddmm_hybrid_ref(arrs, _pad_to(x, 0, WINDOW), y, heads)
     head_dim = None
     if heads:
         assert x.shape[1] % heads == 0, (x.shape, heads)
@@ -335,38 +335,26 @@ def sddmm_apply(arrs, x, y, *, nnz: int, backend: str = "xla",
         x_p = _pad_to(x, 0, WINDOW)
         # §4.3 Ts decomposition: one grid step scores a whole segment of
         # ≤ ts blocks sharing a window — one 8×kf @ kf×(ts·bk) dot,
-        # bitmap-sampled (zero bitmap padding samples to zero and its
-        # out_pos −1 lands in the scatter's swallow slot).
+        # bitmap-sampled (zero bitmap padding samples to zero and is
+        # never gathered).
         s_tc = sddmm_mxu(arrs["tc_seg_cols"], arrs["tc_seg_bitmap"],
                          arrs["tc_seg_window"], x_p, y, kf_tile=kft,
                          heads=heads, head_dim=head_dim, interpret=interpret)
     with jax.named_scope("vpu"):
         # The Cs cap batches whole element tiles per VPU grid step.
-        vpu_mask = arrs["vpu_seg_mask"]
         s_el = sddmm_vpu(arrs["vpu_seg_rows"], arrs["vpu_seg_cols"], x, y,
                          kf_tile=kft, heads=heads, head_dim=head_dim,
                          interpret=interpret)
-    # Fused combine: one scatter of both streams into the canonical nnz
-    # vector (slot nnz swallows -1/masked padding).
+    # Fused combine: each non-zero's scores sit at exactly one position
+    # of the two streams' outputs laid end to end, so one gather by the
+    # plan's inverse position map (``sddmm_src``, built once with the
+    # plan) puts them in canonical nnz order. Padding is never read.
     with jax.named_scope("combine"):
-        if not heads:
-            s_el = jnp.where(vpu_mask, s_el, 0.0)
-        tc_pos = arrs["tc_seg_out_pos"]
-        pos_tc = jnp.where(tc_pos >= 0, tc_pos, nnz)
-        pos_el = jnp.where(vpu_mask, arrs["vpu_seg_out_pos"], nnz)
-        pos = jnp.concatenate([pos_tc.reshape(-1), pos_el.reshape(-1)])
-        if heads:
-            # Each non-zero's scores sit at exactly one output position,
-            # so each is gathered from there, by the position map's
-            # inverse (a function of the plan alone, shared by every call
-            # of a step). On a v5e a scatter of rows of H scores cost
-            # ~11× the single-head scatter per call.
-            src = jnp.zeros((nnz + 1,), jnp.int32).at[pos].set(
-                jnp.arange(pos.shape[0], dtype=jnp.int32))[:nnz]
-            data = jnp.concatenate(
-                [jnp.moveaxis(s_tc, 1, 0).reshape(heads, -1),
-                 s_el.reshape(heads, -1)], axis=1)     # (H, positions)
-            return jnp.take(data, src, axis=1).T
-        data = jnp.concatenate([s_tc.reshape(-1), s_el.reshape(-1)])
-        out = jnp.zeros((nnz + 1,), s_tc.dtype).at[pos].add(data)
-        return out[:nnz]
+        h = heads or 1
+        data = jnp.concatenate(
+            [jnp.moveaxis(s_tc.reshape(s_tc.shape[0], h, -1), 1, 0
+                          ).reshape(h, -1),
+             s_el.reshape(h, -1)], axis=1)             # (H, positions)
+        # (H, nnz); the map is in range by construction.
+        out = jnp.take(data, arrs["sddmm_src"], axis=1, mode="clip")
+        return out.T if heads else out[0]

@@ -110,37 +110,32 @@ def sddmm_tc_ref(tc_cols, tc_bitmap, tc_window, x, y, heads=None):
     return jnp.where(bitmap_mask(tc_bitmap), s, 0.0)
 
 
-def sddmm_vpu_ref(rows, cols, mask, x, y, heads=None):
-    """Element scores: (nt, ts) = ⟨X[row], Y[col]⟩ where mask;
-    (nt, ts, H) per head with ``heads``."""
+def sddmm_vpu_ref(rows, cols, x, y, heads=None):
+    """Element scores: (nt, ts) = ⟨X[row], Y[col]⟩; (nt, ts, H) per head
+    with ``heads``. Padding slots score row 0 against column 0, which
+    the combine never gathers."""
     xg = jnp.take(x, rows, axis=0)  # (nt, ts, kf)
     yg = jnp.take(y, cols, axis=0)
     if heads:
-        s = jnp.einsum("tjhc,tjhc->tjh", xg.reshape(*rows.shape, heads, -1),
-                       yg.reshape(*cols.shape, heads, -1))
-        return jnp.where(mask[..., None], s, 0.0)
-    s = jnp.einsum("tjk,tjk->tj", xg, yg)
-    return jnp.where(mask, s, 0.0)
+        return jnp.einsum("tjhc,tjhc->tjh",
+                          xg.reshape(*rows.shape, heads, -1),
+                          yg.reshape(*cols.shape, heads, -1))
+    return jnp.einsum("tjk,tjk->tj", xg, yg)
 
 
-def sddmm_hybrid_ref(arrs, x, y, nnz, heads=None):
+def sddmm_hybrid_ref(arrs, x, y, heads=None):
     """Hybrid SDDMM on the segment tables, producing the canonical
-    nnz-ordered value vector, ``(nnz, H)`` with ``heads`` (single fused
-    scatter; slot nnz swallows -1/masked padding)."""
+    nnz-ordered value vector, ``(nnz, H)`` with ``heads``: one gather of
+    both streams' scores by the plan's inverse position map
+    (``sddmm_src``), as the Pallas combine does."""
     s_tc = sddmm_tc_ref(arrs["tc_seg_cols"], arrs["tc_seg_bitmap"],
                         arrs["tc_seg_window"], x, y, heads)
-    mask = arrs["vpu_seg_mask"]
-    s_el = sddmm_vpu_ref(arrs["vpu_seg_rows"], arrs["vpu_seg_cols"], mask,
-                         x, y, heads)
-    tc_pos = arrs["tc_seg_out_pos"]
-    pos_tc = jnp.where(tc_pos >= 0, tc_pos, nnz)
-    pos_el = jnp.where(mask, arrs["vpu_seg_out_pos"], nnz)
-    pos = jnp.concatenate([pos_tc.reshape(-1), pos_el.reshape(-1)])
+    s_el = sddmm_vpu_ref(arrs["vpu_seg_rows"], arrs["vpu_seg_cols"], x, y,
+                         heads)
     tail = (heads,) if heads else ()
     data = jnp.concatenate([s_tc.reshape((-1,) + tail),
                             s_el.reshape((-1,) + tail)])
-    out = jnp.zeros((nnz + 1,) + tail, s_tc.dtype).at[pos].add(data)
-    return out[:nnz]
+    return jnp.take(data, arrs["sddmm_src"], axis=0, mode="clip")
 
 
 def revalue_spmm_arrays(arrs, edge_vals):

@@ -21,8 +21,8 @@ scores a whole segment of ≤ ``Ts`` blocks sharing a window — ``bk``
 becomes ``ts·bk`` concatenated condensed vectors, the step is a single
 ``8×kf @ kf×(ts·bk)`` dot, and the shared window's X rows are fetched
 once per segment instead of once per block. Zero-bitmap cap padding
-samples to zero and its ``out_pos`` −1 lands in the combine's swallow
-slot, so the kernel body is layout-agnostic (this docstring's "block"
+samples to zero and is never gathered by the combine, so the kernel
+body is layout-agnostic (this docstring's "block"
 then reads "segment").
 
 **Heads.** With ``H`` heads of width ``c`` (the layout of

@@ -81,11 +81,14 @@ def plan_counts(plan, kind: str) -> dict:
     launches over), their width ``cs`` and their slots (tiles × ``cs``).
     ``vpu_fetches`` is the B rows the VPU kernel fetches a lane tile:
     the sum of the SpMM segment lengths it launches with, every slot for
-    SDDMM.
+    SDDMM. SDDMM adds ``combine_slots``, the length of the score buffer
+    its combine gathers the ``nnz`` scores from (both streams' segment
+    tables, padding included): ``nnz ÷ combine_slots`` is the share of
+    scored cells that are real.
     :func:`_padding_report` derives the padding from these, and the
     ``preprocess.spmm``/``preprocess.sddmm`` spans of an enabled tracer
     carry them as attributes."""
-    from repro.core.formats import spmm_vpu_seg_len
+    from repro.core.formats import sddmm_combine_slots, spmm_vpu_seg_len
 
     tc, vpu = plan.tc, plan.vpu
     if kind == "spmm":
@@ -94,7 +97,7 @@ def plan_counts(plan, kind: str) -> dict:
     else:  # COOTiles: mask marks real elements
         slots, vpu_nnz = vpu.mask, int(vpu.mask.sum())
         fetches = int(slots.size)
-    return {
+    out = {
         "tc_nnz": int(tc.nnz),
         "mxu_blocks": int(tc.cols.shape[0]),
         "bk": int(tc.cols.shape[1]),
@@ -105,6 +108,9 @@ def plan_counts(plan, kind: str) -> dict:
         "vpu_slots": int(slots.size),
         "vpu_fetches": fetches,
     }
+    if kind == "sddmm":
+        out["combine_slots"] = sddmm_combine_slots(plan)
+    return out
 
 
 def head_counts(heads: int, head_dim: int, tile: int) -> dict:

@@ -112,9 +112,8 @@ class TestLazyBitIdentity:
         assert view_of_key("tc_seg_pos") == "revalue"
         assert view_of_key("vpu_seg_pos") == "revalue"
         assert view_of_key("tc_seg_vals") == "segment"
-        # SDDMM scatter maps are structural, not revalue
-        assert view_of_key("tc_seg_out_pos") == "segment"
-        assert view_of_key("vpu_seg_out_pos") == "segment"
+        # SDDMM's combine gather index is structural, not revalue
+        assert view_of_key("sddmm_src") == "segment"
 
     def test_vpu_seg_len_in_pallas_segment_view_only(self, corpus):
         a = next(iter(corpus.values()))

@@ -116,6 +116,71 @@ def test_multihead_sddmm_matches_per_head_oracle(graph, ops, heads, head_dim,
                                rtol=1e-4, atol=1e-4)
 
 
+def _scatter_combine(op, x, y, heads):
+    """The scores of ``sddmm_apply``'s two kernels, combined by a
+    scatter-add into ``nnz + 1`` slots through the host out-position
+    tables (slot ``nnz`` swallows the padding): ``(nnz, H)``."""
+    from repro.core.formats import WINDOW, _sddmm_seg_tables
+    from repro.kernels.ops import _pad_to
+    from repro.kernels.sddmm_mxu import sddmm_mxu
+    from repro.kernels.sddmm_vpu import sddmm_vpu
+    from repro.tune.model import lane_tile
+
+    kh = None if heads == 1 else heads
+    h = heads or 1
+    t = {k: jnp.asarray(v) for k, v in _sddmm_seg_tables(op.plan).items()}
+    kft = lane_tile("sddmm", x.shape[1], op.tune_config, heads=kh)
+    head_dim = x.shape[1] // kh if kh else None
+    x = _pad_to(jnp.asarray(x), 1, kft)
+    y = _pad_to(jnp.asarray(y), 1, kft)
+    s_tc = np.asarray(sddmm_mxu(
+        t["tc_seg_cols"], t["tc_seg_bitmap"], t["tc_seg_window"],
+        _pad_to(x, 0, WINDOW), y, kf_tile=kft, heads=kh, head_dim=head_dim))
+    s_el = np.asarray(sddmm_vpu(t["vpu_seg_rows"], t["vpu_seg_cols"], x, y,
+                                kf_tile=kft, heads=kh, head_dim=head_dim))
+    data = np.concatenate([
+        np.moveaxis(s_tc.reshape(s_tc.shape[0], h, -1), 1, -1).reshape(-1, h),
+        s_el.reshape(h, -1).T])
+    nnz = op.nnz
+    tc_pos = np.asarray(t["tc_seg_out_pos"]).reshape(-1)
+    el_pos = np.where(t["vpu_seg_mask"], t["vpu_seg_out_pos"], nnz)
+    pos = np.concatenate([np.where(tc_pos >= 0, tc_pos, nnz),
+                          np.asarray(el_pos).reshape(-1)])
+    out = np.zeros((nnz + 1, h), np.float32)
+    np.add.at(out, pos, data)
+    return out[:nnz]
+
+
+@pytest.mark.parametrize("one_unit", [False, True])
+@pytest.mark.parametrize("head_dim", [40, 64])
+@pytest.mark.parametrize("heads", [None, 1, 4])
+def test_sddmm_gather_combine_is_the_scatter_add(graph, ops, heads,
+                                                  head_dim, one_unit):
+    """The Pallas SDDMM's combine, a gather by the plan's inverse
+    position map, gives bit for bit what a scatter-add of the same
+    kernels' scores through the host out-position tables gives (each
+    non-zero's one contribution added to zero), at every head count,
+    and still matches the dense oracle head by head."""
+    a = graph[0]
+    op = ops[one_unit][1]
+    h = heads or 1
+    rng = np.random.default_rng(h * 100 + head_dim)
+    x = rng.standard_normal((a.m, h * head_dim)).astype(np.float32)
+    y = rng.standard_normal((a.k, h * head_dim)).astype(np.float32)
+    got = _sddmm(op, x, y, "pallas", heads)
+    want = _scatter_combine(op, x, y, heads)
+    assert got.shape == ((a.nnz,) if heads is None else (a.nnz, h))
+    np.testing.assert_array_equal(got.reshape(a.nnz, h), want)
+    _, rows, cols = graph
+    mask = np.zeros((a.m, a.k))
+    mask[rows, cols] = 1.0
+    oracle = np.stack([ref.sddmm_dense_oracle(
+        mask, x[:, i * head_dim:(i + 1) * head_dim],
+        y[:, i * head_dim:(i + 1) * head_dim]) for i in range(h)], axis=1)
+    np.testing.assert_allclose(got.reshape(a.nnz, h), oracle,
+                               rtol=1e-4, atol=1e-4)
+
+
 def test_multihead_spmm_block_outer_grid(graph, ops):
     """The lane tile's head map follows the lane axis in either grid
     order (a head of 40 straddles the first two 128-lane tiles)."""

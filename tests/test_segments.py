@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.api import ExecSpec
 from repro.core import preprocess
 from repro.core.balance import (
     BalanceParams,
@@ -253,8 +254,10 @@ def test_one_unit_tables_are_the_per_block_tensors(mode):
     the launch tables are the per-block / per-tile host tensors, element
     for element, same shapes and dtypes — for SpMM's MXU and VPU streams
     and SDDMM's. SDDMM's VPU table is the per-tile one whenever a
-    segment holds one tile, whatever ``ts``."""
-    from repro.core.formats import _host_arrays
+    segment holds one tile, whatever ``ts``. SDDMM's out-position tables
+    and mask are host tables (:func:`_sddmm_seg_tables`), folded into
+    the device's ``sddmm_src``."""
+    from repro.core.formats import _host_arrays, _sddmm_seg_tables
 
     a = _skewed(seed=4)
     cfg = TuneConfig(ts=1, cs=16, bk=8, ts_tile=16)
@@ -276,7 +279,7 @@ def test_one_unit_tables_are_the_per_block_tensors(mode):
     for sd_cfg in (cfg, cfg.replace(ts=None)):
         plan = preprocess.preprocess_sddmm(
             a, preprocess.threshold_for_mode_sddmm(mode, 8), cfg=sd_cfg)
-        host, tc, vpu = _host_arrays(plan), plan.tc, plan.vpu
+        host, tc, vpu = _sddmm_seg_tables(plan), plan.tc, plan.vpu
         assert plan.meta["seg_spt"] == 1
         same(host["vpu_seg_rows"], vpu.rows)
         same(host["vpu_seg_cols"], vpu.cols)
@@ -284,7 +287,7 @@ def test_one_unit_tables_are_the_per_block_tensors(mode):
         same(host["vpu_seg_mask"], vpu.mask)
     plan = preprocess.preprocess_sddmm(
         a, preprocess.threshold_for_mode_sddmm(mode, 8), cfg=cfg)
-    host, tc = _host_arrays(plan), plan.tc
+    host, tc = _sddmm_seg_tables(plan), plan.tc
     same(host["tc_seg_cols"], tc.cols)
     same(host["tc_seg_bitmap"], tc.bitmap)
     same(host["tc_seg_window"], tc.window)
@@ -356,6 +359,117 @@ def test_partition_stacks_vpu_seg_len_with_zero_padding():
     # shards with fewer segments are padded with empty ones: length 0
     empty = ~real.any(axis=-1)
     assert empty.any() and (lens[empty] == 0).all()
+
+
+# ------------------------------------------------- SDDMM gather map ---
+def _score_owners(tc_out_pos, vpu_out_pos, vpu_mask, ns=None, ns_vpu=None):
+    """The canonical non-zero each position of the combine's score
+    buffer holds (−1 on padding), in the kernels' order: the MXU cells,
+    then the VPU slots; ``ns``/``ns_vpu`` pad to a stacked shard's
+    segment counts."""
+    tc = np.full((ns or tc_out_pos.shape[0],) + tc_out_pos.shape[1:], -1)
+    tc[:tc_out_pos.shape[0]] = tc_out_pos
+    el = np.full((ns_vpu or vpu_out_pos.shape[0], vpu_out_pos.shape[1]), -1)
+    el[:vpu_out_pos.shape[0]] = np.where(vpu_mask, vpu_out_pos, -1)
+    return np.concatenate([tc.reshape(-1), el.reshape(-1)])
+
+
+def _assert_names_each_once(src, owners, nnz):
+    """``src`` names every non-zero exactly once, in range, never a
+    padding position."""
+    assert src.dtype == np.int32
+    assert ((src >= 0) & (src < owners.size)).all()
+    assert np.array_equal(owners[src[:nnz]], np.arange(nnz))
+    assert int((owners >= 0).sum()) == nnz
+
+
+def _sddmm_plans(name, variant):
+    """(nnz, src, owners) per plan of one corpus matrix, built plain,
+    reorder-remapped, or partitioned over three shards."""
+    from repro.core.formats import _host_arrays, _sddmm_seg_tables
+    from repro.dist.partition import partition_sddmm
+
+    a = _len_corpus()[name]
+    if variant == "partitioned":
+        part = partition_sddmm(a, 3, spec=ExecSpec(tune="off"))
+        src = np.asarray(part.stacked["sddmm_src"])
+        assert src.shape == (3, part.nnz_pad)
+        ns = part.stacked["tc_seg_window"].shape[1]
+        ns_vpu = part.stacked["vpu_seg_rows"].shape[1]
+        out = []
+        for shard in part.shards:
+            cfg = shard.cfg
+            plan = preprocess.preprocess_sddmm(
+                shard.csr, preprocess.threshold_for_mode_sddmm(
+                    "hybrid", cfg.bk, cfg.threshold), cfg=cfg)
+            t = _sddmm_seg_tables(plan)
+            owners = _score_owners(t["tc_seg_out_pos"],
+                                   t["vpu_seg_out_pos"], t["vpu_seg_mask"],
+                                   ns, ns_vpu)
+            out.append((shard.nnz, src[shard.index], owners))
+        return out
+    out = []
+    for cfg in (TuneConfig(), TuneConfig(ts=2, cs=16, ts_tile=8)):
+        plan = preprocess.preprocess_sddmm(a, cfg=cfg)
+        if variant == "reordered":
+            perm = np.random.default_rng(5).permutation(a.nnz)
+            plan = preprocess._remap_sddmm_plan(plan, perm)
+        t = _sddmm_seg_tables(plan)
+        owners = _score_owners(t["tc_seg_out_pos"], t["vpu_seg_out_pos"],
+                               t["vpu_seg_mask"])
+        out.append((plan.nnz, _host_arrays(plan)["sddmm_src"], owners))
+    return out
+
+
+@pytest.mark.parametrize("variant", ["plain", "reordered", "partitioned"])
+@pytest.mark.parametrize("name", sorted(_len_corpus()))
+def test_sddmm_gather_map_names_each_nonzero_once(name, variant):
+    """The plan-time inverse map the SDDMM combine gathers by names
+    every non-zero exactly once, in range, and never a padding position
+    — on plain plans, reorder-remapped ones and the partitioner's
+    stacked shards (whose padding non-zeros read an in-range 0)."""
+    for nnz, src, owners in _sddmm_plans(name, variant):
+        _assert_names_each_once(src, owners, nnz)
+        if variant == "partitioned":
+            assert (src[nnz:] == 0).all()
+        else:
+            assert src.shape == (nnz,)
+
+
+@pytest.mark.parametrize("name", sorted(_len_corpus()))
+def test_sddmm_device_tables_hold_the_map_not_the_out_positions(name):
+    """The out-position tables and the VPU mask stay on the host:
+    the device holds the map, and ``combine_slots`` is the length of
+    the score buffer it indexes."""
+    from repro.core.formats import PlanArrays, _sddmm_seg_tables
+    from repro.obs.explain import plan_counts
+
+    for cfg in (TuneConfig(), TuneConfig(ts=2, cs=16, ts_tile=8)):
+        plan = preprocess.preprocess_sddmm(_len_corpus()[name], cfg=cfg)
+        keys = PlanArrays(plan).apply_keys()
+        assert "sddmm_src" in keys
+        assert not {"tc_seg_out_pos", "vpu_seg_out_pos",
+                    "vpu_seg_mask"} & set(keys)
+        t = _sddmm_seg_tables(plan)
+        slots = t["tc_seg_out_pos"].size + t["vpu_seg_out_pos"].size
+        assert plan_counts(plan, "sddmm")["combine_slots"] == slots
+        assert slots == t["tc_seg_cols"].size * WINDOW \
+            + t["vpu_seg_rows"].size
+
+
+@pytest.mark.parametrize("fault", ["missing", "twice", "out_of_range"])
+def test_sddmm_gather_map_refuses_a_plan_that_misplaces_a_nonzero(fault):
+    from repro.core.formats import sddmm_gather_map
+
+    tc = np.array([[[0, -1], [1, -1]]], np.int32)       # (1, 8→2, 2)
+    el = np.array([[2, 3, 0]], np.int32)
+    mask = np.array([[True, True, False]])
+    assert np.array_equal(sddmm_gather_map(tc, el, mask, 4), [0, 2, 4, 5])
+    bad = {"missing": (tc, el, mask & [[True, False, False]], 4),
+           "twice": (tc, np.array([[2, 2, 0]], np.int32), mask, 4),
+           "out_of_range": (tc, el, mask, 3)}[fault]
+    with pytest.raises(ValueError, match="exactly one position"):
+        sddmm_gather_map(*bad)
 
 
 # --------------------------------------------------- tuner / cache ---

@@ -132,7 +132,8 @@ def _kernel_scopes(text: str) -> dict[str, str]:
 @pytest.mark.parametrize("kind", ["spmm", "sddmm"])
 def test_applies_name_their_kernels_and_scopes(one_chip, graphs, kind):
     """The compiled applies carry the kernel names and the ``mxu`` /
-    ``vpu`` / ``combine`` scopes that a profile is split by."""
+    ``vpu`` / ``combine`` scopes that a profile is split by. SpMM's
+    combine scatter-adds; SDDMM's only gathers."""
     a = graphs["powerlaw"]
     if kind == "spmm":
         op = LibraSpMM(a, spec=ExecSpec(backend="pallas", tune="model",
@@ -159,7 +160,17 @@ def test_applies_name_their_kernels_and_scopes(one_chip, graphs, kind):
         assert f"/{stream}/" in op_name, op_name
         assert op_name.endswith(f"{kind}_{stream}/pallas_call"), op_name
     assert len(kernels) == 2, kernels
-    assert re.search(r'op_name="[^"]*/combine/[^"]*scatter-add', text)
+    combine = [line for line in text.splitlines()
+               if re.search(r'op_name="[^"]*/combine/', line)]
+    if kind == "spmm":
+        assert any(re.search(r'op_name="[^"]*/combine/[^"]*scatter-add',
+                             line) for line in combine)
+    else:
+        # SDDMM's combine gathers by the plan's inverse position map:
+        # no scatter, over nnz + 1 elements or any other.
+        assert not any("scatter" in line for line in combine), \
+            [line for line in combine if "scatter" in line]
+        assert any(re.search(r"\bgather\(", line) for line in combine)
 
 
 @pytest.mark.parametrize("heads,head_dim", [(4, 64), (4, 40)])
