@@ -214,12 +214,37 @@ def make_unimp_train_step(g, lr: float = 0.2):
     return unimp_train_step
 
 
+def exphormer_loss(params, g, gx, feats, labels, norm_vals):
+    from repro.models.gnn import exphormer_forward
+
+    logits = exphormer_forward(params, g, gx, feats, norm_vals)
+    lp = jax.nn.log_softmax(logits)
+    return -jnp.take_along_axis(lp, labels[:, None], axis=1).mean()
+
+
+def make_exphormer_train_step(g, gx, lr: float = 0.2):
+    """Jitted SGD step for Exphormer: per GPS block a GCN SpMM over ``g``
+    and one attention over ``g``'s edges, ``gx``'s (the expander) and a
+    virtual node's, with one softmax across them; named
+    ``exphormer_train_step`` in a profile. The GCN's ``Â`` values are an
+    argument of the step, as in :func:`make_gcn_train_step`."""
+    @jax.jit
+    def exphormer_train_step(params, feats, labels, norm_vals):
+        loss, grads = jax.value_and_grad(exphormer_loss)(
+            params, g, gx, feats, labels, norm_vals)
+        new = jax.tree.map(lambda p, gg: p - lr * gg, params, grads)
+        return new, loss
+    return exphormer_train_step
+
+
 __all__ = [
     "DistGraphOps",
     "agnn_loss",
     "edge_softmax",
+    "exphormer_loss",
     "gcn_loss",
     "make_agnn_train_step",
+    "make_exphormer_train_step",
     "make_gcn_train_step",
     "make_unimp_train_step",
     "unimp_loss",

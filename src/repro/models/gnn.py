@@ -1,4 +1,5 @@
-"""GNN models (GCN, AGNN, UniMP) on Libra hybrid sparse operators.
+"""GNN models (GCN, AGNN, UniMP, Exphormer) on Libra hybrid sparse
+operators.
 
 This is the paper's end-to-end application (§5.5): SpMM performs feature
 aggregation, SDDMM computes per-edge attention. Gradients follow the
@@ -9,7 +10,9 @@ the transposed plan (for features) plus an SDDMM with the same sparsity
 Edge values are ``(nnz,)``, or ``(nnz, H)`` for multi-head attention
 (UniMP): the dense operands then hold H heads of ``c`` features
 contiguously, and every sparse call runs all heads fused
-(:mod:`repro.kernels.ops`).
+(:mod:`repro.kernels.ops`). Exphormer attends over several edge types,
+each sparse type through a :class:`GraphOps` of its own, with one
+softmax across them (:func:`edge_softmax` with ``more``).
 """
 from __future__ import annotations
 
@@ -271,14 +274,48 @@ def _sddmm_ev_bwd(g, heads, resid, d_vals):
 _sddmm_ev.defvjp(_sddmm_ev_fwd, _sddmm_ev_bwd)
 
 
-def edge_softmax(g: GraphOps, scores):
+def edge_softmax(g: GraphOps, scores, *, more=(), dense=None):
     """Numerically stable per-destination-row softmax over edge scores,
-    ``(nnz,)`` or ``(nnz, H)`` (each head apart)."""
-    with jax.named_scope("edge_softmax"):
-        mx = jax.ops.segment_max(scores, g.edge_row, num_segments=g.m)
-        e = jnp.exp(scores - mx[g.edge_row])
-        z = jax.ops.segment_sum(e, g.edge_row, num_segments=g.m)
-        return e / jnp.maximum(z[g.edge_row], 1e-9)
+    ``(nnz,)`` or ``(nnz, H)`` (each head apart).
+
+    With ``more`` further ``(ops, scores)`` parts over the same rows
+    (each its own :class:`GraphOps`, so a pair may be an edge of several
+    parts and counts once in each) and ``dense``, one more score per row
+    (``(m,)`` or ``(m, H)``), the softmax of a row spans all its edges of
+    every part and its dense score together: one row max and one row sum
+    across them, under the ``edge_softmax/typed_softmax`` scope. Returns
+    the weights of each part in order, then the dense term's; the
+    one-part call returns its weights alone, as it always has."""
+    if not more and dense is None:
+        with jax.named_scope("edge_softmax"):
+            mx = jax.ops.segment_max(scores, g.edge_row, num_segments=g.m)
+            e = jnp.exp(scores - mx[g.edge_row])
+            z = jax.ops.segment_sum(e, g.edge_row, num_segments=g.m)
+            return e / jnp.maximum(z[g.edge_row], 1e-9)
+    parts = [(g, scores), *more]
+    if any(ops.m != g.m for ops, _ in parts):
+        raise ValueError("edge_softmax: parts over different row counts "
+                         f"{[ops.m for ops, _ in parts]}")
+    with jax.named_scope("edge_softmax"), jax.named_scope("typed_softmax"):
+        # The rows' max only steadies the exponent; its gradient cancels.
+        tops = [jax.ops.segment_max(s, ops.edge_row, num_segments=g.m,
+                                    indices_are_sorted=True)
+                for ops, s in parts]
+        if dense is not None:
+            tops.append(dense)
+        mx = jax.lax.stop_gradient(functools.reduce(jnp.maximum, tops))
+        es = [jnp.exp(s - mx[ops.edge_row]) for ops, s in parts]
+        sums = [jax.ops.segment_sum(e, ops.edge_row, num_segments=g.m,
+                                    indices_are_sorted=True)
+                for e, (ops, _) in zip(es, parts)]
+        if dense is not None:
+            es.append(jnp.exp(dense - mx))
+            sums.append(es[-1])
+        z = jnp.maximum(functools.reduce(jnp.add, sums), 1e-9)
+        out = [e / z[ops.edge_row] for e, (ops, _) in zip(es, parts)]
+        if dense is not None:
+            out.append(es[-1] / z)
+        return tuple(out)
 
 
 # ------------------------------------------------------------------ GCN ---
@@ -417,3 +454,182 @@ def unimp_forward(params, g: GraphOps, x):
         if not last:
             h = jax.nn.relu(_layer_norm(h, lp["ln_g"], lp["ln_b"]))
     return h
+
+
+# ------------------------------------------------------------ Exphormer ---
+#: Exphormer's score clamp (the authors' ``clamp(-5, 5)`` before ``exp``).
+SCORE_CLAMP = 5.0
+
+#: Exphormer's edge types, each with a key scaling of its own.
+EDGE_TYPES = ("graph", "expander", "virtual")
+
+
+def expander_csr(n: int, perms: int, seed: int) -> SparseCSR:
+    """Exphormer's expander over ``n`` nodes (Shirzad et al.,
+    arXiv:2303.06147): for each of ``perms`` random permutations ``π``
+    drawn from ``seed``, the pairs ``(i, π(i))`` and ``(π(i), i)``; self
+    pairs and repeats dropped. Symmetric, at most ``2·perms`` non-zeros a
+    row, values 1, the same for the same arguments. Under an enabled
+    tracer one ``exphormer.expander`` span with counters ``nodes``,
+    ``perms``, ``nnz`` and ``dropped`` (pairs drawn less ``nnz``)."""
+    with get_tracer().span("exphormer.expander", nodes=n,
+                           perms=perms) as sp:
+        rng = np.random.default_rng(seed)
+        src = np.tile(np.arange(n, dtype=np.int64), perms)
+        dst = np.concatenate([rng.permutation(n) for _ in range(perms)])
+        rows = np.concatenate([src, dst])
+        cols = np.concatenate([dst, src])
+        keep = rows != cols
+        key = np.unique(rows[keep] * n + cols[keep])
+        sp.set(nnz=int(key.size), dropped=int(rows.size - key.size))
+        return coo_to_csr(n, n, key // n, key % n,
+                          np.ones(key.size, np.float32))
+
+
+def init_exphormer(rng, dims: list[int], heads: int):
+    """Exphormer with GraphGPS blocks: an encoder ``dims[0] → w``
+    (``w = dims[1]``), ``len(dims) - 1`` GPS blocks of width ``w`` in
+    ``heads`` heads of ``w // heads``, and a head ``w → dims[-1]``;
+    the virtual node's starting state ``virtual``. Per block: the GCN
+    local's ``l_w``, ``l_b``; query, key and value weights ``(w, H, c)``
+    and biases ``(H, c)``; one key scaling ``(H, c)`` per edge type
+    (``e_graph``, ``e_expander``, ``e_virtual``); the attention output's
+    ``o_w``, ``o_b``; the FFN's ``f1`` (``w → 2w``) and ``f2``; three
+    BatchNorms' scales and shifts. Weights Glorot-scaled normal, biases
+    uniform in ``±1/√fan_in``, type scalings and ``virtual`` standard
+    normal, BatchNorm 1 and 0."""
+    d_in, w, n_out = dims[0], dims[1], dims[-1]
+    if any(d != w for d in dims[1:-1]):
+        raise ValueError(f"init_exphormer: one hidden width, got {dims}")
+    c = w // heads
+    keys = iter(jax.random.split(rng, 5 + 17 * (len(dims) - 1)))
+
+    def weight(shape, fan):
+        return jax.random.normal(next(keys), shape, jnp.float32) / np.sqrt(fan)
+
+    def bias(shape, fan):
+        b = 1.0 / np.sqrt(fan)
+        return jax.random.uniform(next(keys), shape, jnp.float32, -b, b)
+
+    def normal(shape):
+        return jax.random.normal(next(keys), shape, jnp.float32)
+
+    params = {"in_w": weight((d_in, w), d_in), "in_b": bias((w,), d_in),
+              "virtual": normal((w,)), "layers": []}
+    for _ in range(len(dims) - 1):
+        lp = {"l_w": weight((w, w), w), "l_b": bias((w,), w)}
+        for name in ("q", "k", "v"):
+            lp[f"{name}_w"] = weight((w, heads, c), w)
+            lp[f"{name}_b"] = bias((heads, c), w)
+        for t in EDGE_TYPES:
+            lp[f"e_{t}"] = normal((heads, c))
+        lp.update(o_w=weight((w, w), w), o_b=bias((w,), w),
+                  f1_w=weight((w, 2 * w), w), f1_b=bias((2 * w,), w),
+                  f2_w=weight((2 * w, w), 2 * w), f2_b=bias((w,), 2 * w))
+        for i in (1, 2, 3):
+            lp[f"bn{i}_g"] = jnp.ones((w,), jnp.float32)
+            lp[f"bn{i}_b"] = jnp.zeros((w,), jnp.float32)
+        params["layers"].append(lp)
+    params.update(out_w=weight((w, n_out), w), out_b=bias((n_out,), w))
+    return params
+
+
+def _batch_norm(h, scale, shift, eps: float = 1e-5):
+    """BatchNorm in training mode: each feature by its batch statistics
+    over the rows."""
+    mu = h.mean(axis=0)
+    var = ((h - mu) ** 2).mean(axis=0)
+    return (h - mu) * jax.lax.rsqrt(var + eps) * scale + shift
+
+
+def _clamp(s):
+    return jnp.clip(s, -SCORE_CLAMP, SCORE_CLAMP)
+
+
+def _exphormer_attention(lp, g: GraphOps, gx: GraphOps, h):
+    """One attention over the interaction graph of ``h``'s ``n`` real
+    rows and its virtual row ``n``: per head, ``s_ij = clamp((q_i ⊙
+    e_t)·k_j / √c, ±5)`` on each edge of type ``t``, one softmax over all
+    typed edges of a row, ``m_i = Σ α_ij v_j``. Graph edges (``g``'s
+    plan) and expander edges (``gx``'s) run the fused multi-head SDDMM
+    and SpMM; the virtual node's edges (each real row to it, it to every
+    real row) and its own graph self loop are dense."""
+    n = g.m
+    w, heads, c = lp["q_w"].shape
+    with jax.named_scope("qkv"):
+        def proj(name):
+            return h @ lp[f"{name}_w"].reshape(w, -1) \
+                + lp[f"{name}_b"].reshape(-1)
+        # 1/√c rides on q, so the scores leave the SDDMMs scaled.
+        q = proj("q") * (1.0 / np.sqrt(c))
+        k, v = proj("k"), proj("v")
+    q_r, k_r, v_r = q[:n], k[:n], v[:n]
+    q_n, k_n, v_n = (t[n].reshape(heads, c) for t in (q, k, v))
+    e = {t: lp[f"e_{t}"] for t in EDGE_TYPES}
+    tr = get_tracer()
+    types = NULL_SPAN
+    if tr.enabled:
+        from repro.obs.explain import type_counts
+
+        types = tr.span("graphops.types", **type_counts(
+            {"graph": g.nnz, "expander": gx.nnz}, 2 * n + 1))
+    with types:
+        with jax.named_scope("attn"), jax.named_scope("graph"):
+            s_g = _clamp(g.sddmm(q_r * e["graph"].reshape(-1), k_r,
+                                 heads=heads))
+        with jax.named_scope("attn"), jax.named_scope("expander"):
+            s_x = _clamp(gx.sddmm(q_r * e["expander"].reshape(-1), k_r,
+                                  heads=heads))
+        with jax.named_scope("attn"), jax.named_scope("virtual"):
+            k_r3 = k_r.reshape(n, heads, c)
+            s_v = _clamp(jnp.einsum("nhc,hc->nh",
+                                    q_r.reshape(n, heads, c) * e["virtual"],
+                                    k_n))
+        a_g, a_x, a_v = edge_softmax(g, s_g, more=[(gx, s_x)], dense=s_v)
+        with jax.named_scope("attn"), jax.named_scope("graph"):
+            m = g.spmm(a_g, v_r)
+        with jax.named_scope("attn"), jax.named_scope("expander"):
+            m = m + gx.spmm(a_x, v_r)
+        with jax.named_scope("attn"), jax.named_scope("virtual"):
+            m = m + (a_v[:, :, None] * v_n).reshape(n, w)
+            # The virtual row: its edge to every real row and its graph
+            # self loop, in one softmax over n + 1 scores a head.
+            s_out = _clamp(jnp.einsum("hc,nhc->nh", q_n * e["virtual"], k_r3))
+            s_self = _clamp(jnp.sum(q_n * e["graph"] * k_n, axis=-1))
+            a_n = jax.nn.softmax(jnp.concatenate([s_out, s_self[None]]),
+                                 axis=0)
+            m_n = (jnp.einsum("nh,nhc->hc", a_n[:n],
+                              v_r.reshape(n, heads, c))
+                   + a_n[n][:, None] * v_n)
+        return jnp.concatenate([m, m_n.reshape(1, w)])
+
+
+def _gps_layer(lp, g: GraphOps, gx: GraphOps, h, norm_vals):
+    """One GPS block: ``l = BN₁(h + Â h W_l + b_l)`` (the virtual row's
+    only graph edge its self loop, ``Â_nn = 1``), ``a = BN₂(h + m W_o +
+    b_o)`` with ``m`` the typed attention, ``y = l + a``, ``h' = BN₃(y +
+    W₂ ReLU(W₁ y + b₁) + b₂)``."""
+    n = g.m
+    with jax.named_scope("gps"), jax.named_scope("local"):
+        z = h @ lp["l_w"]
+        agg = jnp.concatenate([g.spmm(norm_vals, z[:n]), z[n:]])
+        local = _batch_norm(h + agg + lp["l_b"], lp["bn1_g"], lp["bn1_b"])
+    m = _exphormer_attention(lp, g, gx, h)
+    att = _batch_norm(h + m @ lp["o_w"] + lp["o_b"], lp["bn2_g"], lp["bn2_b"])
+    with jax.named_scope("gps"), jax.named_scope("ffn"):
+        y = local + att
+        f = jax.nn.relu(y @ lp["f1_w"] + lp["f1_b"]) @ lp["f2_w"] + lp["f2_b"]
+        return _batch_norm(y + f, lp["bn3_g"], lp["bn3_b"])
+
+
+def exphormer_forward(params, g: GraphOps, gx: GraphOps, x, norm_vals):
+    """Exphormer (:func:`init_exphormer`) on ``x``'s ``n`` real nodes and
+    one virtual node: encoder, GPS blocks over ``g`` (the graph with self
+    loops, the GCN local's ``Â`` values ``norm_vals`` in its order) and
+    ``gx`` (the expander, :func:`expander_csr`), then the head on the
+    real rows: the logits, ``(n, classes)``."""
+    h = jnp.concatenate([x @ params["in_w"] + params["in_b"],
+                         params["virtual"][None]])
+    for lp in params["layers"]:
+        h = _gps_layer(lp, g, gx, h, norm_vals)
+    return h[:g.m] @ params["out_w"] + params["out_b"]

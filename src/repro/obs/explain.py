@@ -138,6 +138,19 @@ def tile_counts(width: int, tile: int) -> dict:
     return {"width": width, "tile": tile, "lane_tiles": -(-width // tile)}
 
 
+def type_counts(nnz: dict[str, int], dense_edges: int) -> dict:
+    """The edge types of one attention over several sparsity patterns:
+    ``nnz`` the non-zeros of each sparse type's plan, by type name, and
+    ``dense_edges`` the edges its dense ops score (Exphormer's virtual
+    node: ``2n`` edges and its self loop). ``types`` counts the sparse
+    types, and one more where there are dense edges. The typed attention
+    of :func:`repro.models.gnn.exphormer_forward` carries these as the
+    attributes of its ``graphops.types`` spans on an enabled tracer."""
+    return {"types": len(nnz) + (dense_edges > 0),
+            **{f"nnz_{t}": int(v) for t, v in nnz.items()},
+            "dense_edges": int(dense_edges)}
+
+
 def _padding_report(plan, kind: str) -> dict:
     """Zero padding materialized by the condensed formats (bytes the
     kernels stream but the matrix never had)."""

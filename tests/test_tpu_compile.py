@@ -311,3 +311,39 @@ def test_vmem_model_covers_what_mosaic_allocates(one_chip, monkeypatch,
     # earlier trace of the same shapes, made without the limit, is
     # reused.
     assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
+
+
+def test_expander_applies_compile_for_v5e(one_chip):
+    """Exphormer's expander plans (a locality-free pattern of degree at
+    most 6), tuned as the benchmark tunes them: the four-head SpMM and
+    SDDMM at 4 heads of 64 compile, kernels named ``_mh``."""
+    from repro.kernels import ref
+    from repro.models.gnn import expander_csr
+
+    a = expander_csr(NODES, 3, seed=5)
+    heads, width = 4, 256
+    spec = ExecSpec(backend="pallas", tune="model")
+    sp, sd = LibraSpMM(a, spec=spec), LibraSDDMM(a, spec=spec)
+    ev = jax.ShapeDtypeStruct((a.nnz, heads), jnp.float32,
+                              sharding=one_chip)
+    dense = jax.ShapeDtypeStruct((a.k, width), jnp.float32,
+                                 sharding=one_chip)
+
+    def spmm(arrs, ev, b):
+        return spmm_apply(ref.revalue_spmm_arrays(arrs, ev), b, m=sp.m,
+                          nwin=sp.nwin, backend="pallas",
+                          cfg=sp.tune_config, interpret=False)
+
+    compiled = {
+        "spmm": jax.jit(spmm).lower(
+            _shapes(sp.arrays.apply_arrays(revalue=True), one_chip), ev,
+            dense).compile(),
+        "sddmm": sddmm_apply.lower(
+            _shapes(sd.arrays.apply_arrays(), one_chip), dense, dense,
+            nnz=sd.nnz, backend="pallas", cfg=sd.tune_config, heads=heads,
+            interpret=False).compile(),
+    }
+    for kind, exe in compiled.items():
+        _check(exe)
+        assert {k.split(".")[0] for k in _kernel_scopes(exe.as_text())} <= \
+            {f"{kind}_mxu_mh", f"{kind}_vpu_mh"}
